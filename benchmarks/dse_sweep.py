@@ -54,6 +54,7 @@ import os
 import time
 
 from repro.apps import lbm
+from repro.compat import enable_compile_cache, resolve_interpret
 from repro.core.explorer import render_executed
 from repro.core.measure import MeasurementCache, calibrate_backend
 from repro.core.planner import ArchStats, plan, render_plans
@@ -76,10 +77,11 @@ BENCH_PATH = os.path.join(
 )
 
 
-def run(topk: int = 3, interpret: bool = True, reps: int = 3,
+def run(topk: int = 3, interpret: bool | None = None, reps: int = 3,
         bench: dict | None = None,
         cache: MeasurementCache | None = None) -> list[str]:
     """Print the sweep sections; fill ``bench`` (if given) for the JSON."""
+    interpret = resolve_interpret(interpret)
     out = []
     t0 = time.time()
     sim = lbm.LBMSimulation(lbm.LBMProblem(300, 720, mode="wrap"))
@@ -648,7 +650,7 @@ def run(topk: int = 3, interpret: bool = True, reps: int = 3,
 
 
 def write_bench(path: str = BENCH_PATH, topk: int = 3,
-                interpret: bool = True, reps: int = 3) -> list[str]:
+                interpret: bool | None = None, reps: int = 3) -> list[str]:
     """Run the sweeps and record ``BENCH_dse.json`` (the PR-over-PR
     trajectory file: best point, sustained GFLOPS, calibrated
     predicted-vs-measured error, and measurement-cache stats per app).
@@ -670,4 +672,5 @@ def write_bench(path: str = BENCH_PATH, topk: int = 3,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(write_bench()))

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.apps import lbm
+from repro.compat import default_interpret, enable_compile_cache
 from repro.kernels.lbm_stream.ops import lbm_run_blocked
 
 
@@ -51,7 +52,8 @@ def run(h: int = 128, w: int = 256, steps: int = 8) -> list[str]:
             ),
             f0,
         )
-        rows.append((f"pallas temporal-block m={m} (interpret)", t))
+        mode = "interpret" if default_interpret() else "compiled"
+        rows.append((f"pallas temporal-block m={m} ({mode})", t))
 
     out.append("## LBM throughput (CPU), grid %dx%d, %d steps" % (h, w, steps))
     for name, t in rows:
@@ -62,4 +64,5 @@ def run(h: int = 128, w: int = 256, steps: int = 8) -> list[str]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("\n".join(run()))

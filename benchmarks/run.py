@@ -10,6 +10,9 @@ import sys
 
 def main() -> None:
     from benchmarks import dse_sweep, lbm_bench, table3
+    from repro.compat import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = []
     sections += table3.run()

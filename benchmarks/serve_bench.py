@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.apps import diffusion as dif
 from repro.apps import lbm
+from repro.compat import enable_compile_cache
 from repro.core.measure import MeasurementCache
 from repro.serve.sim import PlanResolver, SimEngine, SimRequest
 
@@ -85,7 +86,7 @@ def make_tenants() -> list[dict]:
     context — the realistic serving shape.
     """
     tenants = []
-    for h, w, alpha in ((32, 32, 0.2), (64, 64, 0.1)):
+    for h, w, alpha in ((32, 128, 0.2), (64, 128, 0.1)):
         sim = dif.DiffusionSimulation(h, w, alpha=alpha)
         u0, _ = dif.sine_init(h, w)
         tenants.append({
@@ -94,10 +95,10 @@ def make_tenants() -> list[dict]:
             "state": sim.state(u0),
             "regs": (sim.alpha,),
         })
-    lsim = lbm.LBMSimulation(lbm.LBMProblem(32, 32, mode="wrap"))
-    f0, attr, _ = lbm.taylor_green_init(32, 32)
+    lsim = lbm.LBMSimulation(lbm.LBMProblem(32, 128, mode="wrap"))
+    f0, attr, _ = lbm.taylor_green_init(32, 128)
     tenants.append({
-        "name": "lbm-32x32",
+        "name": "lbm-32x128",
         "core": lsim.stream_kernel(),
         "state": lsim.stream_state(f0, attr),
         "regs": lsim.stream_regs(),
@@ -401,6 +402,7 @@ def main(argv: list[str] | None = None) -> None:
                          "rewriting it (the CI serve job's gate)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.check:
         with open(BENCH_PATH, encoding="utf-8") as fh:
             baseline = json.load(fh)

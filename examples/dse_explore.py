@@ -10,7 +10,8 @@ with the same spatial/temporal trade-off:
     PYTHONPATH=src python examples/dse_explore.py --arch granite-34b
 
 or, after ``pip install -e .``, simply ``repro-explore``. Use
-``--no-execute`` to skip the (host-speed) interpret-mode kernel runs,
+``--no-execute`` to skip the kernel runs (interpreted on the CPU,
+compiled on a TPU),
 ``--topk`` to execute more frontier points, ``--devices N`` to sweep the
 device axis d (multi-chip sharding with halo exchange; off-TPU force
 host devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``

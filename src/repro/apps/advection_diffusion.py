@@ -210,7 +210,7 @@ class AdvectionDiffusionSimulation:
         )
 
     def run(self, u, steps: int, *, fusion: str = "", m: int = 1,
-            block_h: int = 32, interpret: bool = True, d: int = 1):
+            block_h: int = 32, interpret: bool | None = None, d: int = 1):
         """Advance ``steps`` through the program under ``fusion``."""
         out = self.program.kernel(fusion).run_blocked(
             self.state(u), self.regs(), steps=steps, m=m,

@@ -138,7 +138,7 @@ class DiffusionSimulation:
         return self.kernel.pack([u])
 
     def run(self, u, steps: int, *, m: int = 1, block_h: int | None = None,
-            interpret: bool = True, d: int = 1):
+            interpret: bool | None = None, d: int = 1):
         """Advance ``steps`` diffusion steps through the Pallas kernel.
 
         ``d > 1`` shards the grid across that many devices with halo

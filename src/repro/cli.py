@@ -92,7 +92,8 @@ def explore_main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", type=str, default=None, metavar="PATH",
                     help="write the sweep/execution results as JSON")
     ap.add_argument("--no-execute", action="store_true",
-                    help="skip the (host-speed) interpret-mode Pallas runs")
+                    help="skip the Pallas kernel runs (interpret mode on "
+                         "the CPU backend, compiled on a TPU)")
     ap.add_argument("--strategy", default="exhaustive",
                     choices=sorted(STRATEGIES),
                     help="search strategy for the measured sweep "
@@ -208,7 +209,14 @@ def explore_main(argv: list[str] | None = None) -> None:
     if not args.no_execute:
         import jax
 
+        from repro.compat import default_interpret, enable_compile_cache
         from repro.core.measure import MeasurementCache
+
+        enable_compile_cache()
+        mode = (
+            "interpret mode on cpu" if default_interpret()
+            else f"compiled on {jax.default_backend()}"
+        )
 
         mcache = None if args.no_cache else MeasurementCache()
         # Only propose device counts the platform can run: on the tall
@@ -241,7 +249,7 @@ def explore_main(argv: list[str] | None = None) -> None:
         print("=" * 72)
         print(f"3) Model -> measurement: --strategy {args.strategy} "
               f"(budget: {args.budget if args.budget else 'none'}) over the")
-        print("   codegen'd uLBM Pallas kernel (interpret mode, 256x128; "
+        print(f"   codegen'd uLBM Pallas kernel ({mode}, 256x128; "
               "d>1 points run")
         print("   sharded — the grid is tall enough that sharding beats "
               "the halo exchange)")
@@ -255,8 +263,8 @@ def explore_main(argv: list[str] | None = None) -> None:
         f0, attr, _ = lbm.taylor_green_init(256, 128)
         mres = mex.search(
             msweep, msim.stream_state(f0, attr), msim.stream_regs(),
-            strategy=strategy, budget=args.budget, interpret=True,
-            reps=args.reps, calibrate=args.calibrate, cache=mcache,
+            strategy=strategy, budget=args.budget, reps=args.reps,
+            calibrate=args.calibrate, cache=mcache,
             **study_kw,
         )
         print(render_executed(mres.executed))
@@ -280,7 +288,7 @@ def explore_main(argv: list[str] | None = None) -> None:
         u0, _ = dif.sine_init(256, 128)
         dres = dex.search(dsweep, dsim.state(u0), (dsim.alpha,),
                           strategy=strategy, budget=args.budget,
-                          interpret=True, reps=args.reps,
+                          reps=args.reps,
                           calibrate=args.calibrate, cache=mcache,
                           **study_kw)
         print(render_executed(dres.executed))
@@ -325,7 +333,7 @@ def explore_main(argv: list[str] | None = None) -> None:
                 )
                 pres = pex.search(
                     psweep, state, regs, strategy=strategy,
-                    budget=args.budget, interpret=True, reps=args.reps,
+                    budget=args.budget, reps=args.reps,
                     calibrate=args.calibrate, cache=mcache, **study_kw,
                 )
                 print(f"-- {label} ({prog.nstages} stages, partitions: "
@@ -391,13 +399,15 @@ def serve_main(argv: list[str] | None = None) -> None:
 
     from repro.apps import diffusion as dif
     from repro.apps import lbm
+    from repro.compat import enable_compile_cache
     from repro.serve.sim import PlanResolver, SimEngine, SimRequest
 
     ap = argparse.ArgumentParser(prog="repro-serve", description=__doc__)
     ap.add_argument("--tenants", type=int, default=3, metavar="N",
                     help="tenant contexts in the mix, drawn cyclically "
-                         "from the built-in set (diffusion 32x32 / "
-                         "64x64, lbm 32x32); each is a distinct trial "
+                         "from the built-in set (diffusion 32x128 / "
+                         "64x128, lbm 32x128: widths the compiled "
+                         "kernel can stage); each is a distinct trial "
                          "context with its own autotuned plan")
     ap.add_argument("--requests", type=int, default=8, metavar="N",
                     help="requests submitted per tenant")
@@ -426,16 +436,17 @@ def serve_main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", type=str, default=None, metavar="PATH",
                     help="write the engine stats as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mix = []
-    for h, w, alpha in ((32, 32, 0.2), (64, 64, 0.1)):
+    for h, w, alpha in ((32, 128, 0.2), (64, 128, 0.1)):
         sim = dif.DiffusionSimulation(h, w, alpha=alpha)
         u0, _ = dif.sine_init(h, w)
         mix.append((f"diffusion-{h}x{w}", sim.kernel, sim.state(u0),
                     (sim.alpha,)))
-    lsim = lbm.LBMSimulation(lbm.LBMProblem(32, 32, mode="wrap"))
-    f0, attr, _ = lbm.taylor_green_init(32, 32)
-    mix.append(("lbm-32x32", lsim.stream_kernel(),
+    lsim = lbm.LBMSimulation(lbm.LBMProblem(32, 128, mode="wrap"))
+    f0, attr, _ = lbm.taylor_green_init(32, 128)
+    mix.append(("lbm-32x128", lsim.stream_kernel(),
                 lsim.stream_state(f0, attr), lsim.stream_regs()))
     tenants = [mix[i % len(mix)] for i in range(args.tenants)]
 

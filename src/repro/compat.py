@@ -1,65 +1,66 @@
-"""Cross-version jax shims for APIs that moved between releases.
+"""Platform decisions: interpreter vs chip, and the compile cache.
 
-Everything here degrades to the older spelling when the newer one is
-absent, so the same source runs on jax 0.4.x and current jax.
+Two decisions live here and nowhere else:
+
+* :func:`default_interpret` — whether Pallas kernels run under the
+  interpreter. Every ``interpret: bool | None = None`` argument in the
+  package resolves through :func:`resolve_interpret`, so the CPU backend
+  (tests, rehearsals) interprets and an accelerator backend always runs
+  the compiled kernel; nothing on the run path picks the interpreter on
+  a TPU unless a caller passes ``interpret=True`` explicitly.
+* :func:`enable_compile_cache` — where JAX's persistent compilation
+  cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when it is set, otherwise
+  the fixed in-checkout directory :data:`CHECKOUT_CACHE_DIR`.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        # newer jax renamed check_rep -> check_vma; accept the new
-        # spelling everywhere and translate for the old implementation
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
+#: Persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed, git-ignored directory at the root of the checkout
+#: (``src/repro/compat.py`` → ``<checkout>/.jax_cache``). The path is part
+#: of the cache's key, so it never depends on a temp name, pid or time.
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
-def pvary(x, axes):
-    """Mark ``x`` device-varying over ``axes`` inside shard_map.
+def default_interpret() -> bool:
+    """True only on the CPU backend: there Pallas kernels run under the
+    interpreter; on a TPU they are compiled."""
+    return jax.default_backend() == "cpu"
 
-    Uses the varying-axis type system where jax has one
-    (``lax.pcast(..., to="varying")`` / ``lax.pvary``); on older jax the
-    replication checker is simply disabled (check_vma=False -> check_rep)
-    and the marking is a no-op.
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """An explicit ``interpret`` wins; ``None`` asks
+    :func:`default_interpret`."""
+    return default_interpret() if interpret is None else bool(interpret)
+
+
+def compile_cache_dir(environ=os.environ) -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else
+    :data:`CHECKOUT_CACHE_DIR`."""
+    return environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
+    no other directory is set here.
     """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to="varying")
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is not None:
-        return fn(x, axes)
-    return x
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any jax version
-    (older jax wraps the per-module properties dict in a one-element list)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def set_mesh(mesh):
-    """``with set_mesh(mesh): ...`` — ambient-mesh context on any jax.
-
-    Newer jax has ``jax.set_mesh``; on older versions the ``Mesh`` object
-    is itself the context manager that installs the ambient mesh.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-__all__ = ["cost_analysis", "pvary", "set_mesh", "shard_map"]
+__all__ = [
+    "CHECKOUT_CACHE_DIR",
+    "compile_cache_dir",
+    "default_interpret",
+    "enable_compile_cache",
+    "resolve_interpret",
+]

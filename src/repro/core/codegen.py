@@ -54,6 +54,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+
 from .compiler import CompiledCore, eval_expr
 from .dfg import SPDError
 from .legalize import resolve_run_plan
@@ -505,7 +506,7 @@ class StreamKernel:
 
     def __call__(self, state, regs: Sequence = (), *, m: int = 1,
                  block_h: int = 32, double_buffer: bool = True,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
         """One fused launch: advance ``state`` by ``m`` time steps.
 
         ``double_buffer`` selects the streamed launch's buffer protocol
@@ -519,12 +520,12 @@ class StreamKernel:
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
         """Advance ``steps`` time steps using m-fused kernel launches."""
         return self._run_blocked(
             state, self._scal(regs), steps=int(steps), m=int(m),
             block_h=int(block_h), double_buffer=bool(double_buffer),
-            interpret=bool(interpret),
+            interpret=interpret,
         )
 
     def _run_blocked_impl(self, state, scal, *, steps, m, block_h,
@@ -561,7 +562,8 @@ class StreamKernel:
         return self._sharded[(d, dx)]
 
     def run_for_point(self, state, regs: Sequence = (), *, point,
-                      steps: int | None = None, interpret: bool = True):
+                      steps: int | None = None,
+                      interpret: bool | None = None):
         """Advance the grid using a DSE design point's (block_h, m).
 
         The point is legalized with the shared
@@ -580,6 +582,7 @@ class StreamKernel:
         block_h, m, nsteps, double_buffer = resolve_run_plan(
             h, point, steps, halo=self.halo, width=w, words=p, b=b,
             dx=1,  # this is the single-device launch path
+            interpret=interpret,
         )
         out = self.run_blocked(
             state, regs, steps=nsteps, m=m, block_h=block_h,

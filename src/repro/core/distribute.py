@@ -83,10 +83,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.parallel.sharding import stream_grid_pspec
 
-from .legalize import mesh_shape, resolve_run_plan, shard_height, shard_width
+from .legalize import (
+    guard_cols,
+    mesh_shape,
+    resolve_run_plan,
+    shard_height,
+    shard_width,
+)
 
 #: Name of the row device axis (the original ring axis).
 DEVICE_AXIS = "d"
@@ -220,7 +225,7 @@ class ShardedStreamKernel:
         spec = stream_grid_pspec(
             DEVICE_AXIS, axis_x=DEVICE_AXIS_X if self.dx > 1 else None
         )
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             local_run, mesh=self.mesh, in_specs=(spec, P(None)),
             out_specs=spec, check_vma=False,
         ))
@@ -313,6 +318,11 @@ class ShardedStreamKernel:
         step_fn = self.kernel._step_fn_guarded
         mh = m * halo
         mhx = m * halo_x
+        # Guard columns per side: the mhx exchanged columns plus zero
+        # padding up to whole half-lane tiles, so a lane-aligned shard
+        # stays lane-aligned once extended (the TPU kernel stages whole
+        # 128-lane tiles). The zero columns only widen the stale margin.
+        gx = guard_cols(mhx)
         # Row-ring permutes run over DEVICE_AXIS (per mesh column);
         # column-ring permutes over DEVICE_AXIS_X (per mesh row). A
         # size-1 row axis degenerates to the identity permute, which
@@ -333,6 +343,11 @@ class ShardedStreamKernel:
                     double_buffer=double_buffer, interpret=interpret,
                 )
 
+            def widen(left, mid, right):
+                """[zero pad | left | mid | right | zero pad] along x."""
+                pad = jnp.zeros(mid.shape[:2] + (gx - mhx,), mid.dtype)
+                return jnp.concatenate([pad, left, mid, right, pad], axis=2)
+
             def exchange_x(cur):
                 """[left-guard | local | right-guard] via the dx ring."""
                 left = jax.lax.ppermute(
@@ -341,7 +356,7 @@ class ShardedStreamKernel:
                 right = jax.lax.ppermute(
                     cur[:, :, :mhx], DEVICE_AXIS_X, perm_l
                 )
-                return jnp.concatenate([left, cur, right], axis=2)
+                return widen(left, cur, right)
 
             def body(_, cur):
                 if mh == 0 and mhx == 0:
@@ -358,7 +373,7 @@ class ShardedStreamKernel:
                         block_h=block_h, halo=0,
                         double_buffer=double_buffer, interpret=interpret,
                     )
-                    return out[:, :, mhx:mhx + w]
+                    return out[:, :, gx:gx + w]
                 # All first-hop collectives depend only on `cur` and are
                 # issued together: the row exchange (guard rows at local
                 # width) and, when the core reads in x, the column
@@ -387,11 +402,11 @@ class ShardedStreamKernel:
                     dr = jax.lax.ppermute(
                         dn0[:, :, :mhx], DEVICE_AXIS_X, perm_l
                     )
-                    upx = jnp.concatenate([ul, up0, ur], axis=2)
-                    dnx = jnp.concatenate([dl, dn0, dr], axis=2)
+                    upx = widen(ul, up0, ur)
+                    dnx = widen(dl, dn0, dr)
                 else:
                     curx, upx, dnx = cur, up0, dn0
-                wx = w + 2 * mhx
+                wx = w + 2 * gx
                 pad = jnp.zeros((p, block_h - mh, wx), cur.dtype)
                 if overlap and nblk >= 3:
                     # Overlap generalization (DESIGN.md §15): the
@@ -416,7 +431,7 @@ class ShardedStreamKernel:
                         [pad, upx, curx, dnx, pad], axis=1
                     )
                     out = shard_launch(ext, scal)
-                return out[:, :, mhx:mhx + w] if mhx else out
+                return out[:, :, gx:gx + w] if gx else out
 
             return jax.lax.fori_loop(0, steps // m, body, local)
 
@@ -426,7 +441,8 @@ class ShardedStreamKernel:
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
-                    overlap: bool | None = None, interpret: bool = True):
+                    overlap: bool | None = None,
+                    interpret: bool | None = None):
         """Advance ``steps`` time steps, halo-exchanging every m steps.
 
         ``double_buffer`` selects the per-shard streamed launch's buffer
@@ -467,7 +483,8 @@ class ShardedStreamKernel:
         return fn(state, self.kernel._scal(regs))
 
     def run_for_point(self, state, regs: Sequence = (), *, point,
-                      steps: int | None = None, interpret: bool = True):
+                      steps: int | None = None,
+                      interpret: bool | None = None):
         """Advance the grid using a DSE design point's (block_h, m).
 
         The point is legalized *per shard* with the shared
@@ -478,7 +495,7 @@ class ShardedStreamKernel:
         p, h, w = state.shape
         block_h, m, nsteps, double_buffer = resolve_run_plan(
             h, point, steps, halo=self.halo, width=w, words=p, d=self.d,
-            dx=self.dx, halo_x=self.halo_x,
+            dx=self.dx, halo_x=self.halo_x, interpret=interpret,
         )
         out = self.run_blocked(
             state, regs, steps=nsteps, m=m, block_h=block_h,

@@ -42,7 +42,10 @@ import numpy as np
 from .legalize import (
     VMEM_BYTES,
     cluster_vmem_bytes,
+    lane_multiple,
     parse_fusion,
+    stripe_cols,
+    stripe_rows,
     stripe_vmem_bytes,
 )
 
@@ -426,6 +429,11 @@ class TPUTarget:
     # prediction bit-identical; benchmarks/dse_sweep.py §2h calibrates
     # it from a tiny-grid probe through the real execution path.
     launch_overhead_s: float = 0.0
+    # What the shard width must be a multiple of for the kernel to run
+    # (repro.core.legalize.lane_multiple): 128 lanes compiled, 1 under
+    # the interpreter. None follows the live backend, as the legalizer
+    # does, so a model-feasible point is one the legalizer accepts.
+    lanes: int | None = None
 
 
 class TPUModel:
@@ -521,6 +529,12 @@ class TPUModel:
         if dx > 1 and (not w.grid_w or grid_w % dx):
             pt.feasible = False
             pt.limits.append(f"colshard {grid_w}%{dx}!=0")
+        # The compiled kernel stages whole lane tiles: a shard width that
+        # is not a multiple of them cannot launch (legalize's lane rule).
+        lanes = t.lanes or lane_multiple()
+        if w.grid_w and shard_w % lanes:
+            pt.feasible = False
+            pt.limits.append(f"lanes {shard_w}%{lanes}!=0")
 
         # The dy axis decomposes the grid along y into dy equal shards
         # (halo-exchanged over ICI). A height dy does not divide has no
@@ -574,14 +588,17 @@ class TPUModel:
             pt.feasible = False
             pt.limits.append(f"VMEM {vmem}>{t.vmem_bytes}")
 
-        # Halo overhead: the 2·m·halo halo rows are recomputed per block;
-        # under dx > 1 the 2·m·halo_x guard columns add the analogous
-        # column trapezoid (DESIGN.md §15). The batch axis multiplies
+        # Halo overhead: the launch computes (and DMAs in) the whole
+        # stripe — block_h plus the carried halo rows, whole 8-row tiles
+        # (legalize.stripe_rows) — and under dx > 1 the guard columns
+        # (legalize.stripe_cols, DESIGN.md §15): the geometry the kernel
+        # runs, not the m·halo cone alone. The batch axis multiplies
         # sites (b independent grids advance per launch), leaving the
         # useful fraction unchanged.
         if clusters is None:
-            colf = shard_w / (shard_w + 2 * m * hx) if dx > 1 else 1.0
-            useful = bh / (bh + 2 * m * w.halo) * colf
+            colf = (shard_w / stripe_cols(shard_w, m, hx)) if dx > 1 else 1.0
+            useful = bh / stripe_rows(bh, m, w.halo) * colf
+            read_amp = 1.0 / useful
             flops = b * w.elems * w.flops_per_elem * m / useful
             hbm_passes = 1
             launches = 1
@@ -593,13 +610,17 @@ class TPUModel:
             # cluster fuses m_c steps (m when fused, 1 per launch when
             # pipelined — a program step is one pass through the chain).
             launches = m // m_c  # cluster launches per m-step block
-            flops = sum(
-                b * w.elems * c["flops"] * launches * m_c
-                / (bh / (bh + 2 * m_c * c["halo"]))
-                / ((shard_w / (shard_w + 2 * m_c * c["halo"]))
+            amps = [
+                stripe_rows(bh, m_c, c["halo"]) / bh
+                * ((stripe_cols(shard_w, m_c, c["halo"]) / shard_w)
                    if dx > 1 else 1.0)
                 for c in clusters
+            ]
+            flops = sum(
+                b * w.elems * c["flops"] * launches * m_c * amp
+                for c, amp in zip(clusters, amps)
             )
+            read_amp = sum(amps) / len(amps)
             useful = (b * w.elems * w.flops_per_elem * m) / flops
             # Every cut edge costs a full-grid HBM write + read per
             # program step: k clusters = m·k grid passes per m-step
@@ -611,8 +632,11 @@ class TPUModel:
             exch_halo_x = exch_halo  # stage halos are symmetric in x/y
             launches = launches * len(clusters)  # total per m-step block
         t_compute = flops / (d * t.vpu_f32_tflops * 1e12)
+        # Every launch reads its stripes whole (halo rows and guard
+        # columns included, read_amp) and writes the block once.
         t_memory = (
-            hbm_passes * b * w.elems * bytes_per_elem
+            hbm_passes * b * w.elems * 4
+            * (w.words_in * read_amp + w.words_out)
             / (d * t.hbm_gbs * 1e9)
         )
         # Cross-chip halo exchange: the row exchange moves 2·m·halo rows
@@ -751,6 +775,8 @@ class TPUModel:
             # launch geometry (scalar path's limit)
             shard_h = np.maximum(grid_h // dya, 1)
             feasible = feasible & ((dya == 1) | (bh <= shard_h))
+            # the compiled kernel's lane rule (scalar path's limit)
+            feasible = feasible & (shard_w % (t.lanes or lane_multiple()) == 0)
         else:
             # no known width: column sharding has no executable geometry
             feasible = feasible & (dxa == 1)
@@ -759,9 +785,10 @@ class TPUModel:
 
         if clusters is None:
             colf = np.where(
-                dxa > 1, shard_w / (shard_w + 2 * m * hx), 1.0
+                dxa > 1, shard_w / stripe_cols(shard_w, m, hx), 1.0
             )
-            useful = bh / (bh + 2 * m * w.halo) * colf
+            useful = bh / stripe_rows(bh, m, w.halo) * colf
+            read_amp = 1.0 / useful
             flops = batch * w.elems * w.flops_per_elem * m / useful
             hbm_passes = np.ones_like(m, dtype=np.float64)
             launches = np.ones_like(m, dtype=np.float64)
@@ -770,16 +797,20 @@ class TPUModel:
         else:
             m_c = np.where(len(clusters) == 1, m, 1)
             launches = m // m_c
-            flops = sum(
-                batch * w.elems * c["flops"] * launches * m_c
-                / (bh / (bh + 2 * m_c * c["halo"]))
-                / np.where(
+            amps = [
+                stripe_rows(bh, m_c, c["halo"]) / bh
+                * np.where(
                     dxa > 1,
-                    shard_w / (shard_w + 2 * m_c * c["halo"]),
+                    stripe_cols(shard_w, m_c, c["halo"]) / shard_w,
                     1.0,
                 )
                 for c in clusters
+            ]
+            flops = sum(
+                batch * w.elems * c["flops"] * launches * m_c * amp
+                for c, amp in zip(clusters, amps)
             )
+            read_amp = sum(amps) / len(amps)
             useful = (batch * w.elems * w.flops_per_elem * m) / flops
             hbm_passes = np.where(
                 len(clusters) == 1, 1.0, (m * len(clusters)).astype(np.float64)
@@ -792,7 +823,8 @@ class TPUModel:
             launches = (launches * len(clusters)).astype(np.float64)
         t_compute = flops / (chips * t.vpu_f32_tflops * 1e12)
         t_memory = (
-            hbm_passes * batch * w.elems * bytes_per_elem
+            hbm_passes * batch * w.elems * 4
+            * (w.words_in * read_amp + w.words_out)
             / (chips * t.hbm_gbs * 1e9)
         )
         # Two exchange volumes (DESIGN.md §15): rows at shard width over

@@ -40,6 +40,7 @@ from typing import Sequence
 
 import numpy as np
 
+
 from .dse import (
     DesignPoint,
     FPGAModel,
@@ -374,7 +375,7 @@ class Explorer:
         budget: int | None = None,
         core=None,
         steps: int | None = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
         reps: int = 3,
         warmup: int = 1,
         calibrate: bool = True,
@@ -585,7 +586,7 @@ class Explorer:
         core=None,
         k: int = 3,
         steps: int | None = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
         reps: int = 3,
         *,
         warmup: int = 1,

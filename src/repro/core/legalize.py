@@ -14,22 +14,36 @@ means (docs/pipeline.md §legalize).
 ``VMEM_BYTES`` is the single definition of the on-chip vector-memory
 budget: the DSE model's :class:`~repro.core.dse.TPUTarget` feasibility
 check and the legalizer's stripe clamp both read it, so a point the model
-calls feasible is one the legalizer will not shrink.
+calls feasible is one the legalizer will not shrink, and the streamed
+launch hands the same number to the TPU compiler as its scoped-VMEM
+limit. :func:`stripe_vmem_bytes` prices what the compiler allocates for
+one launch: the input stripe buffers, the output staging buffers, and
+the temporaries of the stripe body.
+
+Three rules come from what the TPU compiler refuses, and hold for every
+plan returned here: ``block_h`` is a multiple of :data:`SUBLANE_ROWS`
+(DMA row offsets and the output crop must start on an 8-row tile); the
+halo is carried in whole tiles (:func:`halo_rows`), which the VMEM
+price includes; and, when the kernel is compiled rather than
+interpreted, the shard width is a multiple of :data:`LANES`
+(:func:`lane_multiple`) — every function that is given a ``width``
+takes an ``interpret`` argument for this rule, ``None`` following the
+backend.
 
 The device axis ``d`` (spatial parallelism across chips,
 docs/pipeline.md §distribute) legalizes *per shard*: the grid's ``h``
 rows must split into ``d`` equal shards (a hard error otherwise — there
 is no "closest" shard count), and the (block_h, m) plan is then
 legalized against the shard height ``h / d``, with the same VMEM stripe
-accounting a single device uses (every shard keeps its own
-``block_h + 2·m·halo``-row stripes resident).
+accounting a single device uses (every shard keeps its own stripes
+resident).
 
 ``dx`` factors the device count into a 2-D mesh ``(dy, dx)`` with
 ``dy = d / dx`` (DESIGN.md §15): rows shard over ``dy`` as before and
 columns shard over ``dx``, so the shard geometry is
 ``(h / dy, width / dx)``. Legalization then runs against the shard
 height ``h / dy`` and prices stripes at the per-shard width plus the
-``2·m·halo_x`` guard columns each fused launch keeps resident — wide
+guard columns (:func:`guard_cols`) each fused launch keeps resident — wide
 grids legalize larger ``block_h``/``m`` under ``dx > 1`` because the
 per-stripe width term shrinks by ``dx``. A width the column axis does
 not divide is a hard error (:func:`shard_width`), exactly mirroring the
@@ -75,9 +89,34 @@ from __future__ import annotations
 
 from dataclasses import dataclass, fields
 
-#: TPU v5e on-chip vector memory (VMEM) capacity in bytes. Single source of
-#: truth for the DSE model (``TPUTarget.vmem_bytes``) and the legalizer.
-VMEM_BYTES = 128 * 1024 * 1024
+import numpy as np
+
+from repro.compat import resolve_interpret
+
+#: VMEM budget of one streamed launch, in bytes: the ``vmem_limit_bytes``
+#: every streamed launch compiles with, and the budget the legalizer and
+#: the DSE model (``TPUTarget.vmem_bytes``) price stripes against. A TPU
+#: v5e core has 128 MiB of VMEM; the rest is left to the compiler's own
+#: scratch.
+VMEM_BYTES = 100 * 1024 * 1024
+
+#: Rows of one f32 sublane tile: DMA row offsets into HBM and slices of
+#: VMEM buffers must start on a multiple of it, so ``block_h`` and the
+#: carried halo are whole tiles.
+SUBLANE_ROWS = 8
+
+#: Lanes of one f32 vreg tile: the streamed kernel stages whole rows, so
+#: the width it is launched at must be a multiple of it when compiled
+#: (:func:`lane_multiple`).
+LANES = 128
+
+#: Temporaries of one stripe-body application, in stripe-sized f32 rows
+#: arrays per state word plus a fixed count (``2·words + 3``). Fitted
+#: from v5e compiles of the streamed diffusion (1 word: 3.4-4.0 arrays
+#: beyond the buffers) and uLBM (10 words: 17.6-18.8 arrays) launches;
+#: independent of ``m``, since the m applications run one after another.
+TEMP_ARRAYS_PER_WORD = 2
+TEMP_ARRAYS_FIXED = 3
 
 #: Ping/pong streaming keeps two stripes resident (one computing, one in
 #: DMA flight), so a double-buffered stripe occupies twice its size.
@@ -186,14 +225,68 @@ def parse_fusion(spec: str, nstages: int) -> tuple[int, ...]:
     return sizes
 
 
+def halo_rows(mh, block_h):
+    """Halo rows a streamed launch carries per side for ``mh = m·halo``.
+
+    ``mh`` rounded up to whole :data:`SUBLANE_ROWS` tiles, capped at
+    ``block_h`` (the halo is sourced from one neighbour block): with an
+    aligned ``block_h`` every stripe piece then starts on a tile. Works
+    elementwise on numpy arrays (the model's batched lattice).
+    """
+    rows = np.minimum(-(-mh // SUBLANE_ROWS) * SUBLANE_ROWS, block_h)
+    return rows if np.ndim(rows) else int(rows)
+
+
+def guard_cols(mhx):
+    """Guard columns per side of a column-sharded launch for
+    ``mhx = m·halo_x`` exchanged columns: rounded up to whole half-lane
+    tiles (``LANES // 2``), so a lane-aligned shard width stays
+    lane-aligned once both guards are added. Works on numpy arrays."""
+    half = LANES // 2
+    return -(-mhx // half) * half
+
+
+def stripe_rows(block_h, m, halo):
+    """Rows of one streamed stripe: ``block_h`` plus the carried halo
+    (:func:`halo_rows`) on both sides — what the launch DMAs and
+    computes per block. Works on numpy arrays."""
+    return block_h + 2 * halo_rows(m * halo, block_h)
+
+
+def stripe_cols(width, m, halo_x):
+    """Columns of one streamed launch over a ``width``-column shard:
+    the shard plus :func:`guard_cols` on both sides (``halo_x = 0``
+    when the column axis is unsharded). Works on numpy arrays."""
+    return width + 2 * guard_cols(m * halo_x)
+
+
+def lane_multiple(interpret: bool | None = None) -> int:
+    """What a launch width must be a multiple of: :data:`LANES` for the
+    compiled TPU kernel, 1 under the Pallas interpreter. ``None``
+    follows the backend (:func:`repro.compat.default_interpret`)."""
+    return 1 if resolve_interpret(interpret) else LANES
+
+
+def _lane_error(width: int, dx: int) -> str:
+    over = f" (grid width {width * dx} over dx={dx})" if dx > 1 else ""
+    return (
+        f"shard width {width}{over} is not a multiple of {LANES} lanes: "
+        f"the compiled TPU kernel stages whole {LANES}-lane rows"
+    )
+
+
 def stripe_vmem_bytes(block_h, m, width: int, words: int,
                       halo: int = 1, double_buffer: bool = True,
                       b: int = 1, halo_x: int = 0):
-    """VMEM bytes of one (block_h + 2·m·halo)-row f32 stripe of ``words``
-    fields, matching the residency term of ``TPUModel.evaluate``.
+    """VMEM bytes one streamed launch allocates for ``words`` f32 fields,
+    matching the residency term of ``TPUModel.evaluate``.
 
+    The stripe has ``block_h + 2·halo_rows(m·halo)`` rows. Per buffer
+    slot the launch holds one input stripe and one ``block_h``-row output
+    staging block; the stripe body adds ``TEMP_ARRAYS_PER_WORD·words +
+    TEMP_ARRAYS_FIXED`` stripe-sized temporaries.
     ``double_buffer=True`` prices the ping/pong pair
-    (:data:`VMEM_DOUBLE_BUFFER` stripes resident); ``False`` prices the
+    (:data:`VMEM_DOUBLE_BUFFER` buffer slots); ``False`` prices the
     single-buffer streaming fallback. ``b`` is the batch axis
     (docs/pipeline.md §serve): ``b`` stacked simulations keep ``b``
     copies of every stripe resident, a plain linear multiplier — the one
@@ -203,21 +296,43 @@ def stripe_vmem_bytes(block_h, m, width: int, words: int,
 
     ``halo_x`` prices the guard columns of a column-sharded stripe
     (DESIGN.md §15): under ``dx > 1`` every fused launch keeps
-    ``2·m·halo_x`` neighbor columns resident alongside the per-shard
-    ``width``, mirroring the ``2·m·halo`` guard rows. Callers pass 0
-    when the column axis is unsharded, keeping legacy accounting
-    byte-identical.
+    ``2·guard_cols(m·halo_x)`` columns resident alongside the per-shard
+    ``width``, mirroring the guard rows. Callers pass 0 when the column
+    axis is unsharded.
     """
-    rows = block_h + 2 * m * halo
-    mult = VMEM_DOUBLE_BUFFER if double_buffer else 1
+    rows = stripe_rows(block_h, m, halo)
+    nbuf = VMEM_DOUBLE_BUFFER if double_buffer else 1
     if getattr(b, "shape", None) in (None, ()):  # scalar: clamp to >= 1
         b = max(int(b), 1)
     # else: array batch-axis values broadcast straight through (the
     # model's batched lattice evaluation pre-clamps them)
     if getattr(width, "shape", None) in (None, ()):  # scalar: clamp
         width = max(int(width), 1)
-    cols = width + 2 * m * halo_x
-    return rows * cols * max(words, 1) * 4 * mult * b
+    cols = stripe_cols(width, m, halo_x)
+    words = max(words, 1)
+    temps = TEMP_ARRAYS_PER_WORD * words + TEMP_ARRAYS_FIXED
+    row_arrays = nbuf * words * (rows + block_h) + temps * rows
+    return row_arrays * cols * 4 * b
+
+
+def aligned_divisors(n: int) -> list[int]:
+    """Divisors of ``n`` that are whole :data:`SUBLANE_ROWS` tiles."""
+    return [v for v in range(SUBLANE_ROWS, n + 1, SUBLANE_ROWS)
+            if n % v == 0]
+
+
+def _block_divisors(local_h: int, h: int, d: int) -> list[int]:
+    """:func:`aligned_divisors` of the shard height; a ``ValueError``
+    when there are none."""
+    divisors = aligned_divisors(local_h)
+    if not divisors:
+        raise ValueError(
+            f"shard of h={local_h} rows has no block of whole "
+            f"{SUBLANE_ROWS}-row tiles dividing it (the TPU kernel needs "
+            f"block_h % {SUBLANE_ROWS} == 0"
+            f"{f'; grid h={h} over d={d} shards' if d > 1 else ''})"
+        )
+    return divisors
 
 
 def shard_width(w: int, dx: int) -> int:
@@ -285,10 +400,12 @@ def legal_block_values(h: int, m: int, *, halo: int = 1,
                        d: int = 1,
                        double_buffer: bool = True,
                        b: int = 1, dx: int = 1,
-                       halo_x: int = 0) -> tuple[int, ...]:
+                       halo_x: int = 0,
+                       interpret: bool | None = None) -> tuple[int, ...]:
     """Every legal ``block_h`` for ``m`` fused steps on an ``h``-row grid.
 
-    The ascending chain of shard-height divisors that can source the
+    The ascending chain of tile-aligned shard-height divisors
+    (:func:`aligned_divisors`) that can source the
     ``m·halo`` stencil halo and (when the stripe geometry is supplied)
     fit the shared VMEM budget — i.e. exactly the values
     :func:`blocking_plan` chooses among for the same ``double_buffer``
@@ -301,21 +418,21 @@ def legal_block_values(h: int, m: int, *, halo: int = 1,
     ``dx`` factors ``d`` into the 2-D mesh (DESIGN.md §15): the divisor
     chain runs over the shard height ``h / dy`` and stripes are priced
     at the per-shard width ``width / dx`` plus the ``2·m·halo_x`` guard
-    columns.
+    columns. A shard width the compiled kernel cannot stage
+    (:func:`lane_multiple`) has no legal block: the empty tuple.
     """
     if h < 1:
         raise ValueError(f"grid height must be positive, got {h}")
     dy, dx = mesh_shape(d, dx)
     local_h = shard_height(h, dy)
     local_w = shard_width(width, dx) if width else width
+    if local_w % lane_multiple(interpret):
+        return ()
     guard_x = max(0, int(halo_x)) if dx > 1 else 0
     halo = max(0, int(halo))
     m = max(1, min(int(m), local_h))
     floor = max(1, m * halo)
-    legal = [
-        v for v in range(1, local_h + 1)
-        if local_h % v == 0 and v >= floor
-    ]
+    legal = [v for v in aligned_divisors(local_h) if v >= floor]
     if width and words:
         legal = [
             v for v in legal
@@ -331,7 +448,8 @@ def blocking_plan(h: int, block_h: int, m: int, *, halo: int = 1,
                   vmem_bytes: int = VMEM_BYTES, d: int = 1,
                   double_buffer: bool = True,
                   b: int = 1, dx: int = 1,
-                  halo_x: int = 0) -> tuple[int, int, bool]:
+                  halo_x: int = 0,
+                  interpret: bool | None = None) -> tuple[int, int, bool]:
     """Legalize a model-chosen (block_h, m) for a grid of ``h`` rows.
 
     The temporal-blocking kernels require ``block_h | h`` and
@@ -340,9 +458,10 @@ def blocking_plan(h: int, block_h: int, m: int, *, halo: int = 1,
     ``repro.core.codegen``, 1 for the LBM kernel). The model's lattice is
     grid-agnostic, so its pick may violate either; this returns the
     closest legal plan ``(block_h, m, double_buffer)``: the largest
-    divisor of ``h`` that is <= the requested block (or the smallest one
-    >= m*halo when the request is too small), with ``m`` clamped into
-    [1, h].
+    multiple-of-8 divisor of ``h`` that is <= the requested block (or the
+    smallest one >= m*halo when the request is too small), with ``m``
+    clamped into [1, h]. A grid with no such divisor is an error: the
+    TPU compiler refuses row slices that do not start on an 8-row tile.
 
     With ``d > 1`` the plan is legalized *per shard*: ``h`` must split
     into ``d`` equal shards (:func:`shard_height` raises otherwise) and
@@ -372,17 +491,23 @@ def blocking_plan(h: int, block_h: int, m: int, *, halo: int = 1,
     over the ``dy``-shard height and every stripe is priced at the
     per-shard width plus its ``2·m·halo_x`` guard columns — the reason
     wide grids legalize larger blocks under column sharding.
+
+    Compiled for the TPU (``interpret`` false, or ``None`` on a TPU
+    backend), a shard width that is not a multiple of :data:`LANES` is
+    a ``ValueError``: the kernel cannot stage it, whatever the block.
     """
     if h < 1:
         raise ValueError(f"grid height must be positive, got {h}")
     dy, dx = mesh_shape(d, dx)
     local_h = shard_height(h, dy)
     width = shard_width(width, dx) if width else width
+    if width % lane_multiple(interpret):
+        raise ValueError(_lane_error(width, dx))
     halo_x = max(0, int(halo_x)) if dx > 1 else 0
     halo = max(0, int(halo))
     m = max(1, min(int(m), local_h))
     floor = max(1, m * halo)
-    divisors = [v for v in range(1, local_h + 1) if local_h % v == 0]
+    divisors = _block_divisors(local_h, h, d)
     legal = [v for v in divisors if v >= floor]
     while not legal and m > 1:  # m*halo exceeds the shard: shrink m
         m -= 1
@@ -434,7 +559,8 @@ def constraint_violation(h: int, block_h: int, m: int, *, halo: int = 1,
                          vmem_bytes: int = VMEM_BYTES, d: int = 1,
                          double_buffer: bool = True,
                          b: int = 1, dx: int = 1,
-                         halo_x: int = 0) -> float:
+                         halo_x: int = 0,
+                         interpret: bool | None = None) -> float:
     """Continuous distance-to-feasibility of a (block_h, m, d) request.
 
     Exactly ``0.0`` iff :func:`blocking_plan` would produce a legal plan
@@ -459,7 +585,10 @@ def constraint_violation(h: int, block_h: int, m: int, *, halo: int = 1,
       VMEM violation of the same order);
     * **unshardable grid** — ``h % dy != 0`` (or, for a 2-D mesh,
       ``width % dx != 0`` / ``d % dx != 0``, DESIGN.md §15) has no
-      closest legal plan at all: ``1 +`` the fractional remainder.
+      closest legal plan at all: ``1 +`` the fractional remainder. A
+      shard width the compiled kernel cannot stage
+      (:func:`lane_multiple`) is ``1 +`` the fraction of a lane tile
+      missing.
     """
     if h < 1:
         raise ValueError(f"grid height must be positive, got {h}")
@@ -477,19 +606,26 @@ def constraint_violation(h: int, block_h: int, m: int, *, halo: int = 1,
         return 1.0 + (width % dx) / dx
     local_h = h // dy
     width = width // dx if width else width
+    lanes = lane_multiple(interpret)
+    if width % lanes:
+        return 1.0 + (-width % lanes) / lanes
     halo_x = max(0, int(halo_x)) if dx > 1 else 0
     halo = max(0, int(halo))
     m = max(1, min(int(m), local_h))
-    if halo > local_h:
+    divisors = aligned_divisors(local_h)
+    if not divisors:
+        # no tile-aligned block divides the shard: distance to the
+        # next multiple of SUBLANE_ROWS
+        return 1.0 + (-local_h % SUBLANE_ROWS) / SUBLANE_ROWS
+    if halo > max(divisors):
         # even one fused step cannot source its halo on this shard
-        return 1.0 + (halo - local_h) / local_h
+        return 1.0 + (halo - max(divisors)) / max(divisors)
     if not (width and words):
         return 0.0
     # Mirror blocking_plan's m-shrink loop, then price the smallest
     # legal stripe against the budget. blocking_plan falls back to
     # double_buffer=False before erroring, so a request is only
     # infeasible when even the single-buffered stripe overflows.
-    divisors = [v for v in range(1, local_h + 1) if local_h % v == 0]
     floor = max(1, m * halo)
     legal = [v for v in divisors if v >= floor]
     while not legal and m > 1:
@@ -541,7 +677,9 @@ def program_blocking_plan(h: int, block_h: int, m: int, *,
                           stages, fusion: str = "", width: int = 0,
                           vmem_bytes: int = VMEM_BYTES, d: int = 1,
                           double_buffer: bool = True,
-                          b: int = 1, dx: int = 1) -> tuple[int, int, bool]:
+                          b: int = 1, dx: int = 1,
+                          interpret: bool | None = None,
+                          ) -> tuple[int, int, bool]:
     """Legalize a (block_h, m) plan for a stream *program* under a
     fusion partition (docs/pipeline.md §program, DESIGN.md §14).
 
@@ -564,7 +702,8 @@ def program_blocking_plan(h: int, block_h: int, m: int, *,
     ``dx > 1`` legalizes against the 2-D mesh shard geometry
     (DESIGN.md §15): the divisor chain runs over the ``dy``-shard height
     and every cluster's stripe set is priced at the per-shard width
-    ``width / dx``.
+    ``width / dx``. The compiled kernel's lane rule applies as in
+    :func:`blocking_plan`.
     """
     stages = [(int(w), int(hh)) for (w, hh) in stages]
     sizes = parse_fusion(fusion, len(stages))
@@ -575,11 +714,13 @@ def program_blocking_plan(h: int, block_h: int, m: int, *,
     dy, dx = mesh_shape(d, dx)
     local_h = shard_height(h, dy)
     width = shard_width(width, dx) if width else width
+    if width % lane_multiple(interpret):
+        raise ValueError(_lane_error(width, dx))
     fused = len(clusters) == 1
     m = max(1, min(int(m), local_h))
     b = max(1, int(b))
     spec = fusion or str(len(stages))
-    divisors = [v for v in range(1, local_h + 1) if local_h % v == 0]
+    divisors = _block_divisors(local_h, h, d)
     geom = [
         (sum(w for w, _ in c), sum(hh for _, hh in c)) for c in clusters
     ]
@@ -651,6 +792,7 @@ def resolve_run_plan(
     vmem_bytes: int = VMEM_BYTES, b: int | None = None,
     stages=None, fusion: str | None = None,
     dx: int | None = None, halo_x: int = 0,
+    interpret: bool | None = None,
 ) -> tuple[int, int, int, bool]:
     """Turn a DSE design point into a concrete
     (block_h, m, steps, double_buffer) plan.
@@ -681,7 +823,8 @@ def resolve_run_plan(
     ``dx`` is the mesh column axis (DESIGN.md §15): ``None`` reads the
     point's ``detail['dx']`` (1 when absent, matching pre-mesh points),
     an explicit value overrides; ``halo_x`` is the per-step x stencil
-    reach the guard columns must cover.
+    reach the guard columns must cover. ``interpret`` selects the lane
+    rule of the launch the plan is for (:func:`lane_multiple`).
     """
     detail = getattr(point, "detail", None) or {}
     requested_db = bool(detail.get("double_buffer", True))
@@ -696,14 +839,14 @@ def resolve_run_plan(
             h, int(point.detail["block_rows"]), int(point.m),
             stages=stages, fusion=fusion, width=width,
             vmem_bytes=vmem_bytes, d=d, double_buffer=requested_db, b=b,
-            dx=dx,
+            dx=dx, interpret=interpret,
         )
     else:
         block_h, m, double_buffer = blocking_plan(
             h, int(point.detail["block_rows"]), int(point.m),
             halo=halo, width=width, words=words, d=d,
             vmem_bytes=vmem_bytes, double_buffer=requested_db, b=b,
-            dx=dx, halo_x=halo_x,
+            dx=dx, halo_x=halo_x, interpret=interpret,
         )
     nsteps = m if steps is None else max(m, (steps // m) * m)
     return block_h, m, nsteps, double_buffer
@@ -712,11 +855,17 @@ def resolve_run_plan(
 __all__ = [
     "PLAN_FIELDS",
     "RunPlan",
+    "LANES",
+    "SUBLANE_ROWS",
     "VMEM_BYTES",
     "VMEM_DOUBLE_BUFFER",
+    "aligned_divisors",
     "blocking_plan",
     "cluster_vmem_bytes",
     "constraint_violation",
+    "guard_cols",
+    "halo_rows",
+    "lane_multiple",
     "legal_block_values",
     "mesh_shape",
     "parse_fusion",
@@ -724,5 +873,7 @@ __all__ = [
     "resolve_run_plan",
     "shard_height",
     "shard_width",
+    "stripe_cols",
+    "stripe_rows",
     "stripe_vmem_bytes",
 ]

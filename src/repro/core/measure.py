@@ -56,8 +56,10 @@ from typing import Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.compat import resolve_interpret
+
 from .dse import StreamWorkload, TPUModel, TPUTarget
-from .legalize import blocking_plan
+from .legalize import blocking_plan, lane_multiple
 
 __all__ = [
     "BackendCalibration",
@@ -534,7 +536,7 @@ def _fma_chain_spd(chain: int) -> str:
 
 
 def measure_elementwise_gflops(
-    interpret: bool = True,
+    interpret: bool | None = None,
     *,
     chain: int = 32,
     shape: tuple[int, int] = (128, 128),
@@ -557,7 +559,8 @@ def measure_elementwise_gflops(
     h, w = shape
     kern = Registry().compile(parse_spd(_fma_chain_spd(chain))).stream_kernel()
     state = jnp.full((1, h, w), 0.5, jnp.float32)
-    bh, mm, _ = blocking_plan(h, block_h, m, halo=kern.halo, width=w, words=1)
+    bh, mm, _ = blocking_plan(h, block_h, m, halo=kern.halo, width=w, words=1,
+                              interpret=interpret)
     timing = time_run(
         lambda: kern.run_blocked(
             state, (0.997,), steps=mm, m=mm, block_h=bh, interpret=interpret
@@ -608,7 +611,8 @@ class BackendCalibration:
         host memory system (CPU backend / interpret mode — forced host
         devices split one machine's bandwidth); on real accelerators
         the probe measured a single chip's HBM and every chip has its
-        own, so the per-chip constant stands.
+        own, so the per-chip constant stands. The target's lane rule is
+        the one of the mode measured (``legalize.lane_multiple``).
         """
         base = base or TPUTarget()
         d = max(1, int(d))
@@ -619,6 +623,7 @@ class BackendCalibration:
             name=f"{base.name}+measured[{self.backend}{mode}]",
             vpu_f32_tflops=self.gflops(d) / d / 1e3,
             hbm_gbs=self.mem_gbs / d if shared_memory else self.mem_gbs,
+            lanes=lane_multiple(self.interpret),
         )
 
     def model(self, d: int = 1, base: TPUTarget | None = None) -> TPUModel:
@@ -627,7 +632,7 @@ class BackendCalibration:
 
 
 def calibrate_backend(
-    interpret: bool = True,
+    interpret: bool | None = None,
     *,
     chain: int = 32,
     shape: tuple[int, int] = (128, 128),
@@ -643,6 +648,7 @@ def calibrate_backend(
     For per-kernel anchoring inside the explorer's measurement loop use
     :func:`calibrate_execution`.
     """
+    interpret = resolve_interpret(interpret)
     gflops = measure_elementwise_gflops(
         interpret, chain=chain, shape=shape, reps=reps, warmup=warmup
     )
@@ -676,7 +682,7 @@ def calibrate_execution(
     words: int = 0,
     d_values: Sequence[int] = (1,),
     probe_plans: Sequence[tuple[int, int]] = PROBE_PLANS,
-    interpret: bool = True,
+    interpret: bool | None = None,
     reps: int = 3,
     warmup: int = 1,
     cache: MeasurementCache | None = None,
@@ -702,6 +708,7 @@ def calibrate_execution(
     and a probe plan that legalizes onto a frontier point's plan reuses
     its timing outright.
     """
+    interpret = resolve_interpret(interpret)
     h, w = grid_shape
     halo = workload.halo if halo is None else halo
     backend = backend_descriptor()
@@ -713,7 +720,7 @@ def calibrate_execution(
             try:
                 bh, m, db = blocking_plan(
                     h, req_bh, req_m, halo=halo, width=width, words=words,
-                    d=d,
+                    d=d, interpret=interpret,
                 )
             except ValueError:
                 continue  # this anchor has no legal plan here (e.g. a

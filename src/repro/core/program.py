@@ -49,6 +49,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+
 from .codegen import CodegenError, StreamKernel, stencil_summary
 from .compiler import CompiledCore, Registry
 from .dfg import SPDError
@@ -438,7 +439,7 @@ class ProgramKernel:
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
-                    interpret: bool = True, d: int = 1, dx: int = 1):
+                    interpret: bool | None = None, d: int = 1, dx: int = 1):
         """Advance ``steps`` program steps under this partition.
 
         Fused (one cluster): the standard ``m``-blocked launch chain.
@@ -465,7 +466,7 @@ class ProgramKernel:
             )
         return self._pipelined(
             state, scals, steps=int(steps), block_h=int(block_h),
-            double_buffer=bool(double_buffer), interpret=bool(interpret),
+            double_buffer=bool(double_buffer), interpret=interpret,
         )
 
     def _run_sharded(self, state, regs, *, steps, m, block_h,
@@ -491,7 +492,7 @@ class ProgramKernel:
 
     def run_unfused(self, state, regs: Sequence = (), *, steps: int,
                     block_h: int, double_buffer: bool = True,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
         """The no-pipelining baseline: one host dispatch per cluster per
         step, with every intermediate field synced through the host —
         what a program executed as unrelated single-core runs costs
@@ -510,7 +511,8 @@ class ProgramKernel:
         return state
 
     def run_for_point(self, state, regs: Sequence = (), *, point,
-                      steps: int | None = None, interpret: bool = True):
+                      steps: int | None = None,
+                      interpret: bool | None = None):
         """Advance the grid using a DSE design point, legalized for the
         whole partition via
         :func:`repro.core.legalize.program_blocking_plan` (every
@@ -521,6 +523,7 @@ class ProgramKernel:
         block_h, m, nsteps, double_buffer = resolve_run_plan(
             h, point, steps, width=w,
             stages=self.program.stage_geometry(), fusion=self.fusion,
+            interpret=interpret,
         )
         out = self.run_blocked(
             state, regs, steps=nsteps, m=m, block_h=block_h,
@@ -542,7 +545,7 @@ class ProgramKernel:
 
 
 def program_run_factory(program: StreamProgram, state, regs,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """Adapt a program + initial state into the search runner's
     ``run_factory(nsteps, m, block_h, d, double_buffer, b, fusion,
     dx)`` protocol (docs/pipeline.md §search): the fusion partition

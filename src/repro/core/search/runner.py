@@ -45,6 +45,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Sequence
 
+from repro.compat import resolve_interpret
+
 from ..dse import DesignPoint, StreamWorkload
 from ..legalize import PLAN_FIELDS, RunPlan, resolve_run_plan
 
@@ -195,7 +197,8 @@ class ExecutedPoint:
         }
 
 
-def kernel_run_factory(kern, state, regs: Sequence, interpret: bool):
+def kernel_run_factory(kern, state, regs: Sequence,
+                       interpret: bool | None):
     """The default back end: a codegen'd StreamKernel, sharded for d>1.
 
     Returns the ``run_factory(nsteps, m, block_h, d, double_buffer, b,
@@ -260,7 +263,7 @@ class SearchRunner:
         words: int | None = None,
         stages: tuple | None = None,
         steps: int | None = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
         reps: int = 3,
         warmup: int = 1,
         calibrate: bool = True,
@@ -288,7 +291,7 @@ class SearchRunner:
         # (legalize.program_blocking_plan) at each point's fusion spec.
         self.stages = None if stages is None else tuple(stages)
         self.steps = steps
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         self.reps = int(reps)
         self.warmup = int(warmup)
         self.calibrate = bool(calibrate)
@@ -338,6 +341,9 @@ class SearchRunner:
         self.last_blocked = None  # the candidate BudgetExhausted cut off
         self.prefetched = 0  # warm-ups dispatched (observability)
         self._prefetch = None  # (plan.key(), Thread) of an in-flight warm-up
+        # plan.key() -> exception a warm-up raised; re-raised by
+        # measure() when that plan is timed, never swallowed.
+        self._prefetch_errors: dict[tuple, Exception] = {}
 
     # ---- model-side helpers ------------------------------------------------
 
@@ -386,7 +392,7 @@ class SearchRunner:
                 self.h, point, self.steps, halo=self.halo,
                 width=self.width, words=self.words, d=d, b=b,
                 stages=self.stages, fusion=fusion,
-                dx=dx, halo_x=self.halo_x,
+                dx=dx, halo_x=self.halo_x, interpret=self.interpret,
             )
         except ValueError:
             return None
@@ -502,7 +508,7 @@ class SearchRunner:
                 self.h, point, self.steps, halo=self.halo,
                 width=self.width, words=self.words, d=d, b=b,
                 stages=self.stages, fusion=fusion,
-                dx=dx, halo_x=self.halo_x,
+                dx=dx, halo_x=self.halo_x, interpret=self.interpret,
             )
         except ValueError:
             self.skipped_illegal += 1
@@ -536,6 +542,11 @@ class SearchRunner:
                 # Timed reps never overlap a background warm-up: wait
                 # out any in-flight prefetch before the clock starts.
                 self._join_prefetch()
+                err = self._prefetch_errors.pop(plan.key(), None)
+                if err is not None:
+                    raise RuntimeError(
+                        f"warm-up of plan {plan.as_dict()} failed"
+                    ) from err
                 wall, record = self._time(plan, run)
                 self.budget_spent += 1
                 self._counts[plan.key()] = self._counts.get(plan.key(), 0) + 1
@@ -635,15 +646,19 @@ class SearchRunner:
             return False
         import threading
 
+        key = plan.key()
+
         def warm():
+            # A failing warm-up must not kill the search from a background
+            # thread; it is recorded and surfaces when the plan is measured.
             try:
                 run()
-            except Exception:
-                pass  # a failing warm-up must never kill the search
+            except Exception as exc:
+                self._prefetch_errors[key] = exc
 
         thread = threading.Thread(target=warm, daemon=True)
         thread.start()
-        self._prefetch = (plan.key(), thread)
+        self._prefetch = (key, thread)
         self.prefetched += 1
         return True
 

@@ -213,6 +213,7 @@ class LocalRefine:
             runner.h, m, halo=runner.halo, width=runner.width,
             words=runner.words, d=d, double_buffer=db,
             dx=dx, halo_x=runner.halo_x if dx > 1 else 0,
+            interpret=runner.interpret,
         )
         below = [v for v in chain if v < bh]
         above = [v for v in chain if v > bh]

@@ -129,6 +129,7 @@ class TPESearch:
                     runner.h, bh, m, halo=runner.halo, width=runner.width,
                     words=runner.words, d=d, double_buffer=req_db, b=b,
                     dx=dxv, halo_x=runner.halo_x,
+                    interpret=runner.interpret,
                 )
                 out.append(_Candidate(
                     point=pt, coords=coords,

@@ -10,7 +10,7 @@ from .ref import attention_chunked_ref, attention_ref
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               scale: float | None = None, use_pallas: bool | None = None,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Dispatch attention to the Pallas kernel or the jnp reference.
 
     ``use_pallas=None`` auto-selects: the kernel on TPU backends, the

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.apps.lbm import EX, EY, OPP, W as LATTICE_W
+from repro.compat import resolve_interpret
 
 
 def _shift_x(a, dx: int):
@@ -117,7 +118,7 @@ def _kernel(scal_ref, fc_ref, fu_ref, fd_ref, ac_ref, au_ref, ad_ref,
     jax.jit, static_argnames=("m", "block_h", "interpret")
 )
 def lbm_multistep(f, attr, one_tau, u_lid=0.0, *, m: int = 4,
-                  block_h: int = 32, interpret: bool = True):
+                  block_h: int = 32, interpret: bool | None = None):
     """Fused m-step periodic LBM update.
 
     Args:
@@ -127,8 +128,8 @@ def lbm_multistep(f, attr, one_tau, u_lid=0.0, *, m: int = 4,
       u_lid: lid velocity for attr==2 cells.
       m: fused time steps per HBM round-trip (temporal parallelism).
       block_h: rows per grid program (spatial tile).
-      interpret: run in Pallas interpret mode (CPU validation); on real TPU
-        pass False.
+      interpret: run in Pallas interpret mode; ``None`` decides by backend
+        (``repro.compat.default_interpret``: CPU only).
     """
     _, h, w = f.shape
     if h % block_h:
@@ -155,5 +156,5 @@ def lbm_multistep(f, attr, one_tau, u_lid=0.0, *, m: int = 4,
         ],
         out_specs=pl.BlockSpec((9, block_h, w), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(f.shape, f.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scal, f, f, f, attr, attr, attr)

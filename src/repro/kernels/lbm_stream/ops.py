@@ -19,7 +19,7 @@ from .ref import lbm_multistep_ref
 
 
 def lbm_run_for_point(f, attr, one_tau, point, *, steps: int | None = None,
-                      u_lid=0.0, interpret: bool = True):
+                      u_lid=0.0, interpret: bool | None = None):
     """Advance the lattice using a DSE design point's (block_h, m).
 
     See :func:`resolve_run_plan` for how the point is legalized — with
@@ -41,7 +41,7 @@ def lbm_run_for_point(f, attr, one_tau, point, *, steps: int | None = None,
 
 @functools.partial(jax.jit, static_argnames=("steps", "m", "block_h", "interpret"))
 def lbm_run_blocked(f, attr, one_tau, u_lid=0.0, *, steps: int, m: int = 4,
-                    block_h: int = 32, interpret: bool = True):
+                    block_h: int = 32, interpret: bool | None = None):
     """Advance ``steps`` LBM time steps using m-fused kernel launches."""
     if steps % m:
         raise ValueError(f"steps={steps} must be a multiple of m={m}")

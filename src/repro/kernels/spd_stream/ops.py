@@ -20,7 +20,7 @@ from .spd_stream import spd_multistep
 
 
 def stream_run_blocked(multistep: Callable, state, scal, *, steps: int,
-                       m: int, block_h: int, interpret: bool = True):
+                       m: int, block_h: int, interpret: bool | None = None):
     """Advance ``steps`` time steps using m-fused kernel launches.
 
     ``multistep`` is a (typically jitted) closure over

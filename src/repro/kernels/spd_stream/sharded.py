@@ -40,11 +40,13 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import resolve_interpret
+
 from .spd_stream import _kernel, spd_multistep
 
 
 def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
-                       halo: int, interpret: bool = True):
+                       halo: int, interpret: bool | None = None):
     """Fused m-step launch over one halo-extended shard.
 
     Args:
@@ -56,7 +58,8 @@ def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
       scal: (R,) f32 ``Append_Reg`` scalars (SMEM).
       m / block_h / halo: as in ``spd_multistep``; ``halo == 0`` cores
         need no exchanged rows and take the plain launch.
-      interpret: run under the Pallas interpreter (CPU validation).
+      interpret: run under the Pallas interpreter; ``None`` decides by
+        backend (``repro.compat.default_interpret``: CPU only).
 
     Returns the advanced ``(P, local_h, W)`` shard (guard blocks dropped).
     """
@@ -96,5 +99,5 @@ def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
         ],
         out_specs=pl.BlockSpec((p, block_h, w), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, local_h, w), ext.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scal, ext, ext, ext)

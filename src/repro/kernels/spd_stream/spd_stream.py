@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import resolve_interpret
+
 
 def _kernel(scal_ref, fc_ref, fu_ref, fd_ref, out_ref, *,
             step_fn: Callable, m: int, block_h: int, mh: int):
@@ -63,7 +65,7 @@ def _kernel(scal_ref, fc_ref, fu_ref, fd_ref, out_ref, *,
 
 
 def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
-                  halo: int, interpret: bool = True):
+                  halo: int, interpret: bool | None = None):
     """Fused m-step launch of a codegen'd stripe function.
 
     Args:
@@ -80,8 +82,8 @@ def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
       block_h: rows per grid program (spatial tile).
       halo: per-step stencil reach in rows (inferred by the codegen);
         the stripe carries ``m*halo`` extra rows per side.
-      interpret: run under the Pallas interpreter (CPU validation); on
-        real TPU pass False.
+      interpret: run under the Pallas interpreter; ``None`` decides by
+        backend (``repro.compat.default_interpret``: CPU only).
     """
     *lead, h, w = state.shape
     if h % block_h:
@@ -113,5 +115,5 @@ def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
             (*lead, block_h, w), lambda i: zeros + (i, 0)
         ),
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scal, state, state, state)

@@ -3,9 +3,9 @@
 The BlockSpec launch in :mod:`.spd_stream` describes stripes
 *declaratively* and leaves the HBM↔VMEM movement to the Pallas grid
 pipeliner. This module is the explicit form (DESIGN.md §12,
-docs/pipeline.md §stream): the state stays in ``pltpu.ANY`` memory (HBM
+docs/pipeline.md §stream): the state stays in ``pl.ANY`` memory (HBM
 on real TPUs), a single kernel program walks the row blocks with
-``jax.lax.fori_loop``, and every ``(P, block_h + 2·m·halo, W)`` stripe
+``jax.lax.fori_loop``, and every ``(P, block_h + 2·mh, W)`` stripe
 is staged through VMEM scratch buffers by explicit async copies
 (``pltpu.make_async_copy`` + DMA semaphores) — ``emit_pipeline``-style
 manual pipelining, written out so the buffer protocol is inspectable
@@ -36,9 +36,22 @@ every stripe DMA moves all leading axes whole, and the VMEM scratch
 stacks scale by B exactly as the legalizer's
 ``stripe_vmem_bytes(..., b=B)`` prices them. The width axis is opaque
 the same way: under a column-sharded mesh (``dx > 1``, DESIGN.md §15)
-``W`` arrives guard-column-extended to ``W/dx + 2·m·halo_x`` and the
-legalizer prices the stripes at that width
+``W`` arrives guard-column-extended to ``W/dx + 2·guard_cols(m·halo_x)``
+and the legalizer prices the stripes at that width
 (``stripe_vmem_bytes(..., halo_x=)``); the walk itself is unchanged.
+Compiled for the TPU, the launch width must be a multiple of 128 lanes
+and ``block_h`` a multiple of 8 rows (a ``ValueError`` says so before
+the compiler does).
+
+Halo rows are carried in whole sublane tiles: ``mh`` is ``m·halo``
+rounded up to a multiple of 8 rows (capped at ``block_h``,
+:func:`repro.core.legalize.halo_rows`), so with an 8-row-aligned
+``block_h`` every DMA row offset and the output crop start on a tile
+boundary, as the TPU compiler requires. The extra rows only widen the
+trapezoid's stale margin; the centre block is computed from the same
+values, so results are unchanged. The launch runs under the one VMEM
+limit the legalizer prices against
+(:data:`repro.core.legalize.VMEM_BYTES`).
 """
 
 from __future__ import annotations
@@ -50,6 +63,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.compat import resolve_interpret
+from repro.core.legalize import (
+    LANES,
+    SUBLANE_ROWS,
+    VMEM_BYTES,
+    halo_rows,
+)
 
 
 def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
@@ -155,6 +176,13 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
 def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
                    out_h, src_starts, interpret):
     *lead, _, w = state.shape
+    interpret = resolve_interpret(interpret)
+    if not interpret and (w % LANES or block_h % SUBLANE_ROWS):
+        raise ValueError(
+            f"the TPU kernel stages whole ({SUBLANE_ROWS}, {LANES}) tiles: "
+            f"width {w} must be a multiple of {LANES} and block_h "
+            f"{block_h} a multiple of {SUBLANE_ROWS}"
+        )
     rows = block_h + 2 * mh
     return pl.pallas_call(
         functools.partial(
@@ -163,9 +191,9 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((*lead, out_h, w), state.dtype),
         scratch_shapes=[
             pltpu.VMEM((nbuf, *lead, rows, w), state.dtype),
@@ -173,6 +201,9 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
             pltpu.SemaphoreType.DMA((nbuf, 3 if mh else 1)),
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_BYTES
+        ),
         interpret=interpret,
     )(scal, state)
 
@@ -180,7 +211,7 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
 def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
                            block_h: int, halo: int,
                            double_buffer: bool = True,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Streamed fused m-step launch, periodic in y.
 
     Drop-in for :func:`repro.kernels.spd_stream.spd_multistep` — same
@@ -192,11 +223,11 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
     *_, h, _ = state.shape
     if h % block_h:
         raise ValueError(f"H={h} must be divisible by block_h={block_h}")
-    mh = m * halo
-    if mh > block_h:
+    if m * halo > block_h:
         raise ValueError(
-            f"m*halo={mh} must be <= block_h={block_h} (halo source)"
+            f"m*halo={m * halo} must be <= block_h={block_h} (halo source)"
         )
+    mh = halo_rows(m * halo, block_h)
     nblk = h // block_h
     nbuf = 2 if double_buffer else 1
 
@@ -216,7 +247,7 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
 def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
                                 block_h: int, halo: int,
                                 double_buffer: bool = True,
-                                interpret: bool = True):
+                                interpret: bool | None = None):
     """Streamed fused m-step launch over one halo-extended shard.
 
     The streamed twin of
@@ -226,8 +257,7 @@ def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
     block i+1, its halos come from ext blocks i / i+2 (docs/pipeline.md
     §stream).
     """
-    mh = m * halo
-    if mh == 0:
+    if m * halo == 0:
         return spd_multistep_streamed(
             step_fn, ext, scal, m=m, block_h=block_h, halo=0,
             double_buffer=double_buffer, interpret=interpret,
@@ -239,10 +269,11 @@ def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
             f"extended shard of {rows} rows is not local_h + 2*block_h "
             f"with block_h={block_h} dividing local_h"
         )
-    if mh > block_h:
+    if m * halo > block_h:
         raise ValueError(
-            f"m*halo={mh} must be <= block_h={block_h} (halo source)"
+            f"m*halo={m * halo} must be <= block_h={block_h} (halo source)"
         )
+    mh = halo_rows(m * halo, block_h)
     nblk = local_h // block_h
 
     def src_starts(i):

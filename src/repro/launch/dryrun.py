@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis, set_mesh
 from repro.configs import ARCHS, SHAPES, shape_applicable
 from repro.models import registry
 from repro.launch.mesh import dp_axes_for, make_production_mesh, mesh_axis_sizes
@@ -275,13 +274,13 @@ def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
         a2a=mesh if use_a2a else None,
         fsdp=fsdp_axes_ if use_a2a else None,
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
     coll = collective_stats(hlo)
     # trip-count-corrected costs (XLA cost_analysis counts loop bodies once;

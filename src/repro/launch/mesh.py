@@ -10,10 +10,17 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; multi_pod adds a 2-pod leading axis."""
+    """16x16 = 256 chips per pod; multi_pod adds a 2-pod leading axis.
+
+    The axes are ``Auto``: the LM tier shards through GSPMD constraints
+    (``repro.parallel.hints``), not explicit-sharding types, and
+    ``jax.make_mesh`` defaults to ``Explicit`` axes on the installed JAX.
+    """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_axis_sizes(mesh) -> dict:

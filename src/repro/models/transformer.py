@@ -172,6 +172,25 @@ def _scan_layers(stacked, x, cfg, positions, *, causal: bool, moe: bool):
     return x
 
 
+def _require_untyped_sharding(**arrays):
+    """The LM tier shards through GSPMD constraints (Auto mesh axes, or
+    ``jit(in_shardings=...)``). Arrays whose *types* carry a sharding —
+    placed with ``device_put`` on a mesh of Explicit axes,
+    ``jax.make_mesh``'s default — leave its gathers and head-split
+    reshapes without an inferable sharding, so fail here with the remedy
+    instead of deep inside a layer."""
+    for name, a in arrays.items():
+        spec = getattr(jax.typeof(a).sharding, "spec", ())
+        if any(s is not None for s in spec):
+            raise ValueError(
+                f"the LM tier needs arrays without explicit sharding "
+                f"types, got {name}: {jax.typeof(a)}; place them on a "
+                f"mesh with AxisType.Auto axes "
+                f"(repro.launch.mesh.make_production_mesh, or "
+                f"jax.make_mesh(..., axis_types=(AxisType.Auto,) * n))"
+            )
+
+
 def embed_tokens(params, cfg, tokens, embeds=None):
     """Token embedding with optional frontend (VLM patches / audio frames)
     prepended. embeds: (B, T_front, d_model)."""
@@ -183,6 +202,7 @@ def embed_tokens(params, cfg, tokens, embeds=None):
 
 def forward(params, cfg, tokens, embeds=None, positions=None):
     """-> logits (B, S_total, vocab). Decoder-only path."""
+    _require_untyped_sharding(embed=params["embed"], tokens=tokens)
     x = embed_tokens(params, cfg, tokens, embeds)
     b, s, _ = x.shape
     if positions is None:
@@ -200,6 +220,7 @@ def forward(params, cfg, tokens, embeds=None, positions=None):
 
 def encode(params, cfg, frames):
     """Encoder stack over stubbed frame embeddings (B, T, d) -> states."""
+    _require_untyped_sharding(frames=frames)
     x = frames.astype(cfg.param_dtype)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
     x = _scan_layers(params["enc_layers"], x, cfg, positions,

@@ -23,7 +23,6 @@ from functools import partial
 
 import jax
 
-from repro.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -100,7 +99,7 @@ def moe_ep_apply(xt, idx, gates, w_gate, w_up, w_down, *, mesh, dp_axes,
         ).sum(1)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

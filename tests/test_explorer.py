@@ -331,9 +331,13 @@ def test_blocking_plan_legalizes():
     assert blocking_plan(64, 64, 4) == (64, 4, True)
     assert blocking_plan(64, 256, 4) == (64, 4, True)  # clamp to grid
     assert blocking_plan(64, 24, 4) == (16, 4, True)  # nearest divisor below
-    assert blocking_plan(48, 8, 12) == (12, 12, True)  # m forces block up
-    bh, m, _ = blocking_plan(30, 7, 4)
-    assert 30 % bh == 0 and m <= bh
+    # m forces the block up to the next tile-aligned divisor
+    assert blocking_plan(48, 8, 12) == (16, 12, True)
+    bh, m, _ = blocking_plan(40, 7, 4)
+    assert 40 % bh == 0 and bh % 8 == 0 and m <= bh
+    # no 8-row-aligned block divides 30 rows: an error, not a guess
+    with pytest.raises(ValueError, match="8-row tiles"):
+        blocking_plan(30, 7, 4)
 
 
 # ----------------------- execution loop (interpret mode) -----------------------
@@ -367,7 +371,8 @@ def test_run_factory_path_gets_vmem_stripe_check(explorer):
     from repro.core.legalize import VMEM_BYTES
 
     assert stripe_vmem_bytes(
-        r.block_h, r.m, w, sweep.workload.words_in, sweep.workload.halo
+        r.block_h, r.m, w, sweep.workload.words_in, sweep.workload.halo,
+        double_buffer=r.double_buffer,
     ) <= VMEM_BYTES
     want = resolve_run_plan(
         h, r.point, None, halo=sweep.workload.halo, width=w,

@@ -55,7 +55,6 @@ def test_collectives_counted_inside_loops():
     if jax.device_count() < 1:
         return
 
-    from repro.compat import shard_map
 
     def f(x):
         def body(c, _):
@@ -64,7 +63,7 @@ def test_collectives_counted_inside_loops():
         return y
 
     mesh = jax.make_mesh((1,), ("i",))
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P()))
     hlo = g.lower(jax.ShapeDtypeStruct((64,), jnp.float32)).compile().as_text()
     hc = analyze_hlo(hlo)
     # 7 iterations x 64 floats x 4B (device_count=1 may elide the op; accept

@@ -306,8 +306,10 @@ def test_calibration_falls_back_when_probe_anchors_are_infeasible():
     from repro.core.dse import StreamWorkload
     from repro.core.explorer import Explorer
 
+    # halo 3: the probes' fused steps need 16- and 32-row blocks, whose
+    # stripes overflow VMEM at this width; the (8, 1) stripe fits.
     w = StreamWorkload(
-        "wide", 4, 10, 10, 10, 1000, 256 * 100_000, grid_w=100_000
+        "wide", 4, 10, 10, 10, 1000, 256 * 20_000, grid_w=20_000, halo=3
     )
     ex = Explorer(w)
     sweep = ex.sweep_tpu(bh_values=(8,), m_values=(1,), d_values=(1,))
@@ -316,7 +318,7 @@ def test_calibration_falls_back_when_probe_anchors_are_infeasible():
         return lambda: None
 
     runs = ex.execute_frontier(
-        sweep, run_factory=rf, grid_shape=(256, 100_000), k=1, reps=1,
+        sweep, run_factory=rf, grid_shape=(256, 20_000), k=1, reps=1,
         calibrate=True,
     )
     assert len(runs) == 1

@@ -196,6 +196,40 @@ def test_model_and_legalizer_agree_on_shard_geometry():
             ) <= VMEM_BYTES
 
 
+def test_model_prices_the_sharded_launch_geometry():
+    """The model's recompute geometry under a (2, 2) mesh is the one the
+    shard launches allocate: block_h × shard width useful sites out of
+    the stripe buffer's rows × guard-extended columns."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices "
+                    "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+    import jax.numpy as jnp
+
+    kern = dif.DiffusionSimulation(64, 256).kernel
+    sk = kern.sharded(4, dx=2)
+    w = StreamWorkload("t", 7, 1, 1, 100, 1000, 64 * 256, grid_w=256,
+                       halo=kern.halo)
+
+    def calls(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from calls(inner)
+
+    state = jnp.zeros((1, 64, 256), jnp.float32)
+    for bh, m in ((8, 1), (16, 2), (32, 3)):
+        fn = sk._fn(m, m, bh, True, False, True)
+        (eqn,) = calls(jax.make_jaxpr(fn)(state, kern._scal((0.2,))).jaxpr)
+        rows, cols = [v.aval for v in eqn.params["jaxpr"].invars
+                      if "vmem" in str(v.aval)][0].shape[-2:]
+        pt = TPUModel().evaluate(w, bh, m, d=4, dx=2)
+        assert pt.detail["halo_useful_fraction"] == pytest.approx(
+            bh * 128 / (rows * cols), rel=1e-12)
+
+
 def test_model_marks_bad_meshes_infeasible():
     model = TPUModel()
     w = StreamWorkload("t", 7, 1, 1, 100, 1000, 64 * 70, grid_w=70)
@@ -240,15 +274,17 @@ def test_sweep_tpu_enumerates_the_mesh_axis():
 def test_wide_grid_prefers_columns_tall_prefers_rows():
     """The mesh axis earns its place in the search: at a fixed device
     count the model matches the mesh to the grid's aspect — a wide grid
-    picks a column-heavy mesh, a tall grid the row ring (mirrored)."""
+    picks a column-heavy mesh, a tall grid the row ring (mirrored). The
+    grids are wide enough that a shard's guard columns (whole half-lane
+    tiles) stay small next to it."""
     model = TPUModel()
-    wide = StreamWorkload("w", 7, 1, 1, 100, 1000, 128 * 512, grid_w=512)
-    tall = StreamWorkload("t", 7, 1, 1, 100, 1000, 512 * 128, grid_w=128)
+    wide = StreamWorkload("w", 7, 1, 1, 100, 1000, 128 * 4096, grid_w=4096)
+    tall = StreamWorkload("t", 7, 1, 1, 100, 1000, 4096 * 128, grid_w=128)
 
     def best_dx(w):
         return max(
             (1, 2, 4, 8),
-            key=lambda dx: model.evaluate(w, 16, 2, d=8, dx=dx)
+            key=lambda dx: model.evaluate(w, 64, 8, d=8, dx=dx)
             .sustained_gflops,
         )
 
@@ -334,6 +370,29 @@ def test_prefetch_warms_candidate_and_never_overlaps_timing():
     assert runner.measure(nxt) is not None
     assert done.is_set()
     assert runner._prefetch is None
+
+
+def test_prefetch_warm_up_error_surfaces_on_measure():
+    """A warm-up that raises (on a chip: a compile the compiler refuses)
+    is recorded, and measuring that plan raises it instead of timing."""
+
+    def rf(nsteps, m, block_h, d, double_buffer=True, b=1, dx=1):
+        def run():
+            if block_h == 16:
+                raise ValueError("compiler refused block_h=16")
+        return run
+
+    runner = SearchRunner(
+        workload=TOY, grid_shape=(64, 64), run_factory=rf,
+        model=TPUModel(), fingerprint="mesh-prefetch-error",
+        calibrate=False, cache=False, timer=lambda *a: 1e-3, max_devices=4,
+    )
+    bad = runner.point(16, 1)
+    assert runner.prefetch(bad) is True
+    with pytest.raises(RuntimeError, match="warm-up") as info:
+        runner.measure(bad)
+    assert isinstance(info.value.__cause__, ValueError)
+    assert runner.budget_spent == 0
 
 
 def test_prefetch_gates_on_idle_devices():

@@ -35,7 +35,6 @@ def test_pjit_sharded_train_step_matches_single_device():
     run_in_subprocess("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import set_mesh
         from repro.configs import get_arch
         from repro.configs.base import ShapeConfig
         from repro.models import registry
@@ -56,12 +55,14 @@ def test_pjit_sharded_train_step_matches_single_device():
         # single device
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-        # sharded: mesh (data=2, model=4)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        # sharded: mesh (data=2, model=4), Auto axes as the model's
+        # GSPMD sharding expects (make_mesh defaults to Explicit)
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pspecs = build_param_specs(
             jax.eval_shape(bundle.init, jax.random.PRNGKey(0)),
             model_axis_size=4)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             sh = lambda spec: NamedSharding(mesh, spec)
             params_s = jax.tree.map(
                 lambda x, s: jax.device_put(x, sh(s)), params, pspecs)
@@ -77,6 +78,48 @@ def test_pjit_sharded_train_step_matches_single_device():
                                    rtol=2e-3, atol=2e-4)
         print('pjit OK')
     """)
+
+
+def test_explicit_mesh_is_refused_with_a_clear_error():
+    """The LM tier shards through GSPMD constraints. Arrays placed on
+    ``jax.make_mesh``'s default Explicit axes carry their sharding in
+    their types; the forward pass refuses them up front, naming the
+    remedy, rather than failing inside a gather or reshape. The same
+    arrays on Auto axes, and unplaced arrays under an Explicit mesh (the
+    dry-run's ``jit(in_shardings=...)`` path), run."""
+    import dataclasses
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_arch
+    from repro.configs.base import ShapeConfig
+    from repro.models import registry
+    from repro.models.transformer import forward
+
+    cfg = dataclasses.replace(get_arch('qwen3-8b').reduced(),
+                              n_layers=1, d_model=32, vocab=64,
+                              n_heads=2, n_kv_heads=1, head_dim=16)
+    bundle = registry.build(cfg)
+    params = bundle.init(jax.random.PRNGKey(0))
+    tokens = registry.make_batch(cfg, ShapeConfig('t', 8, 2, 'train'))[
+        "tokens"]
+    fwd = jax.jit(lambda p, t: forward(p, cfg, t))
+
+    def placed(mesh):
+        put = lambda x, s: jax.device_put(x, NamedSharding(mesh, s))
+        return ({**params, "embed": put(params["embed"], P(None, 'model'))},
+                put(tokens, P('data', None)))
+
+    explicit = jax.make_mesh((1, 1), ('data', 'model'))
+    auto = jax.make_mesh((1, 1), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    with jax.set_mesh(explicit):
+        with pytest.raises(ValueError, match="AxisType.Auto"):
+            fwd(*placed(explicit))
+        assert fwd(params, tokens).shape == (2, 8, 64)
+    with jax.set_mesh(auto):
+        assert fwd(*placed(auto)).shape == (2, 8, 64)
 
 
 def test_pipeline_parallel_matches_sequential():
@@ -118,7 +161,6 @@ def test_compressed_psum_across_devices():
     run_in_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.parallel.compression import (CompressionConfig,
             compressed_psum, init_residuals)
 
@@ -131,7 +173,7 @@ def test_compressed_psum_across_devices():
             return compressed_psum(gs, rs, 'data',
                                    CompressionConfig('int8_ef'))
 
-        f = jax.jit(shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                     in_specs=(P('data', None), P('data', None)),
                     out_specs=(P(None), P('data', None))))
         # shard_map splits axis0; each worker sees (1, 64)
@@ -142,7 +184,7 @@ def test_compressed_psum_across_devices():
         # error feedback residual = local grad - local dequantized
         assert float(np.abs(np.asarray(new_r['w'])).max()) < 2e-3
         # exact scheme is exact
-        f0 = jax.jit(shard_map(
+        f0 = jax.jit(jax.shard_map(
             lambda gs, rs: compressed_psum(gs, rs, 'data',
                                            CompressionConfig('none')),
             mesh=mesh, in_specs=(P('data', None), P('data', None)),
@@ -160,7 +202,6 @@ def test_dryrun_machinery_small_mesh():
     run_in_subprocess("""
         import dataclasses, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import set_mesh
         from repro.configs import get_arch
         from repro.configs.base import ShapeConfig
         from repro.models import registry
@@ -189,12 +230,11 @@ def test_dryrun_machinery_small_mesh():
             jax.tree.map(sh, ospecs),
             {k: sh(P('data', None)) for k in batch},
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=in_sh).lower(
                 params_shape, opt_shape, batch)
             compiled = lowered.compile()
-        from repro.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         ma = compiled.memory_analysis()
         assert ca.get('flops', 0) > 0
         txt = compiled.as_text()

@@ -265,11 +265,11 @@ def test_legal_partitions_fit_vmem():
 
 
 def test_unsourceable_composed_halo_names_cluster():
-    # Fusing two halo-3 stages composes halo 6 > the 4-row shard.
+    # Fusing two halo-5 stages composes halo 10 > the 8-row shard.
     with pytest.raises(ValueError,
                        match=r"fusion cluster 0 of spec '2'.*composed "
-                             r"stencil halo 6"):
-        program_blocking_plan(4, 4, 1, stages=((1, 3), (1, 3)),
+                             r"stencil halo 10"):
+        program_blocking_plan(8, 8, 1, stages=((1, 5), (1, 5)),
                               fusion="2", width=W)
 
 

@@ -31,11 +31,15 @@ import jax
 from _hypothesis_stub import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
 from repro.apps import diffusion as dif
 from repro.apps import lbm
-from repro.core.dse import StreamWorkload, TPUModel
+from repro.core.dse import StreamWorkload, TPUModel, TPUTarget
 from repro.core.legalize import (
+    LANES,
     VMEM_BYTES,
     blocking_plan,
+    constraint_violation,
+    halo_rows,
     legal_block_values,
+    program_blocking_plan,
     resolve_run_plan,
     stripe_vmem_bytes,
 )
@@ -146,11 +150,14 @@ def test_single_block_grid_streams(dif_sim):
 def test_blocking_plan_falls_back_to_single_buffer():
     """A minimal stripe that overflows double-buffered but fits
     single-buffered legalizes onto the fallback instead of raising."""
-    # smallest stripe (bh=2, m=2, halo=1): 6 rows × 64 × 1 word × 4 B
-    #   = 1536 B single-buffered, 3072 B ping/pong.
-    bh, m, db = blocking_plan(16, 8, 2, width=64, words=1, vmem_bytes=2000)
+    # smallest launch (bh=8, m=2, halo carried as one 8-row tile):
+    #   24-row stripe + 8-row output block per slot + 5 stripe-sized
+    #   temporaries, × 64 × 4 B = 38912 B single-buffered, 47104 B
+    #   ping/pong.
+    bh, m, db = blocking_plan(16, 8, 2, width=64, words=1,
+                              vmem_bytes=40000)
     assert db is False
-    assert stripe_vmem_bytes(bh, m, 64, 1, 1, False) <= 2000
+    assert stripe_vmem_bytes(bh, m, 64, 1, 1, False) <= 40000
     # With the room, the requested ping/pong protocol is honored.
     assert blocking_plan(16, 8, 2, width=64, words=1,
                          vmem_bytes=10**9) == (8, 2, True)
@@ -179,7 +186,7 @@ def test_vmem_overflow_grid_executes_via_streaming(dif_sim):
     pt = TPUModel().evaluate(
         dif_sim.explorer().workload, bh=8, m=2, double_buffer=True
     )
-    budget = 2000  # fits (2, 2) single-buffered only (1536 B vs 3072 B)
+    budget = 40000  # fits (8, 2) single-buffered only (38912 vs 47104 B)
     with pytest.raises(ValueError, match="fallback"):
         # sanity: with the fallback forbidden this budget is hopeless
         blocking_plan(16, 8, 2, width=64, words=1, vmem_bytes=budget // 2)
@@ -229,14 +236,98 @@ def test_model_vmem_accounting_is_the_legalizers(double_buffer):
             assert int(batch["vmem_bytes"][0]) == pt.detail["vmem_bytes"]
 
 
+def _kernel_stripe_rows(kern, h, w, block_h, m):
+    """Rows of the input stripe buffer the streamed launch allocates,
+    read from the traced ``pallas_call`` (its first VMEM scratch ref)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    state = jnp.zeros((len(kern._ports), h, w), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        kern._streamed, m=m, block_h=block_h, double_buffer=True,
+        interpret=True,
+    ))(state, kern._scal((0.0,) * len(kern._regs)))
+
+    def calls(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from calls(inner)
+
+    (eqn,) = calls(jaxpr.jaxpr)
+    vmem = [v.aval for v in eqn.params["jaxpr"].invars
+            if "vmem" in str(v.aval)]
+    return vmem[0].shape[-2]
+
+
+@pytest.mark.parametrize("app", ["diffusion", "lbm"])
+def test_model_compute_geometry_is_the_kernels(app, dif_sim):
+    """The model prices the stripe the kernel runs: its useful fraction
+    is block_h over the rows of the launch's own stripe buffer (halo
+    carried in whole 8-row tiles), scalar and batched alike."""
+    if app == "diffusion":
+        kern, words = dif_sim.kernel, 1
+    else:
+        kern, words = lbm.LBMSimulation(lbm.LBMProblem(64, 64)) \
+            .stream_kernel(), 10
+    h, w = 64, 64
+    wl = StreamWorkload("t", 7, words, words, 100, 1000, h * w, grid_w=w,
+                        halo=kern.halo)
+    model = TPUModel()
+    for bh, m in ((8, 1), (16, 2), (32, 3), (16, 8), (64, 8)):
+        rows = _kernel_stripe_rows(kern, h, w, bh, m)
+        pt = model.evaluate(wl, bh, m)
+        assert pt.detail["halo_useful_fraction"] == bh / rows
+        batch = model.evaluate_batch(wl, [bh], [m])
+        assert float(batch["halo_useful_fraction"][0]) == bh / rows
+        assert float(batch["t_memory_s"][0]) == pt.detail["t_memory_s"]
+
+
+def test_lane_rule_is_a_legalizer_rule():
+    """Compiled for the TPU the launch stages whole 128-lane rows: a
+    shard width that is not a multiple of them has no plan, and the
+    legalizer, the violation distance and the model all say so. Under
+    the interpreter any width runs."""
+    with pytest.raises(ValueError, match="128 lanes"):
+        blocking_plan(64, 32, 2, width=96, words=1, interpret=False)
+    assert legal_block_values(64, 2, width=96, words=1,
+                              interpret=False) == ()
+    assert constraint_violation(64, 32, 2, width=96, words=1,
+                                interpret=False) > 0
+    with pytest.raises(ValueError, match="128 lanes"):
+        program_blocking_plan(64, 32, 2, stages=[(1, 1), (1, 1)],
+                              width=96, interpret=False)
+    # dx = 2: 256 columns give 128-column shards; 128 give 64
+    assert blocking_plan(64, 32, 2, width=256, words=1, d=2, dx=2,
+                         halo_x=1, interpret=False)
+    with pytest.raises(ValueError, match="over dx=2"):
+        blocking_plan(64, 32, 2, width=128, words=1, d=2, dx=2, halo_x=1,
+                      interpret=False)
+    assert constraint_violation(64, 32, 2, width=128, words=1, d=2, dx=2,
+                                halo_x=1, interpret=False) > 0
+    # interpreted, the same requests legalize
+    assert blocking_plan(64, 32, 2, width=96, words=1, interpret=True)
+    assert constraint_violation(64, 32, 2, width=96, words=1,
+                                interpret=True) == 0.0
+    wl = StreamWorkload("t", 7, 1, 1, 100, 1000, 64 * 96, grid_w=96)
+    for lanes, ok in ((LANES, False), (1, True)):
+        model = TPUModel(TPUTarget(lanes=lanes))
+        assert model.evaluate(wl, 32, 2).feasible is ok
+        assert bool(model.evaluate_batch(wl, [32], [2])["feasible"][0]) is ok
+
+
 def test_single_buffer_halves_the_budget_and_widens_feasibility():
     """The fallback exists to buy headroom: a stripe priced infeasible
-    ping/pong can be feasible single-buffered, at exactly half."""
+    ping/pong can be feasible single-buffered (one buffer slot less)."""
     w = StreamWorkload("t", 7, 8, 8, 100, 1000, 4096 * 1440,
                        grid_w=1440, halo=1)
     model = TPUModel()
     over = next(
-        bh for bh in (512, 1024, 2048, 4096)
+        bh for bh in range(8, 4097, 8)
         if stripe_vmem_bytes(bh, 4, 1440, 8, 1, True) > VMEM_BYTES
         and stripe_vmem_bytes(bh, 4, 1440, 8, 1, False) <= VMEM_BYTES
     )
@@ -255,18 +346,23 @@ def test_single_buffer_halves_the_budget_and_widens_feasibility():
 )
 @settings(max_examples=40, deadline=None)
 def test_prop_double_buffer_costs_exactly_double(block_h, m, words, width):
-    """Any legal double-buffered plan needs exactly twice the VMEM of
-    its single-buffered twin — the invariant the fallback banks on."""
+    """Any legal double-buffered plan costs its single-buffered twin
+    plus exactly one more buffer slot (input stripe + output block);
+    the stripe body's temporaries are not doubled — the saving the
+    fallback banks on."""
     try:
         bh, mm, db = blocking_plan(16, block_h, m, width=width, words=words)
     except ValueError:
         return
+    rows = bh + 2 * halo_rows(mm, bh)
+    slot = words * (rows + bh) * width * 4
     assert stripe_vmem_bytes(bh, mm, width, words, 1, True) == (
-        2 * stripe_vmem_bytes(bh, mm, width, words, 1, False)
+        stripe_vmem_bytes(bh, mm, width, words, 1, False) + slot
     )
     if db:
-        # the honored ping/pong plan fits; its fallback twin fits in half
-        assert stripe_vmem_bytes(bh, mm, width, words, 1, False) * 2 \
+        # the honored ping/pong plan fits; its fallback twin fits with
+        # one slot to spare
+        assert stripe_vmem_bytes(bh, mm, width, words, 1, False) + slot \
             <= VMEM_BYTES
 
 
