@@ -48,6 +48,7 @@ feed inputs across fused steps, the same chaining contract as
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Mapping, Sequence
 
@@ -55,9 +56,10 @@ import jax
 import jax.numpy as jnp
 
 
+from . import tracing
 from .compiler import CompiledCore, eval_expr
 from .dfg import SPDError
-from .legalize import resolve_run_plan
+from .legalize import launch_dma_bytes, resolve_run_plan
 from .library import LibraryModule
 
 #: 1-D stream-state modules with no 2-D stripe lowering.
@@ -425,21 +427,50 @@ class StreamKernel:
         self._ports = core.main_input_ports()
         self._regs = list(core.regs)
         self._params = dict(core.params)
+        #: The kernel's name in the compiled program and device trace.
+        self.name = tracing.kernel_name(core.name)
+        from repro.kernels.spd_stream.ops import stream_run_blocked
         from repro.kernels.spd_stream.spd_stream import spd_multistep
         from repro.kernels.spd_stream.streaming import spd_multistep_streamed
+
+        # The jitted entries are named functions, not partials, so their
+        # XLA modules read jit_spd_… in the compiled program and trace.
+        def spd_multistep_blockspec(state, scal, *, m, block_h,
+                                    interpret=None):
+            with jax.named_scope(tracing.LAUNCH):
+                return spd_multistep(
+                    self._step_fn, state, scal, m=m, block_h=block_h,
+                    halo=self.halo, interpret=interpret, name=self.name,
+                )
+
+        def spd_launch(state, scal, *, m, block_h, double_buffer=True,
+                       interpret=None):
+            with jax.named_scope(tracing.LAUNCH):
+                return spd_multistep_streamed(
+                    self._step_fn, state, scal, m=m, block_h=block_h,
+                    halo=self.halo, double_buffer=double_buffer,
+                    interpret=interpret, name=self.name,
+                )
+
+        def spd_run_blocked(state, scal, *, steps, m, block_h,
+                            double_buffer, interpret):
+            return stream_run_blocked(
+                functools.partial(self._streamed,
+                                  double_buffer=double_buffer),
+                state, scal, steps=steps, m=m, block_h=block_h,
+                interpret=interpret,
+            )
 
         # Declarative BlockSpec launch: the reference pipeline (tests
         # compare the streamed path against it bit for bit).
         self._multistep = jax.jit(
-            functools.partial(spd_multistep, self._step_fn, halo=self.halo),
+            spd_multistep_blockspec,
             static_argnames=("m", "block_h", "interpret"),
         )
         # Manually pipelined launch (docs/pipeline.md §stream): the
         # execution path, with double_buffer a real plan knob.
         self._streamed = jax.jit(
-            functools.partial(
-                spd_multistep_streamed, self._step_fn, halo=self.halo
-            ),
+            spd_launch,
             static_argnames=("m", "block_h", "double_buffer", "interpret"),
         )
         self._sharded: dict[tuple[int, int], object] = {}
@@ -449,7 +480,7 @@ class StreamKernel:
         # which is also what makes fused vs. pipelined program walls in
         # benchmarks/dse_sweep.py §2h an apples-to-apples comparison).
         self._run_blocked = jax.jit(
-            self._run_blocked_impl,
+            spd_run_blocked,
             static_argnames=("steps", "m", "block_h", "double_buffer",
                              "interpret"),
         )
@@ -504,6 +535,14 @@ class StreamKernel:
         vals = list(regs) if regs else [0.0]
         return jnp.asarray(vals, jnp.float32)
 
+    def launch_dma_bytes(self, state, *, m: int, block_h: int) -> int:
+        """Bytes one streamed fused launch over ``state`` moves by DMA
+        (:func:`repro.core.legalize.launch_dma_bytes`)."""
+        *lead, h, w = state.shape
+        return launch_dma_bytes(h, w, math.prod(lead), block_h=block_h,
+                                m=m, halo=self.halo,
+                                itemsize=state.dtype.itemsize)
+
     def __call__(self, state, regs: Sequence = (), *, m: int = 1,
                  block_h: int = 32, double_buffer: bool = True,
                  interpret: bool | None = None):
@@ -513,30 +552,31 @@ class StreamKernel:
         (ping/pong vs single-buffer, docs/pipeline.md §stream); both are
         bitwise identical to the declarative BlockSpec launch.
         """
-        return self._streamed(
-            state, self._scal(regs), m=m, block_h=block_h,
-            double_buffer=double_buffer, interpret=interpret,
-        )
+        with jax.profiler.TraceAnnotation(tracing.RUN):
+            out = self._streamed(
+                state, self._scal(regs), m=m, block_h=block_h,
+                double_buffer=double_buffer, interpret=interpret,
+            )
+            tracing.count(launches=1, steps=m,
+                          dma_bytes=self.launch_dma_bytes(
+                              state, m=m, block_h=block_h))
+        return out
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
                     interpret: bool | None = None):
         """Advance ``steps`` time steps using m-fused kernel launches."""
-        return self._run_blocked(
-            state, self._scal(regs), steps=int(steps), m=int(m),
-            block_h=int(block_h), double_buffer=bool(double_buffer),
-            interpret=interpret,
-        )
-
-    def _run_blocked_impl(self, state, scal, *, steps, m, block_h,
-                          double_buffer, interpret):
-        from repro.kernels.spd_stream.ops import stream_run_blocked
-
-        return stream_run_blocked(
-            functools.partial(self._streamed, double_buffer=double_buffer),
-            state, scal, steps=steps, m=m, block_h=block_h,
-            interpret=interpret,
-        )
+        steps, m, block_h = int(steps), int(m), int(block_h)
+        with jax.profiler.TraceAnnotation(tracing.RUN):
+            out = self._run_blocked(
+                state, self._scal(regs), steps=steps, m=m, block_h=block_h,
+                double_buffer=bool(double_buffer), interpret=interpret,
+            )
+            launches = steps // m
+            tracing.count(launches=launches, steps=steps,
+                          dma_bytes=launches * self.launch_dma_bytes(
+                              state, m=m, block_h=block_h))
+        return out
 
     def sharded(self, d: int, devices: Sequence | None = None,
                 dx: int = 1):

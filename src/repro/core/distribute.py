@@ -85,12 +85,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.parallel.sharding import stream_grid_pspec
 
+from . import tracing
 from .legalize import (
     guard_cols,
+    launch_dma_bytes,
     mesh_shape,
     resolve_run_plan,
     shard_height,
     shard_width,
+    stripe_cols,
 )
 
 #: Name of the row device axis (the original ring axis).
@@ -239,40 +242,44 @@ class ShardedStreamKernel:
         before."""
         from repro.kernels.spd_stream.streaming import (
             spd_multistep_halo_streamed,
-            spd_multistep_streamed,
         )
 
         d, halo = self.d, self.halo
         step_fn = self.kernel._step_fn
+        name = self.kernel.name
         mh = m * halo
         perm_dn = [(i, (i + 1) % d) for i in range(d)]  # bottom rows -> next
         perm_up = [(i, (i - 1) % d) for i in range(d)]  # top rows -> previous
 
-        def local_run(local, scal):
+        # Named for the compiled module (jit_spd_run_sharded).
+        def spd_run_sharded(local, scal):
             p, lh, w = local.shape
             nblk = lh // block_h
 
             def shard_launch(ext, scal):
-                return spd_multistep_halo_streamed(
-                    step_fn, ext, scal, m=m, block_h=block_h, halo=halo,
-                    double_buffer=double_buffer, interpret=interpret,
-                )
+                with jax.named_scope(tracing.LAUNCH):
+                    return spd_multistep_halo_streamed(
+                        step_fn, ext, scal, m=m, block_h=block_h,
+                        halo=halo, double_buffer=double_buffer,
+                        interpret=interpret, name=name,
+                    )
 
             def body(_, cur):
                 if mh == 0:
                     # Elementwise core: shards never read each other.
-                    return spd_multistep_streamed(
-                        step_fn, cur, scal, m=m, block_h=block_h, halo=0,
-                        double_buffer=double_buffer, interpret=interpret,
-                    )
+                    return shard_launch(cur, scal)
                 # Ring halo exchange: receive the up-neighbor's bottom
                 # rows and the down-neighbor's top rows (periodic in y
                 # because the ring closes).
-                up = jax.lax.ppermute(
-                    cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
-                )
-                dn = jax.lax.ppermute(cur[:, :mh, :], DEVICE_AXIS, perm_up)
-                pad = jnp.zeros((p, block_h - mh, w), cur.dtype)
+                with jax.named_scope(tracing.EXCHANGE):
+                    up = jax.lax.ppermute(
+                        cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
+                    )
+                    dn = jax.lax.ppermute(
+                        cur[:, :mh, :], DEVICE_AXIS, perm_up
+                    )
+                with jax.named_scope(tracing.ASSEMBLE):
+                    pad = jnp.zeros((p, block_h - mh, w), cur.dtype)
                 if overlap and nblk >= 3:
                     # Overlapped exchange (docs/pipeline.md §overlap):
                     # the interior blocks 1..nblk-2 read only local rows
@@ -285,21 +292,24 @@ class ShardedStreamKernel:
                     # launch below, keeping the decomposition (and the
                     # sharded run) bitwise identical.
                     interior = shard_launch(cur, scal)
-                    ext_top = jnp.concatenate(
-                        [pad, up, cur[:, :2 * block_h, :]], axis=1
-                    )
-                    ext_bot = jnp.concatenate(
-                        [cur[:, lh - 2 * block_h:, :], dn, pad], axis=1
-                    )
+                    with jax.named_scope(tracing.ASSEMBLE):
+                        ext_top = jnp.concatenate(
+                            [pad, up, cur[:, :2 * block_h, :]], axis=1
+                        )
+                        ext_bot = jnp.concatenate(
+                            [cur[:, lh - 2 * block_h:, :], dn, pad], axis=1
+                        )
                     top = shard_launch(ext_top, scal)
                     bot = shard_launch(ext_bot, scal)
-                    return jnp.concatenate([top, interior, bot], axis=1)
-                ext = jnp.concatenate([pad, up, cur, dn, pad], axis=1)
+                    with jax.named_scope(tracing.ASSEMBLE):
+                        return jnp.concatenate([top, interior, bot], axis=1)
+                with jax.named_scope(tracing.ASSEMBLE):
+                    ext = jnp.concatenate([pad, up, cur, dn, pad], axis=1)
                 return shard_launch(ext, scal)
 
             return jax.lax.fori_loop(0, steps // m, body, local)
 
-        return local_run
+        return spd_run_sharded
 
     def _local_run_mesh(self, steps, m, block_h, double_buffer, overlap,
                         interpret):
@@ -310,12 +320,12 @@ class ShardedStreamKernel:
         wrap."""
         from repro.kernels.spd_stream.streaming import (
             spd_multistep_halo_streamed,
-            spd_multistep_streamed,
         )
 
         dy, halo, halo_x = self.dy, self.halo, self.halo_x
         dx = self.dx
         step_fn = self.kernel._step_fn_guarded
+        name = self.kernel.name
         mh = m * halo
         mhx = m * halo_x
         # Guard columns per side: the mhx exchanged columns plus zero
@@ -333,55 +343,61 @@ class ShardedStreamKernel:
         perm_r = [(j, (j + 1) % dx) for j in range(dx)]  # right cols -> next
         perm_l = [(j, (j - 1) % dx) for j in range(dx)]  # left cols -> prev
 
-        def local_run(local, scal):
+        # Named for the compiled module (jit_spd_run_sharded).
+        def spd_run_sharded(local, scal):
             p, lh, w = local.shape
             nblk = lh // block_h
 
             def shard_launch(ext, scal):
-                return spd_multistep_halo_streamed(
-                    step_fn, ext, scal, m=m, block_h=block_h, halo=halo,
-                    double_buffer=double_buffer, interpret=interpret,
-                )
+                with jax.named_scope(tracing.LAUNCH):
+                    return spd_multistep_halo_streamed(
+                        step_fn, ext, scal, m=m, block_h=block_h,
+                        halo=halo, double_buffer=double_buffer,
+                        interpret=interpret, name=name,
+                    )
 
             def widen(left, mid, right):
                 """[zero pad | left | mid | right | zero pad] along x."""
-                pad = jnp.zeros(mid.shape[:2] + (gx - mhx,), mid.dtype)
-                return jnp.concatenate([pad, left, mid, right, pad], axis=2)
+                with jax.named_scope(tracing.ASSEMBLE):
+                    pad = jnp.zeros(mid.shape[:2] + (gx - mhx,), mid.dtype)
+                    return jnp.concatenate([pad, left, mid, right, pad],
+                                           axis=2)
 
             def exchange_x(cur):
                 """[left-guard | local | right-guard] via the dx ring."""
-                left = jax.lax.ppermute(
-                    cur[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                )
-                right = jax.lax.ppermute(
-                    cur[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                )
+                with jax.named_scope(tracing.EXCHANGE):
+                    left = jax.lax.ppermute(
+                        cur[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
+                    )
+                    right = jax.lax.ppermute(
+                        cur[:, :, :mhx], DEVICE_AXIS_X, perm_l
+                    )
                 return widen(left, cur, right)
+
+            def crop(out):
+                """The shard's own columns of an extended-width launch."""
+                with jax.named_scope(tracing.ASSEMBLE):
+                    return out[:, :, gx:gx + w] if gx else out
 
             def body(_, cur):
                 if mh == 0 and mhx == 0:
                     # Elementwise core: shards never read each other.
-                    return spd_multistep_streamed(
-                        step_fn, cur, scal, m=m, block_h=block_h, halo=0,
-                        double_buffer=double_buffer, interpret=interpret,
-                    )
+                    return shard_launch(cur, scal)
                 if mh == 0:
                     # x-only stencil: column exchange, launch over the
                     # extended width, crop the stale guard columns.
-                    out = spd_multistep_streamed(
-                        step_fn, exchange_x(cur), scal, m=m,
-                        block_h=block_h, halo=0,
-                        double_buffer=double_buffer, interpret=interpret,
-                    )
-                    return out[:, :, gx:gx + w]
+                    return crop(shard_launch(exchange_x(cur), scal))
                 # All first-hop collectives depend only on `cur` and are
                 # issued together: the row exchange (guard rows at local
                 # width) and, when the core reads in x, the column
                 # exchange.
-                up0 = jax.lax.ppermute(
-                    cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
-                )
-                dn0 = jax.lax.ppermute(cur[:, :mh, :], DEVICE_AXIS, perm_up)
+                with jax.named_scope(tracing.EXCHANGE):
+                    up0 = jax.lax.ppermute(
+                        cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
+                    )
+                    dn0 = jax.lax.ppermute(
+                        cur[:, :mh, :], DEVICE_AXIS, perm_up
+                    )
                 if mhx:
                     curx = exchange_x(cur)
                     # Corner second hop (DESIGN.md §15): column-permute
@@ -390,24 +406,26 @@ class ShardedStreamKernel:
                     # same values a width-extended row exchange would
                     # have shipped, but only (mh × mhx) elements per
                     # link.
-                    ul = jax.lax.ppermute(
-                        up0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                    )
-                    ur = jax.lax.ppermute(
-                        up0[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                    )
-                    dl = jax.lax.ppermute(
-                        dn0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                    )
-                    dr = jax.lax.ppermute(
-                        dn0[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                    )
+                    with jax.named_scope(tracing.EXCHANGE):
+                        ul = jax.lax.ppermute(
+                            up0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
+                        )
+                        ur = jax.lax.ppermute(
+                            up0[:, :, :mhx], DEVICE_AXIS_X, perm_l
+                        )
+                        dl = jax.lax.ppermute(
+                            dn0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
+                        )
+                        dr = jax.lax.ppermute(
+                            dn0[:, :, :mhx], DEVICE_AXIS_X, perm_l
+                        )
                     upx = widen(ul, up0, ur)
                     dnx = widen(dl, dn0, dr)
                 else:
                     curx, upx, dnx = cur, up0, dn0
                 wx = w + 2 * gx
-                pad = jnp.zeros((p, block_h - mh, wx), cur.dtype)
+                with jax.named_scope(tracing.ASSEMBLE):
+                    pad = jnp.zeros((p, block_h - mh, wx), cur.dtype)
                 if overlap and nblk >= 3:
                     # Overlap generalization (DESIGN.md §15): the
                     # interior blocks span the full (extended) shard
@@ -417,25 +435,29 @@ class ShardedStreamKernel:
                     # Every block's stripe assembles the same values as
                     # the monolithic launch below: bitwise identical.
                     interior = shard_launch(curx, scal)
-                    ext_top = jnp.concatenate(
-                        [pad, upx, curx[:, :2 * block_h, :]], axis=1
-                    )
-                    ext_bot = jnp.concatenate(
-                        [curx[:, lh - 2 * block_h:, :], dnx, pad], axis=1
-                    )
+                    with jax.named_scope(tracing.ASSEMBLE):
+                        ext_top = jnp.concatenate(
+                            [pad, upx, curx[:, :2 * block_h, :]], axis=1
+                        )
+                        ext_bot = jnp.concatenate(
+                            [curx[:, lh - 2 * block_h:, :], dnx, pad],
+                            axis=1,
+                        )
                     top = shard_launch(ext_top, scal)
                     bot = shard_launch(ext_bot, scal)
-                    out = jnp.concatenate([top, interior, bot], axis=1)
+                    with jax.named_scope(tracing.ASSEMBLE):
+                        out = jnp.concatenate([top, interior, bot], axis=1)
                 else:
-                    ext = jnp.concatenate(
-                        [pad, upx, curx, dnx, pad], axis=1
-                    )
+                    with jax.named_scope(tracing.ASSEMBLE):
+                        ext = jnp.concatenate(
+                            [pad, upx, curx, dnx, pad], axis=1
+                        )
                     out = shard_launch(ext, scal)
-                return out[:, :, gx:gx + w] if gx else out
+                return crop(out)
 
             return jax.lax.fori_loop(0, steps // m, body, local)
 
-        return local_run
+        return spd_run_sharded
 
     # ---- launches (mirroring StreamKernel) ---------------------------------
 
@@ -478,9 +500,22 @@ class ShardedStreamKernel:
             )
         if steps % m:
             raise ValueError(f"steps={steps} must be a multiple of m={m}")
-        fn = self._fn(steps, m, block_h, bool(double_buffer), bool(overlap),
-                      interpret)
-        return fn(state, self.kernel._scal(regs))
+        with jax.profiler.TraceAnnotation(tracing.RUN):
+            fn = self._fn(steps, m, block_h, bool(double_buffer),
+                          bool(overlap), interpret)
+            out = fn(state, self.kernel._scal(regs))
+            launches = steps // m
+            # Every shard's launch moves the same rows; the interior and
+            # edge launches of the overlapped exchange move, together,
+            # what one monolithic launch over the extended shard moves.
+            shard_bytes = launch_dma_bytes(
+                local_h, stripe_cols(local_w, m,
+                                     self.halo_x if self.dx > 1 else 0),
+                p, block_h=block_h, m=m, halo=self.halo,
+                itemsize=state.dtype.itemsize)
+            tracing.count(launches=launches, steps=steps,
+                          dma_bytes=launches * self.d * shard_bytes)
+        return out
 
     def run_for_point(self, state, regs: Sequence = (), *, point,
                       steps: int | None = None,

@@ -260,6 +260,20 @@ def stripe_cols(width, m, halo_x):
     return width + 2 * guard_cols(m * halo_x)
 
 
+def launch_dma_bytes(rows: int, width: int, planes: int, *, block_h: int,
+                     m: int, halo: int, itemsize: int) -> int:
+    """Bytes the DMAs of one streamed fused launch are programmed to move
+    over a ``(planes, rows, width)`` state (``planes`` = batch × words):
+    each of the ``rows / block_h`` blocks reads its stripe
+    (:func:`stripe_rows`) into VMEM, and the ``rows`` output rows are
+    written back. A halo-extended shard's launch (its interior and two
+    edge launches alike) moves the same rows: ``rows`` is then the
+    shard's height and ``width`` its launch width (:func:`stripe_cols`)."""
+    nblk = rows // block_h
+    return (planes * width * itemsize
+            * (nblk * stripe_rows(block_h, m, halo) + rows))
+
+
 def lane_multiple(interpret: bool | None = None) -> int:
     """What a launch width must be a multiple of: :data:`LANES` for the
     compiled TPU kernel, 1 under the Pallas interpreter. ``None``
@@ -866,6 +880,7 @@ __all__ = [
     "guard_cols",
     "halo_rows",
     "lane_multiple",
+    "launch_dma_bytes",
     "legal_block_values",
     "mesh_shape",
     "parse_fusion",

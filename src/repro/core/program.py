@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 
+from . import tracing
 from .codegen import CodegenError, StreamKernel, stencil_summary
 from .compiler import CompiledCore, Registry
 from .dfg import SPDError
@@ -398,10 +399,29 @@ class ProgramKernel:
         #: per-stage geometry, the launches read each cluster kernel's
         #: own inferred halo).
         self.halo = max(k.halo for k in self.clusters)
+
+        def spd_run_pipelined(state, scals, *, steps, block_h,
+                              double_buffer, interpret):
+            """``steps`` program steps as one jitted chain: every
+            cluster launches once per step at ``m=1`` (temporal blocking
+            does not cross a cut edge), and because the whole loop is a
+            single jit the inter-cluster fields never leave the
+            device."""
+
+            def body(_, s):
+                for kern, scal in zip(self.clusters, scals):
+                    s = kern._streamed(
+                        s, scal, m=1, block_h=block_h,
+                        double_buffer=double_buffer, interpret=interpret,
+                    )
+                return s
+
+            return jax.lax.fori_loop(0, steps, body, state)
+
         self._pipelined = jax.jit(
-            self._pipelined_impl,
+            spd_run_pipelined,
             static_argnames=("steps", "block_h", "double_buffer",
-                            "interpret"),
+                             "interpret"),
         )
 
     @property
@@ -420,22 +440,12 @@ class ProgramKernel:
             for kern, (a, b) in zip(self.clusters, self.spans)
         )
 
-    def _pipelined_impl(self, state, scals, *, steps, block_h,
-                        double_buffer, interpret):
-        """``steps`` program steps as one jitted chain: every cluster
-        launches once per step at ``m=1`` (temporal blocking does not
-        cross a cut edge), and because the whole loop is a single jit
-        the inter-cluster fields never leave the device."""
-
-        def body(_, s):
-            for kern, scal in zip(self.clusters, scals):
-                s = kern._streamed(
-                    s, scal, m=1, block_h=block_h,
-                    double_buffer=double_buffer, interpret=interpret,
-                )
-            return s
-
-        return jax.lax.fori_loop(0, steps, body, state)
+    def _count_steps(self, state, steps: int, block_h: int) -> None:
+        """Count ``steps`` program steps of one launch per cluster."""
+        step_bytes = sum(k.launch_dma_bytes(state, m=1, block_h=block_h)
+                         for k in self.clusters)
+        tracing.count(launches=steps * len(self.clusters), steps=steps,
+                      dma_bytes=steps * step_bytes)
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
@@ -464,10 +474,13 @@ class ProgramKernel:
                 steps=steps, m=m, block_h=block_h,
                 double_buffer=double_buffer, interpret=interpret,
             )
-        return self._pipelined(
-            state, scals, steps=int(steps), block_h=int(block_h),
-            double_buffer=bool(double_buffer), interpret=interpret,
-        )
+        with jax.profiler.TraceAnnotation(tracing.RUN):
+            out = self._pipelined(
+                state, scals, steps=int(steps), block_h=int(block_h),
+                double_buffer=bool(double_buffer), interpret=interpret,
+            )
+            self._count_steps(state, int(steps), int(block_h))
+        return out
 
     def _run_sharded(self, state, regs, *, steps, m, block_h,
                      double_buffer, interpret, d, dx=1):
@@ -502,12 +515,14 @@ class ProgramKernel:
 
         scals = self._scals(regs)
         for _ in range(int(steps)):
-            for kern, scal in zip(self.clusters, scals):
-                out = kern._streamed(
-                    state, scal, m=1, block_h=block_h,
-                    double_buffer=double_buffer, interpret=interpret,
-                )
-                state = jnp.asarray(np.asarray(out))  # host round-trip
+            with jax.profiler.TraceAnnotation(tracing.RUN):
+                for kern, scal in zip(self.clusters, scals):
+                    out = kern._streamed(
+                        state, scal, m=1, block_h=block_h,
+                        double_buffer=double_buffer, interpret=interpret,
+                    )
+                    state = jnp.asarray(np.asarray(out))  # host round-trip
+                self._count_steps(state, 1, int(block_h))
         return state
 
     def run_for_point(self, state, regs: Sequence = (), *, point,

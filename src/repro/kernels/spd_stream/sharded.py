@@ -46,7 +46,8 @@ from .spd_stream import _kernel, spd_multistep
 
 
 def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
-                       halo: int, interpret: bool | None = None):
+                       halo: int, interpret: bool | None = None,
+                       name: str | None = None):
     """Fused m-step launch over one halo-extended shard.
 
     Args:
@@ -60,6 +61,7 @@ def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
         need no exchanged rows and take the plain launch.
       interpret: run under the Pallas interpreter; ``None`` decides by
         backend (``repro.compat.default_interpret``: CPU only).
+      name: the kernel's name in the compiled program and device trace.
 
     Returns the advanced ``(P, local_h, W)`` shard (guard blocks dropped).
     """
@@ -68,7 +70,7 @@ def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
         # Elementwise core: no neighbor rows, no guard blocks expected.
         return spd_multistep(
             step_fn, ext, scal, m=m, block_h=block_h, halo=0,
-            interpret=interpret,
+            interpret=interpret, name=name,
         )
     p, rows, w = ext.shape
     local_h = rows - 2 * block_h
@@ -100,4 +102,5 @@ def spd_multistep_halo(step_fn: Callable, ext, scal, *, m: int, block_h: int,
         out_specs=pl.BlockSpec((p, block_h, w), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, local_h, w), ext.dtype),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(scal, ext, ext, ext)

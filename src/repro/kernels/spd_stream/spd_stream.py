@@ -65,7 +65,8 @@ def _kernel(scal_ref, fc_ref, fu_ref, fd_ref, out_ref, *,
 
 
 def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
-                  halo: int, interpret: bool | None = None):
+                  halo: int, interpret: bool | None = None,
+                  name: str | None = None):
     """Fused m-step launch of a codegen'd stripe function.
 
     Args:
@@ -84,6 +85,7 @@ def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
         the stripe carries ``m*halo`` extra rows per side.
       interpret: run under the Pallas interpreter; ``None`` decides by
         backend (``repro.compat.default_interpret``: CPU only).
+      name: the kernel's name in the compiled program and device trace.
     """
     *lead, h, w = state.shape
     if h % block_h:
@@ -116,4 +118,5 @@ def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
         ),
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(scal, state, state, state)

@@ -174,7 +174,7 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
 
 
 def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
-                   out_h, src_starts, interpret):
+                   out_h, src_starts, interpret, name):
     *lead, _, w = state.shape
     interpret = resolve_interpret(interpret)
     if not interpret and (w % LANES or block_h % SUBLANE_ROWS):
@@ -205,20 +205,23 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
             vmem_limit_bytes=VMEM_BYTES
         ),
         interpret=interpret,
+        name=name,
     )(scal, state)
 
 
 def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
                            block_h: int, halo: int,
                            double_buffer: bool = True,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           name: str | None = None):
     """Streamed fused m-step launch, periodic in y.
 
     Drop-in for :func:`repro.kernels.spd_stream.spd_multistep` — same
     stripe function contract, same validation, bitwise-identical output
     — but with manual double-buffered DMA staging (docs/pipeline.md
     §stream). ``double_buffer`` picks the ping/pong (True) or
-    single-buffer streaming-fallback (False) protocol.
+    single-buffer streaming-fallback (False) protocol. ``name`` names
+    the kernel in the compiled program and the device trace.
     """
     *_, h, _ = state.shape
     if h % block_h:
@@ -241,13 +244,15 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
     return _streamed_call(
         step_fn, state, scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
         nbuf=nbuf, out_h=h, src_starts=src_starts, interpret=interpret,
+        name=name,
     )
 
 
 def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
                                 block_h: int, halo: int,
                                 double_buffer: bool = True,
-                                interpret: bool | None = None):
+                                interpret: bool | None = None,
+                                name: str | None = None):
     """Streamed fused m-step launch over one halo-extended shard.
 
     The streamed twin of
@@ -260,7 +265,7 @@ def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
     if m * halo == 0:
         return spd_multistep_streamed(
             step_fn, ext, scal, m=m, block_h=block_h, halo=0,
-            double_buffer=double_buffer, interpret=interpret,
+            double_buffer=double_buffer, interpret=interpret, name=name,
         )
     *_, rows, _ = ext.shape
     local_h = rows - 2 * block_h
@@ -283,5 +288,5 @@ def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
     return _streamed_call(
         step_fn, ext, scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
         nbuf=2 if double_buffer else 1, out_h=local_h,
-        src_starts=src_starts, interpret=interpret,
+        src_starts=src_starts, interpret=interpret, name=name,
     )

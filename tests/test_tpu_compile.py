@@ -129,6 +129,32 @@ def test_sharded_2x2_mesh_compiles(topo, kernels, app, n, block_h, m):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+    # The exchange and the shard assembly carry the program's scopes.
+    assert text.startswith("HloModule jit_spd_run_sharded")
+    assert "/spd.exchange/ppermute" in text
+    assert "/spd.assemble/concatenate" in text
+
+
+def test_run_blocked_kernel_is_named_under_its_launch_scope(one_chip,
+                                                            kernels):
+    """The timed entry compiles as ``jit_spd_run_blocked``, and its
+    kernel is a custom call named ``spd_<core>`` whose ``op_name`` is
+    under ``spd.launch``: what the device trace shows."""
+    import re
+
+    kern = kernels["lbm"]
+    state, scal = _shapes(kern, (len(kern._ports), 1024, 1024), one_chip)
+    text = kern._run_blocked.lower(
+        state, scal, steps=8, m=4, block_h=32, double_buffer=True,
+        interpret=False).compile().as_text()
+    assert text.startswith("HloModule jit_spd_run_blocked")
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*%{kern.name}\.\d+ = ", line), line
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "/spd.launch/" in op_name
 
 
 @pytest.mark.parametrize("app,h,w", [
