@@ -444,12 +444,12 @@ class StreamKernel:
                 )
 
         def spd_launch(state, scal, *, m, block_h, double_buffer=True,
-                       interpret=None):
+                       interpret=None, dst=None):
             with jax.named_scope(tracing.LAUNCH):
                 return spd_multistep_streamed(
                     self._step_fn, state, scal, m=m, block_h=block_h,
                     halo=self.halo, double_buffer=double_buffer,
-                    interpret=interpret, name=self.name,
+                    interpret=interpret, name=self.name, dst=dst,
                 )
 
         def spd_run_blocked(state, scal, *, steps, m, block_h,
@@ -575,7 +575,8 @@ class StreamKernel:
             launches = steps // m
             tracing.count(launches=launches, steps=steps,
                           dma_bytes=launches * self.launch_dma_bytes(
-                              state, m=m, block_h=block_h))
+                              state, m=m, block_h=block_h),
+                          aliased_launches=max(0, launches - 2))
         return out
 
     def sharded(self, d: int, devices: Sequence | None = None,

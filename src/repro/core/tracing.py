@@ -18,6 +18,10 @@ Counters (process-wide, read with :func:`snapshot`):
   added once per call from its plan (:func:`count`);
 * ``dma_bytes``: the bytes those launches' DMAs are programmed to move
   (:func:`repro.core.legalize.launch_dma_bytes`, summed over shards);
+* ``aliased_launches``: those launches that wrote into a buffer the
+  one-chip launch loop recycles (``input_output_aliases``) rather than
+  a new one: ``max(0, launches - 2)`` per ``StreamKernel.run_blocked``
+  call (:func:`repro.kernels.spd_stream.stream_run_blocked`);
 * ``jit_traces``: jaxpr traces, one per jit cache miss of any function
   in the process (the program's and its caller's alike);
 * ``jit_s``: seconds spent tracing, lowering and compiling (a
@@ -56,8 +60,8 @@ COMPILE_EVENTS = (
 )
 
 _lock = threading.Lock()
-_counters = {"launches": 0, "steps": 0, "dma_bytes": 0, "jit_traces": 0,
-             "jit_s": 0.0}
+_counters = {"launches": 0, "steps": 0, "dma_bytes": 0,
+             "aliased_launches": 0, "jit_traces": 0, "jit_s": 0.0}
 #: Disjoint compile spans seen so far, ``[start, end]`` in seconds.
 _spans: list[list[float]] = []
 
@@ -68,12 +72,15 @@ def kernel_name(core_name: str) -> str:
     return "spd_" + re.sub(r"[^A-Za-z0-9_]", "_", core_name)
 
 
-def count(*, launches: int, steps: int, dma_bytes: int) -> None:
-    """Add one call's launches, steps and programmed DMA bytes."""
+def count(*, launches: int, steps: int, dma_bytes: int,
+          aliased_launches: int = 0) -> None:
+    """Add one call's launches, steps, programmed DMA bytes and the
+    launches among them that wrote into a recycled buffer."""
     with _lock:
         _counters["launches"] += int(launches)
         _counters["steps"] += int(steps)
         _counters["dma_bytes"] += int(dma_bytes)
+        _counters["aliased_launches"] += int(aliased_launches)
 
 
 def snapshot() -> dict:

@@ -173,8 +173,15 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
     jax.lax.fori_loop(0, nbuf, drain, 0)
 
 
+def _into_dst(scal_ref, state_ref, _dst_ref, *refs, **kw):
+    """:func:`_stream_kernel` with a destination input that it never
+    reads: the output is that input's buffer (``input_output_aliases``),
+    and every row of it is written."""
+    _stream_kernel(scal_ref, state_ref, *refs, **kw)
+
+
 def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
-                   out_h, src_starts, interpret, name):
+                   out_h, src_starts, interpret, name, dst=None):
     *lead, _, w = state.shape
     interpret = resolve_interpret(interpret)
     if not interpret and (w % LANES or block_h % SUBLANE_ROWS):
@@ -184,17 +191,20 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
             f"{block_h} a multiple of {SUBLANE_ROWS}"
         )
     rows = block_h + 2 * mh
+    operands = (scal, state) if dst is None else (scal, state, dst)
     return pl.pallas_call(
         functools.partial(
-            _stream_kernel, step_fn=step_fn, m=m, block_h=block_h, mh=mh,
-            nblk=nblk, nbuf=nbuf, src_starts=src_starts,
+            _stream_kernel if dst is None else _into_dst, step_fn=step_fn,
+            m=m, block_h=block_h, mh=mh, nblk=nblk, nbuf=nbuf,
+            src_starts=src_starts,
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((*lead, out_h, w), state.dtype),
+        input_output_aliases={} if dst is None else {2: 0},
         scratch_shapes=[
             pltpu.VMEM((nbuf, *lead, rows, w), state.dtype),
             pltpu.VMEM((nbuf, *lead, block_h, w), state.dtype),
@@ -206,14 +216,14 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
         ),
         interpret=interpret,
         name=name,
-    )(scal, state)
+    )(*operands)
 
 
 def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
                            block_h: int, halo: int,
                            double_buffer: bool = True,
                            interpret: bool | None = None,
-                           name: str | None = None):
+                           name: str | None = None, dst=None):
     """Streamed fused m-step launch, periodic in y.
 
     Drop-in for :func:`repro.kernels.spd_stream.spd_multistep` — same
@@ -222,6 +232,13 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
     §stream). ``double_buffer`` picks the ping/pong (True) or
     single-buffer streaming-fallback (False) protocol. ``name`` names
     the kernel in the compiled program and the device trace.
+
+    ``dst``, an array of ``state``'s shape and dtype that must not be
+    ``state``, is the buffer the output is written into
+    (``input_output_aliases``): the kernel never reads it, so a launch
+    loop that hands each launch the buffer it last wrote needs no copy
+    (:func:`repro.kernels.spd_stream.stream_run_blocked`). Without it
+    the output is a new buffer.
     """
     *_, h, _ = state.shape
     if h % block_h:
@@ -244,7 +261,7 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
     return _streamed_call(
         step_fn, state, scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
         nbuf=nbuf, out_h=h, src_starts=src_starts, interpret=interpret,
-        name=name,
+        name=name, dst=dst,
     )
 
 

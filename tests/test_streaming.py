@@ -144,6 +144,38 @@ def test_single_block_grid_streams(dif_sim):
                                rtol=2e-5, atol=1e-6)
 
 
+# ----------------- the launch loop ping-pongs, never aliasing its input -----
+
+
+@pytest.mark.parametrize("launches", [1, 2, 3, 4, 5])
+@pytest.mark.parametrize("app", ["lbm", "diffusion"])
+def test_run_blocked_equals_single_launches(dif_sim, lbm_sim, app,
+                                            launches):
+    """``run_blocked``'s two recycled buffers (``dst`` aliased to the
+    output) give the bits of as many single ``__call__`` launches, and
+    the caller's input is only read: unchanged after the call, and a
+    second call on it gives the same output."""
+    if app == "lbm":
+        kern, regs = lbm_sim.stream_kernel(), LBM_REGS
+        f, attr, _ = lbm.taylor_green_init(16, 64)
+        state = lbm_sim.stream_state(f, attr)
+    else:
+        kern, regs = dif_sim.kernel, (0.2,)
+        state = dif_sim.state(dif.sine_init(16, 64)[0])
+    m, block_h = 2, 4
+    before = np.asarray(state).copy()
+    out = kern.run_blocked(state, regs, steps=launches * m, m=m,
+                           block_h=block_h)
+    want = state
+    for _ in range(launches):
+        want = kern(want, regs, m=m, block_h=block_h)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(state), before)
+    again = kern.run_blocked(state, regs, steps=launches * m, m=m,
+                             block_h=block_h)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
+
+
 # ----------------- VMEM overflow: the streaming fallback ---------------------
 
 

@@ -135,26 +135,55 @@ def test_sharded_2x2_mesh_compiles(topo, kernels, app, n, block_h, m):
     assert "/spd.assemble/concatenate" in text
 
 
-def test_run_blocked_kernel_is_named_under_its_launch_scope(one_chip,
-                                                            kernels):
-    """The timed entry compiles as ``jit_spd_run_blocked``, and its
-    kernel is a custom call named ``spd_<core>`` whose ``op_name`` is
-    under ``spd.launch``: what the device trace shows."""
+def _run_blocked_text(kern, one_chip, n, steps):
+    """The compiled ``jit_spd_run_blocked`` of LBM-sized state at n²,
+    plan (32, 4)."""
+    state, scal = _shapes(kern, (len(kern._ports), n, n), one_chip)
+    return kern._run_blocked.lower(
+        state, scal, steps=steps, m=4, block_h=32, double_buffer=True,
+        interpret=False).compile().as_text()
+
+
+def _assert_kernel_named_under_launch_scope(kern, text):
     import re
 
-    kern = kernels["lbm"]
-    state, scal = _shapes(kern, (len(kern._ports), 1024, 1024), one_chip)
-    text = kern._run_blocked.lower(
-        state, scal, steps=8, m=4, block_h=32, double_buffer=True,
-        interpret=False).compile().as_text()
     assert text.startswith("HloModule jit_spd_run_blocked")
     calls = [line for line in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert calls
     for line in calls:
-        assert re.match(rf"\s*%{kern.name}\.\d+ = ", line), line
+        assert re.match(rf"\s*(ROOT )?%{kern.name}\.\d+ = ", line), line
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         assert "/spd.launch/" in op_name
+
+
+def test_run_blocked_kernel_is_named_under_its_launch_scope(one_chip,
+                                                            kernels):
+    """The timed entry compiles as ``jit_spd_run_blocked``, and its
+    kernel is a custom call named ``spd_<core>`` whose ``op_name`` is
+    under ``spd.launch``: what the device trace shows."""
+    kern = kernels["lbm"]
+    _assert_kernel_named_under_launch_scope(
+        kern, _run_blocked_text(kern, one_chip, 1024, 8))
+
+
+@pytest.mark.parametrize("launches", [16, 17])
+def test_run_blocked_copies_no_state(one_chip, kernels, launches):
+    """The launch loop ping-pongs between two buffers the kernel writes
+    (``input_output_aliases``), so the compiled loop holds no copy of
+    state size: neither the loop carry into each launch's input nor the
+    caller's input into the carry. An odd launch count adds a launch
+    after the loop; the kernel keeps its name and scope."""
+    import re
+
+    kern = kernels["lbm"]
+    n = 1024
+    text = _run_blocked_text(kern, one_chip, n, 4 * launches)
+    state = rf"f32\[{len(kern._ports)},{n},{n}\]"
+    copies = [line for line in text.splitlines()
+              if re.search(rf"= {state}\S* copy\(", line)]
+    assert not copies, copies
+    _assert_kernel_named_under_launch_scope(kern, text)
 
 
 @pytest.mark.parametrize("app,h,w", [

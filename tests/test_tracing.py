@@ -1,9 +1,10 @@
 """The launch path's names and counters (``repro.core.tracing``).
 
 On the CPU, with the Pallas kernels in interpret mode: one
-``run_blocked`` call adds its plan's launches, steps and the DMA bytes
+``run_blocked`` call adds its plan's launches, steps, the DMA bytes
 of :func:`repro.core.legalize.launch_dma_bytes` (checked here against
-the formula written out by hand), a first call traces and a second
+the formula written out by hand) and the launches that wrote into a
+recycled buffer, a first call traces and a second
 does not, the jitted entries compile as ``jit_spd_…`` modules with the
 launch under ``spd.launch``, and the dispatch is a ``spd.run`` host
 span in the profiler's trace. The mesh path's counters and scopes run
@@ -81,10 +82,17 @@ def test_launch_dma_bytes_by_hand(rows, width, planes, block_h, m, halo,
     ("lbm", (), 8, 4, 16),
     ("diffusion", (), 6, 2, 8),
     ("diffusion", (3,), 4, 4, 32),  # a batch of three in one launch
+    ("diffusion", (), 8, 2, 16),
+    ("diffusion", (), 10, 2, 16),
+    ("diffusion", (), 32, 2, 16),
 ])
 def test_run_blocked_counts_its_plan(kernels, app, lead, steps, m,
                                      block_h):
+    """Besides launches, steps and bytes: the first two launches of a
+    call write new buffers, and every later one writes into a buffer
+    the loop recycles."""
     kern = kernels[app]
+    launches = steps // m
     x = _state(kern, *lead)
     planes = len(kern._ports) * (lead[0] if lead else 1)
     mh = -(-m * kern.halo // 8) * 8  # the halo in whole 8-row tiles
@@ -94,14 +102,15 @@ def test_run_blocked_counts_its_plan(kernels, app, lead, steps, m,
                      block_h=block_h).block_until_ready()
     first = tracing.snapshot()
     d = _delta(before, first)
-    assert (d["launches"], d["steps"]) == (steps // m, steps)
-    assert d["dma_bytes"] == steps // m * per_launch
+    assert (d["launches"], d["steps"]) == (launches, steps)
+    assert d["dma_bytes"] == launches * per_launch
+    assert d["aliased_launches"] == max(0, launches - 2)
     assert d["jit_traces"] > 0 and d["jit_s"] > 0
     kern.run_blocked(x, _regs(kern), steps=steps, m=m,
                      block_h=block_h).block_until_ready()
     d = _delta(first, tracing.snapshot())
-    assert (d["launches"], d["dma_bytes"]) == \
-        (steps // m, steps // m * per_launch)
+    assert (d["launches"], d["dma_bytes"], d["aliased_launches"]) == \
+        (launches, launches * per_launch, max(0, launches - 2))
     assert d["jit_traces"] == 0 and d["jit_s"] == 0
 
 
@@ -111,6 +120,7 @@ def test_single_launch_counts_one(kernels):
     kern(_state(kern), _regs(kern), m=2, block_h=16).block_until_ready()
     d = _delta(before, tracing.snapshot())
     assert (d["launches"], d["steps"]) == (1, 2)
+    assert d["aliased_launches"] == 0
     assert d["dma_bytes"] == launch_dma_bytes(N, N, 1, block_h=16, m=2,
                                               halo=kern.halo, itemsize=4)
 
@@ -214,3 +224,5 @@ def test_mesh_counts_every_shard_and_scopes_its_glue(tmp_path):
         assert got["delta"]["launches"] == steps // m
         assert got["delta"]["steps"] == steps
         assert got["delta"]["dma_bytes"] == steps // m * 4 * per_shard
+        # The mesh runs its own launch loop, which recycles nothing.
+        assert got["delta"]["aliased_launches"] == 0
