@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from . import tracing
 from .compiler import CompiledCore, eval_expr
 from .dfg import SPDError
-from .legalize import launch_dma_bytes, resolve_run_plan
+from .legalize import launch_dma_bytes, launch_flops, resolve_run_plan
 from .library import LibraryModule
 
 #: 1-D stream-state modules with no 2-D stripe lowering.
@@ -543,6 +543,13 @@ class StreamKernel:
                                 m=m, halo=self.halo,
                                 itemsize=state.dtype.itemsize)
 
+    def launch_flops(self, state, *, m: int, block_h: int) -> int:
+        """Float operations one streamed fused launch over ``state``
+        executes (:func:`repro.core.legalize.launch_flops`)."""
+        *batch, _, h, w = state.shape
+        return launch_flops(h, w, math.prod(batch), block_h=block_h, m=m,
+                            halo=self.halo, flops=self.compiled.flops)
+
     def __call__(self, state, regs: Sequence = (), *, m: int = 1,
                  block_h: int = 32, double_buffer: bool = True,
                  interpret: bool | None = None):
@@ -559,6 +566,8 @@ class StreamKernel:
             )
             tracing.count(launches=1, steps=m,
                           dma_bytes=self.launch_dma_bytes(
+                              state, m=m, block_h=block_h),
+                          kernel_flops=self.launch_flops(
                               state, m=m, block_h=block_h))
         return out
 
@@ -575,6 +584,8 @@ class StreamKernel:
             launches = steps // m
             tracing.count(launches=launches, steps=steps,
                           dma_bytes=launches * self.launch_dma_bytes(
+                              state, m=m, block_h=block_h),
+                          kernel_flops=launches * self.launch_flops(
                               state, m=m, block_h=block_h),
                           aliased_launches=max(0, launches - 2))
         return out

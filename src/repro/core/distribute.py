@@ -89,6 +89,7 @@ from . import tracing
 from .legalize import (
     guard_cols,
     launch_dma_bytes,
+    launch_flops,
     mesh_shape,
     resolve_run_plan,
     shard_height,
@@ -505,16 +506,20 @@ class ShardedStreamKernel:
                           bool(overlap), interpret)
             out = fn(state, self.kernel._scal(regs))
             launches = steps // m
-            # Every shard's launch moves the same rows; the interior and
-            # edge launches of the overlapped exchange move, together,
-            # what one monolithic launch over the extended shard moves.
+            # Every shard's launch moves and computes the same rows; the
+            # interior and edge launches of the overlapped exchange move
+            # and compute, together, what one monolithic launch over the
+            # extended shard does.
+            width = stripe_cols(local_w, m, self.halo_x if self.dx > 1 else 0)
             shard_bytes = launch_dma_bytes(
-                local_h, stripe_cols(local_w, m,
-                                     self.halo_x if self.dx > 1 else 0),
-                p, block_h=block_h, m=m, halo=self.halo,
+                local_h, width, p, block_h=block_h, m=m, halo=self.halo,
                 itemsize=state.dtype.itemsize)
+            shard_flops = launch_flops(
+                local_h, width, 1, block_h=block_h, m=m, halo=self.halo,
+                flops=self.kernel.compiled.flops)
             tracing.count(launches=launches, steps=steps,
-                          dma_bytes=launches * self.d * shard_bytes)
+                          dma_bytes=launches * self.d * shard_bytes,
+                          kernel_flops=launches * self.d * shard_flops)
         return out
 
     def run_for_point(self, state, regs: Sequence = (), *, point,

@@ -274,6 +274,19 @@ def launch_dma_bytes(rows: int, width: int, planes: int, *, block_h: int,
             * (nblk * stripe_rows(block_h, m, halo) + rows))
 
 
+def launch_flops(rows: int, width: int, batch: int, *, block_h: int,
+                 m: int, halo: int, flops: int) -> int:
+    """Float operations one streamed fused launch executes over a
+    ``(batch, words, rows, width)`` state for a core of ``flops``
+    operations per site: each of the ``rows / block_h`` blocks applies
+    the core ``m`` times to every site of its stripe
+    (:func:`stripe_rows`), halo rows included. A halo-extended shard's
+    launch counts as :func:`launch_dma_bytes` does: ``rows`` is the
+    shard's height and ``width`` its launch width."""
+    nblk = rows // block_h
+    return batch * width * nblk * stripe_rows(block_h, m, halo) * m * flops
+
+
 def lane_multiple(interpret: bool | None = None) -> int:
     """What a launch width must be a multiple of: :data:`LANES` for the
     compiled TPU kernel, 1 under the Pallas interpreter. ``None``
@@ -881,6 +894,7 @@ __all__ = [
     "halo_rows",
     "lane_multiple",
     "launch_dma_bytes",
+    "launch_flops",
     "legal_block_values",
     "mesh_shape",
     "parse_fusion",

@@ -444,8 +444,11 @@ class ProgramKernel:
         """Count ``steps`` program steps of one launch per cluster."""
         step_bytes = sum(k.launch_dma_bytes(state, m=1, block_h=block_h)
                          for k in self.clusters)
+        step_flops = sum(k.launch_flops(state, m=1, block_h=block_h)
+                         for k in self.clusters)
         tracing.count(launches=steps * len(self.clusters), steps=steps,
-                      dma_bytes=steps * step_bytes)
+                      dma_bytes=steps * step_bytes,
+                      kernel_flops=steps * step_flops)
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,

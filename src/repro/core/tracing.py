@@ -18,6 +18,10 @@ Counters (process-wide, read with :func:`snapshot`):
   added once per call from its plan (:func:`count`);
 * ``dma_bytes``: the bytes those launches' DMAs are programmed to move
   (:func:`repro.core.legalize.launch_dma_bytes`, summed over shards);
+* ``kernel_flops``: the float operations those launches execute, halo
+  rows of every stripe included (:func:`repro.core.legalize.launch_flops`
+  from the compiled core's per-site count, summed over shards and over
+  a program's fused cores);
 * ``aliased_launches``: those launches that wrote into a buffer the
   one-chip launch loop recycles (``input_output_aliases``) rather than
   a new one: ``max(0, launches - 2)`` per ``StreamKernel.run_blocked``
@@ -60,7 +64,7 @@ COMPILE_EVENTS = (
 )
 
 _lock = threading.Lock()
-_counters = {"launches": 0, "steps": 0, "dma_bytes": 0,
+_counters = {"launches": 0, "steps": 0, "dma_bytes": 0, "kernel_flops": 0,
              "aliased_launches": 0, "jit_traces": 0, "jit_s": 0.0}
 #: Disjoint compile spans seen so far, ``[start, end]`` in seconds.
 _spans: list[list[float]] = []
@@ -72,14 +76,16 @@ def kernel_name(core_name: str) -> str:
     return "spd_" + re.sub(r"[^A-Za-z0-9_]", "_", core_name)
 
 
-def count(*, launches: int, steps: int, dma_bytes: int,
+def count(*, launches: int, steps: int, dma_bytes: int, kernel_flops: int,
           aliased_launches: int = 0) -> None:
-    """Add one call's launches, steps, programmed DMA bytes and the
-    launches among them that wrote into a recycled buffer."""
+    """Add one call's launches, steps, programmed DMA bytes, executed
+    float operations and the launches among them that wrote into a
+    recycled buffer."""
     with _lock:
         _counters["launches"] += int(launches)
         _counters["steps"] += int(steps)
         _counters["dma_bytes"] += int(dma_bytes)
+        _counters["kernel_flops"] += int(kernel_flops)
         _counters["aliased_launches"] += int(aliased_launches)
 
 
