@@ -20,12 +20,11 @@ bottom of the paper's flow, where the generated datapath actually runs
    neighbor values; ``halo`` edge rows go stale per application — the
    temporal-blocking trapezoid), x stencil reads become periodic
    in-register shifts (the full row width is VMEM-resident). Under a
-   column-sharded 2-D device mesh the x reads switch to the same
-   non-periodic zero-fill treatment as y (:meth:`StreamKernel
-   ._step_fn_guarded`): the stripe then carries ``m·halo_x`` guard
-   columns per side whose values came off-device, and columns consuming
-   the zero fill are exactly the guard columns the launch crops
-   (DESIGN.md §15).
+   column-sharded 2-D device mesh the same periodic body runs over a
+   stripe laid out ``[shard | right guard | left guard]``: the x wrap
+   lands each shard edge on the guard columns that came off-device,
+   and the stale columns stay where the two guards meet (DESIGN.md
+   §15).
 3. **Launch + legalization** — the stripe function is handed to
    :func:`repro.kernels.spd_stream.spd_multistep` for the
    ``(block_h + 2·m·halo)``-row Pallas launch; explorer-chosen
@@ -280,19 +279,13 @@ def stencil_summary(compiled: CompiledCore,
 # --------------------------------------------------------------------------
 
 
-def _stripe_shift(x, dy: int, dx: int, periodic_x: bool = True):
+def _stripe_shift(x, dy: int, dx: int):
     """``out[y, x] = in[y-dy, x-dx]`` on a (rows, W) stripe.
 
     y is shifted non-periodically with zero fill — the stripe's halo rows
     hold the true neighbor values, and rows that consume the zero fill
     are exactly the rows the trapezoid retires; x is shifted
     periodically in-register (the full row width is resident).
-
-    ``periodic_x=False`` is the column-sharded lowering (DESIGN.md §15):
-    x gets the same zero-fill treatment as y, because the stripe then
-    carries guard columns holding the true neighbor values — columns
-    that consume the zero fill are exactly the stale guard columns the
-    sharded launch crops.
     """
     if dy:
         pad = jnp.zeros((abs(dy),) + x.shape[1:], x.dtype)
@@ -301,15 +294,6 @@ def _stripe_shift(x, dy: int, dx: int, periodic_x: bool = True):
             if dy > 0
             else jnp.concatenate([x[-dy:], pad], axis=0)
         )
-    if not periodic_x:
-        if dx:
-            pad = jnp.zeros(x.shape[:-1] + (abs(dx),), x.dtype)
-            x = (
-                jnp.concatenate([pad, x[:, :-dx]], axis=1)
-                if dx > 0
-                else jnp.concatenate([x[:, -dx:], pad], axis=1)
-            )
-        return x
     dx %= x.shape[1]  # periodic: offsets beyond one row width wrap
     if dx:
         # With dx normalized into [1, W), this one concatenate is the
@@ -318,8 +302,7 @@ def _stripe_shift(x, dy: int, dx: int, periodic_x: bool = True):
     return x
 
 
-def _eval_stripe(compiled: CompiledCore, env: dict,
-                 periodic_x: bool = True) -> list:
+def _eval_stripe(compiled: CompiledCore, env: dict) -> list:
     """Evaluate a core's DFG over (rows, W) stripe arrays.
 
     Structurally identical to :meth:`CompiledCore.apply` (same casts,
@@ -353,7 +336,6 @@ def _eval_stripe(compiled: CompiledCore, env: dict,
                     _stripe_shift(
                         jnp.asarray(ins[0], jnp.float32),
                         int(p.get("dy", 0)), int(p.get("dx", 0)),
-                        periodic_x=periodic_x,
                     )
                 ]
             else:
@@ -363,7 +345,7 @@ def _eval_stripe(compiled: CompiledCore, env: dict,
             sub_env.update({
                 k: jnp.float32(v) for k, v in mod.core.params.items()
             })
-            outs = _eval_stripe(mod, sub_env, periodic_x=periodic_x)
+            outs = _eval_stripe(mod, sub_env)
         if len(outs) != len(node.outputs):
             raise CodegenError(
                 f"node {node.name}: module {node.module} returned "
@@ -498,27 +480,12 @@ class StreamKernel:
         are handled by vmapping this same body over each leading axis,
         so batched and unbatched launches share one lowering.
         """
-        return self._apply_stripe(f_ext, regs, periodic_x=True)
-
-    def _step_fn_guarded(self, f_ext, regs):
-        """The column-sharded stripe body (DESIGN.md §15): identical
-        arithmetic, but x stencil reads are non-periodic zero-fill
-        shifts — the stripe's ``m·halo_x`` guard columns hold the true
-        neighbor values (delivered by the mesh's column-halo exchange),
-        and the columns consuming the zero fill are exactly the stale
-        guard columns the sharded launch crops.
-        """
-        return self._apply_stripe(f_ext, regs, periodic_x=False)
-
-    def _apply_stripe(self, f_ext, regs, *, periodic_x):
         if f_ext.ndim > 3:
-            return jax.vmap(
-                lambda s: self._apply_stripe(s, regs, periodic_x=periodic_x)
-            )(f_ext)
+            return jax.vmap(lambda s: self._step_fn(s, regs))(f_ext)
         env: dict = {p: f_ext[i] for i, p in enumerate(self._ports)}
         env.update(dict(zip(self._regs, regs)))
         env.update({k: jnp.float32(v) for k, v in self._params.items()})
-        outs = _eval_stripe(self.compiled, env, periodic_x=periodic_x)
+        outs = _eval_stripe(self.compiled, env)
         n = len(self._ports)
         return jnp.stack([jnp.asarray(o, f_ext.dtype) for o in outs[:n]])
 

@@ -21,48 +21,52 @@ Halo-exchange protocol, per fused launch (DESIGN.md §8 for the row axis,
 1. each shard sends its bottom ``m·halo`` rows to the next row shard and
    its top ``m·halo`` rows to the previous one, and — when ``dx > 1`` —
    its rightmost ``m·halo_x`` columns to the next column shard and its
-   leftmost to the previous one (four ``ppermute`` collectives issued
+   leftmost to the previous one (``ppermute`` collectives issued
    together, all depending only on the current shard — on TPU these ride
    the ICI links the DSE model's ``t_collective`` term prices, row and
-   column volumes separately);
-2. a small second hop column-permutes the edges of the received row
+   column volumes separately); a mesh with one row shard (``dy == 1``)
+   sends no rows, since its halo rows wrap within the shard;
+2. on the 1-D ring (``dx == 1``) the received rows are padded out to one
+   full ``block_h`` guard block per side, giving
+   ``[pad | up-halo | local | down-halo | pad]``, and
+   :func:`repro.kernels.spd_stream.sharded.spd_multistep_halo` (via its
+   streamed twin) advances the extended shard m steps with the exact
+   single-device stripe assembly;
+3. on the 2-D mesh (``dx > 1``) nothing shard-sized is assembled: the
+   two received column pieces are padded into one guard array of
+   ``2·guard_cols(m·halo_x)`` columns, ``[right | zeros | left]``; a
+   small second hop column-permutes the edges of the received row
    guards to fetch the four ``(m·halo, m·halo_x)`` corner blocks from
-   the diagonal neighbors, then the shard is extended to
-   ``[left-guard | local | right-guard]`` in x and the row guards padded
-   out to one full ``block_h`` guard block per side, giving
-   ``[pad | up-halo | local | down-halo | pad]`` over the extended
-   width;
-3. :func:`repro.kernels.spd_stream.sharded.spd_multistep_halo` (via its
-   streamed twin) advances the shard m steps with the exact
-   single-device stripe assembly — under ``dx > 1`` the stripe body is
-   the kernel's *guarded* variant
-   (:meth:`~repro.core.codegen.StreamKernel._step_fn_guarded`), whose x
-   stencil reads are non-periodic zero-fill shifts so the guard columns
-   supply the neighbor values; the ``m·halo_x`` guard columns go stale
-   one stencil reach per application (the same trapezoid as the guard
-   rows) and are cropped from the launch output.
+   the diagonal neighbors, which join the row guards in the same
+   layout; and :func:`repro.kernels.spd_stream.streaming
+   .spd_multistep_gathered` DMAs each stripe from the shard, the guard
+   array and the row guards where they lie, into a VMEM stripe laid
+   out ``[shard | right guard | left guard]``. The kernel's periodic
+   stripe body then wraps each shard edge onto the guard columns that
+   hold its true neighbors; the stale columns stay where the two guards
+   meet, and the launch writes back only the shard's own columns, into
+   one of two buffers the loop ping-pongs as on one chip.
 
-Because step 3 reuses the single-device kernel arithmetic and steps 1–2
-deliver exactly the rows and columns the periodic index maps / periodic
-in-register x shifts would have read, the sharded run is **bit-identical**
-to the single-device kernel for any legal mesh — the correctness
-contract asserted in ``tests/test_distribute.py`` (1-D ring) and
-``tests/test_mesh.py`` (the 2-D mesh matrix).
+Because the launches reuse the single-device kernel arithmetic and the
+exchange delivers exactly the rows and columns the periodic index maps
+and periodic in-register x shifts would have read, the sharded run is
+**bit-identical** to the single-device kernel for any legal mesh — the
+correctness contract asserted in ``tests/test_distribute.py`` (1-D
+ring), ``tests/test_mesh.py`` and ``tests/test_mesh_launch.py`` (the
+2-D mesh).
 
-**Overlapped exchange** (docs/pipeline.md §overlap, DESIGN.md §12, §15):
-only the shard's two *edge* row blocks read exchanged rows — every
-interior block's stripe is fully local in y. When a shard has at least
-three blocks, the fused launch is decomposed into an interior launch
-plus two one-block edge launches; the interior launch depends on the
-column exchange (every row block spans the full shard width) but not on
-the row exchange or the corner hop, so XLA is free to run the row
-exchange and corner fetch on the ICI links while the interior blocks
-compute — the generalization of the 1-D overlap, where the interior
-depended on no collective at all. Each block's stripe is assembled from
-exactly the same values either way, which keeps the decomposition
-bitwise identical to the monolithic launch (and the sharded run
-bit-identical to single-device); shards shorter than three blocks fall
-back to the monolithic exchange-then-compute path.
+**Overlapped exchange** on the ring (docs/pipeline.md §overlap,
+DESIGN.md §12): only the shard's two *edge* row blocks read exchanged
+rows — every interior block's stripe is fully local in y. When a shard
+has at least three blocks, the fused launch is decomposed into an
+interior launch plus two one-block edge launches; the interior launch
+depends on no collective, so XLA is free to run the exchange on the ICI
+links while the interior blocks compute. Each block's stripe is
+assembled from exactly the same values either way, which keeps the
+decomposition bitwise identical to the monolithic launch; shards
+shorter than three blocks fall back to the monolithic
+exchange-then-compute path. The mesh runs one launch per ``m`` steps:
+its launch reads the column guards in every block.
 
 Plans come from the shared legalizer (docs/pipeline.md §legalize) with
 per-shard accounting: ``blocking_plan(..., d=d, dx=dx)`` requires
@@ -88,6 +92,7 @@ from repro.parallel.sharding import stream_grid_pspec
 from . import tracing
 from .legalize import (
     guard_cols,
+    halo_rows,
     launch_dma_bytes,
     launch_flops,
     mesh_shape,
@@ -219,13 +224,18 @@ class ShardedStreamKernel:
     def _fn(self, steps: int, m: int, block_h: int, double_buffer: bool,
             overlap: bool, interpret: bool):
         """Build (and cache) the jitted shard_map'd run for one plan."""
+        overlap = overlap and self.dx == 1  # the mesh loop has no split
         key = (steps, m, block_h, double_buffer, overlap, interpret)
         cached = self._jitted.get(key)
         if cached is not None:
             return cached
-        local_run = (
-            self._local_run_ring if self.dx == 1 else self._local_run_mesh
-        )(steps, m, block_h, double_buffer, overlap, interpret)
+        if self.dx == 1:
+            local_run = self._local_run_ring(steps, m, block_h,
+                                             double_buffer, overlap,
+                                             interpret)
+        else:
+            local_run = self._local_run_mesh(steps, m, block_h,
+                                             double_buffer, interpret)
         spec = stream_grid_pspec(
             DEVICE_AXIS, axis_x=DEVICE_AXIS_X if self.dx > 1 else None
         )
@@ -312,151 +322,115 @@ class ShardedStreamKernel:
 
         return spd_run_sharded
 
-    def _local_run_mesh(self, steps, m, block_h, double_buffer, overlap,
-                        interpret):
-        """The 2-D mesh per-shard loop (DESIGN.md §15): column-halo
-        exchange + guard columns around the row-ring protocol, with the
-        stripe body switched to the kernel's guarded (zero-fill x)
-        variant so the guard columns stand in for the periodic x
-        wrap."""
-        from repro.kernels.spd_stream.streaming import (
-            spd_multistep_halo_streamed,
-        )
+    def _local_run_mesh(self, steps, m, block_h, double_buffer, interpret):
+        """The 2-D mesh per-shard loop (DESIGN.md §15): each launch
+        gathers its stripes from the shard and the exchanged guard
+        pieces in place and writes only the shard's own columns, and
+        the launches ping-pong between two buffers the kernel writes,
+        as on one chip (:func:`~repro.kernels.spd_stream
+        .stream_run_blocked`)."""
+        from repro.kernels.spd_stream.ops import stream_run_blocked
+        from repro.kernels.spd_stream.streaming import spd_multistep_gathered
 
-        dy, halo, halo_x = self.dy, self.halo, self.halo_x
-        dx = self.dx
-        step_fn = self.kernel._step_fn_guarded
+        dy, dx, halo = self.dy, self.dx, self.halo
+        step_fn = self.kernel._step_fn
         name = self.kernel.name
         mh = m * halo
-        mhx = m * halo_x
+        mhx = m * self.halo_x
         # Guard columns per side: the mhx exchanged columns plus zero
-        # padding up to whole half-lane tiles, so a lane-aligned shard
-        # stays lane-aligned once extended (the TPU kernel stages whole
-        # 128-lane tiles). The zero columns only widen the stale margin.
+        # padding up to whole half-lane tiles, so the two guards make
+        # whole 128-lane tiles. The zero columns only widen the stale
+        # margin where the right guard meets the left one.
         gx = guard_cols(mhx)
+        # The halo rows the stripe carries (whole 8-row tiles): the
+        # exchanged mh rows next to the shard, zero rows beyond them.
+        rows = halo_rows(mh, block_h)
         # Row-ring permutes run over DEVICE_AXIS (per mesh column);
         # column-ring permutes over DEVICE_AXIS_X (per mesh row). A
-        # size-1 row axis degenerates to the identity permute, which
-        # delivers the shard its *own* boundary rows — exactly the
-        # periodic wrap.
+        # size-1 row axis needs no row exchange: the launch wraps its
+        # halo rows within the shard, as on one chip.
         perm_dn = [(i, (i + 1) % dy) for i in range(dy)]
         perm_up = [(i, (i - 1) % dy) for i in range(dy)]
         perm_r = [(j, (j + 1) % dx) for j in range(dx)]  # right cols -> next
         perm_l = [(j, (j - 1) % dx) for j in range(dx)]  # left cols -> prev
 
+        def guard(left, right):
+            """``[right | zeros | left]``: the stripe's guard columns,
+            right after the shard's own (the stripe body wraps in x)."""
+            with jax.named_scope(tracing.ASSEMBLE):
+                pad = jnp.zeros(left.shape[:-1] + (2 * (gx - mhx),),
+                                left.dtype)
+                return jnp.concatenate([right, pad, left], axis=-1)
+
+        def exchange_x(piece):
+            """The column guard of ``piece``'s rows via the dx ring."""
+            w = piece.shape[-1]
+
+            def ship(cols, perm):
+                # Sent flat: a (…, rows, mhx) operand takes a column-major
+                # layout for the collective, which XLA would otherwise
+                # push back onto the whole shard in the launch loop (a
+                # shard-sized copy a launch); flat, it stays row-major.
+                shape = cols.shape
+                flat = cols.reshape(shape[:-2] + (shape[-2] * shape[-1],))
+                return jax.lax.ppermute(
+                    flat, DEVICE_AXIS_X, perm).reshape(shape)
+
+            with jax.named_scope(tracing.EXCHANGE):
+                left = ship(piece[..., w - mhx:], perm_r)
+                right = ship(piece[..., :mhx], perm_l)
+            return guard(left, right)
+
+        def edge(got, corner, top):
+            """One exchanged halo-row piece in the stripe's layout: the
+            received rows with their corner guard, padded with zero
+            rows away from the shard to whole tiles."""
+            with jax.named_scope(tracing.ASSEMBLE):
+                piece = got if corner is None else jnp.concatenate(
+                    [got, corner], axis=-1)
+                pad = jnp.zeros(piece.shape[:-2] + (rows - mh,)
+                                + piece.shape[-1:], piece.dtype)
+                return jnp.concatenate(
+                    [pad, piece] if top else [piece, pad], axis=-2)
+
+        def launch(cur, scal, *, m, block_h, interpret, dst=None):
+            lh = cur.shape[-2]
+            # All first-hop collectives depend only on `cur` and are
+            # issued together: the column exchange and, on a sharded
+            # row axis, the row exchange (guard rows at local width).
+            guards = exchange_x(cur) if mhx else None
+            edges = None
+            if mh and dy > 1:
+                with jax.named_scope(tracing.EXCHANGE):
+                    up = jax.lax.ppermute(
+                        cur[..., lh - mh:, :], DEVICE_AXIS, perm_dn
+                    )
+                    dn = jax.lax.ppermute(
+                        cur[..., :mh, :], DEVICE_AXIS, perm_up
+                    )
+                # Corner second hop (DESIGN.md §15): column-permute the
+                # received row guards' edges, which fetches the diagonal
+                # neighbors' (mh, mhx) corner blocks — the same values a
+                # width-extended row exchange would have shipped, but
+                # only (mh × mhx) elements per link.
+                edges = (
+                    edge(up, exchange_x(up) if mhx else None, top=True),
+                    edge(dn, exchange_x(dn) if mhx else None, top=False),
+                )
+            with jax.named_scope(tracing.LAUNCH):
+                return spd_multistep_gathered(
+                    step_fn, cur, scal, m=m, block_h=block_h, halo=halo,
+                    guards=guards, edges=edges,
+                    double_buffer=double_buffer, interpret=interpret,
+                    name=name, dst=dst,
+                )
+
         # Named for the compiled module (jit_spd_run_sharded).
         def spd_run_sharded(local, scal):
-            p, lh, w = local.shape
-            nblk = lh // block_h
-
-            def shard_launch(ext, scal):
-                with jax.named_scope(tracing.LAUNCH):
-                    return spd_multistep_halo_streamed(
-                        step_fn, ext, scal, m=m, block_h=block_h,
-                        halo=halo, double_buffer=double_buffer,
-                        interpret=interpret, name=name,
-                    )
-
-            def widen(left, mid, right):
-                """[zero pad | left | mid | right | zero pad] along x."""
-                with jax.named_scope(tracing.ASSEMBLE):
-                    pad = jnp.zeros(mid.shape[:2] + (gx - mhx,), mid.dtype)
-                    return jnp.concatenate([pad, left, mid, right, pad],
-                                           axis=2)
-
-            def exchange_x(cur):
-                """[left-guard | local | right-guard] via the dx ring."""
-                with jax.named_scope(tracing.EXCHANGE):
-                    left = jax.lax.ppermute(
-                        cur[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                    )
-                    right = jax.lax.ppermute(
-                        cur[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                    )
-                return widen(left, cur, right)
-
-            def crop(out):
-                """The shard's own columns of an extended-width launch."""
-                with jax.named_scope(tracing.ASSEMBLE):
-                    return out[:, :, gx:gx + w] if gx else out
-
-            def body(_, cur):
-                if mh == 0 and mhx == 0:
-                    # Elementwise core: shards never read each other.
-                    return shard_launch(cur, scal)
-                if mh == 0:
-                    # x-only stencil: column exchange, launch over the
-                    # extended width, crop the stale guard columns.
-                    return crop(shard_launch(exchange_x(cur), scal))
-                # All first-hop collectives depend only on `cur` and are
-                # issued together: the row exchange (guard rows at local
-                # width) and, when the core reads in x, the column
-                # exchange.
-                with jax.named_scope(tracing.EXCHANGE):
-                    up0 = jax.lax.ppermute(
-                        cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
-                    )
-                    dn0 = jax.lax.ppermute(
-                        cur[:, :mh, :], DEVICE_AXIS, perm_up
-                    )
-                if mhx:
-                    curx = exchange_x(cur)
-                    # Corner second hop (DESIGN.md §15): column-permute
-                    # the received row guards' edges, which fetches the
-                    # diagonal neighbors' (mh, mhx) corner blocks — the
-                    # same values a width-extended row exchange would
-                    # have shipped, but only (mh × mhx) elements per
-                    # link.
-                    with jax.named_scope(tracing.EXCHANGE):
-                        ul = jax.lax.ppermute(
-                            up0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                        )
-                        ur = jax.lax.ppermute(
-                            up0[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                        )
-                        dl = jax.lax.ppermute(
-                            dn0[:, :, w - mhx:], DEVICE_AXIS_X, perm_r
-                        )
-                        dr = jax.lax.ppermute(
-                            dn0[:, :, :mhx], DEVICE_AXIS_X, perm_l
-                        )
-                    upx = widen(ul, up0, ur)
-                    dnx = widen(dl, dn0, dr)
-                else:
-                    curx, upx, dnx = cur, up0, dn0
-                wx = w + 2 * gx
-                with jax.named_scope(tracing.ASSEMBLE):
-                    pad = jnp.zeros((p, block_h - mh, wx), cur.dtype)
-                if overlap and nblk >= 3:
-                    # Overlap generalization (DESIGN.md §15): the
-                    # interior blocks span the full (extended) shard
-                    # width, so they depend on the column exchange but
-                    # NOT on the row exchange or the corner hop — the
-                    # interior launch runs while those are in flight.
-                    # Every block's stripe assembles the same values as
-                    # the monolithic launch below: bitwise identical.
-                    interior = shard_launch(curx, scal)
-                    with jax.named_scope(tracing.ASSEMBLE):
-                        ext_top = jnp.concatenate(
-                            [pad, upx, curx[:, :2 * block_h, :]], axis=1
-                        )
-                        ext_bot = jnp.concatenate(
-                            [curx[:, lh - 2 * block_h:, :], dnx, pad],
-                            axis=1,
-                        )
-                    top = shard_launch(ext_top, scal)
-                    bot = shard_launch(ext_bot, scal)
-                    with jax.named_scope(tracing.ASSEMBLE):
-                        out = jnp.concatenate([top, interior, bot], axis=1)
-                else:
-                    with jax.named_scope(tracing.ASSEMBLE):
-                        ext = jnp.concatenate(
-                            [pad, upx, curx, dnx, pad], axis=1
-                        )
-                    out = shard_launch(ext, scal)
-                return crop(out)
-
-            return jax.lax.fori_loop(0, steps // m, body, local)
+            return stream_run_blocked(
+                launch, local, scal, steps=steps, m=m, block_h=block_h,
+                interpret=interpret,
+            )
 
         return spd_run_sharded
 
@@ -470,8 +444,10 @@ class ShardedStreamKernel:
 
         ``double_buffer`` selects the per-shard streamed launch's buffer
         protocol (docs/pipeline.md §stream); ``overlap`` toggles the
-        exchange/compute overlap decomposition (docs/pipeline.md
-        §overlap, default: the kernel's construction-time setting).
+        ring's exchange/compute overlap decomposition (docs/pipeline.md
+        §overlap, default: the kernel's construction-time setting). The
+        mesh loop (``dx > 1``) runs one launch per ``m`` steps either
+        way. ``state`` is only read.
         """
         if self.d == 1:
             return self.kernel.run_blocked(
@@ -506,10 +482,11 @@ class ShardedStreamKernel:
                           bool(overlap), interpret)
             out = fn(state, self.kernel._scal(regs))
             launches = steps // m
-            # Every shard's launch moves and computes the same rows; the
-            # interior and edge launches of the overlapped exchange move
-            # and compute, together, what one monolithic launch over the
-            # extended shard does.
+            # Every shard's launch moves and computes the same rows: on
+            # the ring, the interior and edge launches of the overlapped
+            # exchange move and compute, together, what one monolithic
+            # launch over the extended shard does; on the mesh, a launch
+            # gathers and computes its stripes at the guarded width.
             width = stripe_cols(local_w, m, self.halo_x if self.dx > 1 else 0)
             shard_bytes = launch_dma_bytes(
                 local_h, width, p, block_h=block_h, m=m, halo=self.halo,
@@ -517,9 +494,13 @@ class ShardedStreamKernel:
             shard_flops = launch_flops(
                 local_h, width, 1, block_h=block_h, m=m, halo=self.halo,
                 flops=self.kernel.compiled.flops)
+            # The mesh loop ping-pongs two kernel-written buffers, as on
+            # one chip; the ring loop recycles none.
             tracing.count(launches=launches, steps=steps,
                           dma_bytes=launches * self.d * shard_bytes,
-                          kernel_flops=launches * self.d * shard_flops)
+                          kernel_flops=launches * self.d * shard_flops,
+                          aliased_launches=max(0, launches - 2)
+                          if self.dx > 1 else 0)
         return out
 
     def run_for_point(self, state, regs: Sequence = (), *, point,

@@ -2,9 +2,9 @@
 
 Device-side names: every fused launch runs under the
 ``jax.named_scope`` :data:`LAUNCH`, the halo exchange's collectives and
-the slices that feed them under :data:`EXCHANGE`, and every
-concatenate, pad or crop of a shard-sized array under :data:`ASSEMBLE`
-(``repro.core.distribute``). XLA writes the scope into each op's
+the slices that feed them under :data:`EXCHANGE`, and the assembly of
+what was exchanged under :data:`ASSEMBLE` (``repro.core.distribute``:
+the ring's guard-block concatenates, the mesh's guard-sized pieces). XLA writes the scope into each op's
 ``op_name``, which the profiler keeps as the op's ``tf_op``, so an op
 the compiler inserts on its own (a loop-carry copy) is the one left
 with no ``spd.*`` scope. The kernel itself is named by
@@ -23,9 +23,10 @@ Counters (process-wide, read with :func:`snapshot`):
   from the compiled core's per-site count, summed over shards and over
   a program's fused cores);
 * ``aliased_launches``: those launches that wrote into a buffer the
-  one-chip launch loop recycles (``input_output_aliases``) rather than
-  a new one: ``max(0, launches - 2)`` per ``StreamKernel.run_blocked``
-  call (:func:`repro.kernels.spd_stream.stream_run_blocked`);
+  launch loop recycles (``input_output_aliases``) rather than a new
+  one: ``max(0, launches - 2)`` per ``StreamKernel.run_blocked`` call
+  and per mesh ``ShardedStreamKernel.run_blocked`` call
+  (:func:`repro.kernels.spd_stream.stream_run_blocked`);
 * ``jit_traces``: jaxpr traces, one per jit cache miss of any function
   in the process (the program's and its caller's alike);
 * ``jit_s``: seconds spent tracing, lowering and compiling (a
@@ -50,7 +51,7 @@ RUN = "spd.run"
 LAUNCH = "spd.launch"
 #: Device scope of the mesh's halo exchange: ppermutes and their slices.
 EXCHANGE = "spd.exchange"
-#: Device scope of shard-sized concatenates, pads and crops.
+#: Device scope of the exchanged halo's assembly: concatenates and pads.
 ASSEMBLE = "spd.assemble"
 
 #: The JAX compile stages whose spans ``jit_s`` unites; the first is

@@ -22,13 +22,10 @@ index maps: block 0's "previous block" is the up guard block, the last
 block's "next block" is the down guard block. One code path, one
 bit-for-bit stripe assembly, on- or off-device.
 
-Under a 2-D device mesh (DESIGN.md §15) the launch is width-agnostic:
-when columns are sharded too (``dx > 1``), ``repro.core.distribute``
-hands in an extended-*width* shard ``W/dx + 2·m·halo_x`` whose guard
-columns were column-exchanged, ``step_fn`` is the guarded
-(``periodic_x=False``) stripe body from ``repro.core.codegen``, and
-the caller crops the advanced shard back to ``W/dx`` — nothing here
-changes, the guard columns ride along inside ``W``.
+A 2-D device mesh (DESIGN.md §15) does not come through here: its
+launch gathers each stripe from the shard and the exchanged guard
+pieces in place
+(:func:`repro.kernels.spd_stream.streaming.spd_multistep_gathered`).
 """
 
 from __future__ import annotations

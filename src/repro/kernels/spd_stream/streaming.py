@@ -34,14 +34,18 @@ Like the BlockSpec launch, state may carry extra leading dimensions —
 (docs/pipeline.md §serve, DESIGN.md §13): rows stay on axis ``-2``,
 every stripe DMA moves all leading axes whole, and the VMEM scratch
 stacks scale by B exactly as the legalizer's
-``stripe_vmem_bytes(..., b=B)`` prices them. The width axis is opaque
-the same way: under a column-sharded mesh (``dx > 1``, DESIGN.md §15)
-``W`` arrives guard-column-extended to ``W/dx + 2·guard_cols(m·halo_x)``
-and the legalizer prices the stripes at that width
-(``stripe_vmem_bytes(..., halo_x=)``); the walk itself is unchanged.
-Compiled for the TPU, the launch width must be a multiple of 128 lanes
-and ``block_h`` a multiple of 8 rows (a ``ValueError`` says so before
-the compiler does).
+``stripe_vmem_bytes(..., b=B)`` prices them. Each stripe is gathered
+from a list of pieces (:class:`Piece`), each one DMA from one source
+array into a window of the stripe buffer: on one chip the three pieces
+above, from the state itself. Under a column-sharded mesh (``dx > 1``,
+DESIGN.md §15) :func:`spd_multistep_gathered` gathers a stripe
+``W/dx + 2·guard_cols(m·halo_x)`` lanes wide from the shard and the
+exchanged guard pieces where they lie, and writes back only the
+shard's own ``W/dx`` columns; the legalizer prices the stripes at that
+width (``stripe_vmem_bytes(..., halo_x=)``). Compiled for the TPU,
+every piece's width and the stripe's must be multiples of 128 lanes and
+``block_h`` a multiple of 8 rows (a ``ValueError`` says so before the
+compiler does).
 
 Halo rows are carried in whole sublane tiles: ``mh`` is ``m·halo``
 rounded up to a multiple of 8 rows (capped at ``block_h``,
@@ -57,7 +61,7 @@ limit the legalizer prices against
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -73,47 +77,86 @@ from repro.core.legalize import (
 )
 
 
-def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
-                   step_fn: Callable, m: int, block_h: int, mh: int,
-                   nblk: int, nbuf: int, src_starts: Callable):
+class Piece(NamedTuple):
+    """One DMA of a stripe: ``size`` rows of source ref ``src`` from row
+    ``start(i)`` into the stripe buffer at row ``row`` and lane ``col``.
+
+    ``start`` and ``when`` take the (traced) block index; ``when`` says
+    whether block ``i`` reads the piece at all (``None``: every block
+    does). A source as wide as the stripe fills all its lanes.
+    """
+
+    src: int
+    start: Callable
+    size: int
+    row: int
+    col: int = 0
+    when: Callable | None = None
+
+
+def _stream_kernel(scal_ref, *refs, step_fn: Callable, m: int, block_h: int,
+                   mh: int, nblk: int, nbuf: int, pieces: tuple,
+                   nsrc: int, aliased: bool):
     """One-program streaming walk over ``nblk`` row blocks.
 
-    ``buf``/``obuf`` are ``(nbuf, …)`` VMEM scratch stacks; ``insem`` /
-    ``outsem`` the matching DMA semaphore stacks. ``src_starts(i)``
-    maps a (traced) block index to the three source-row offsets of its
-    stripe pieces in ``state_ref`` — periodic or guard-block-extended.
-    Rows are addressed on axis ``-2``; any leading (batch) axes are
-    copied whole per stripe piece.
+    ``refs`` are the ``nsrc`` source refs, the aliased destination when
+    ``aliased`` (never read: the output is its buffer), the output ref,
+    the ``(nbuf, …)`` VMEM stripe and output stacks and their DMA
+    semaphore stacks. Block ``i``'s stripe is gathered from the sources
+    by the :class:`Piece` list ``pieces``, one semaphore a piece. Rows
+    are addressed on axis ``-2``; any leading (batch) axes are copied
+    whole per stripe piece. The output holds the centre rows of each
+    stripe's first ``out_ref.shape[-1]`` lanes.
     """
+    srcs = refs[:nsrc]
+    out_ref, buf, obuf, insem, outsem = refs[nsrc + aliased:]
     regs = tuple(scal_ref[i] for i in range(scal_ref.shape[0]))
     # Full-slice prefix covering the leading axes (P, or B and P when
-    # batched): state_ref is (…, H, W), buf slots are (…, rows, W).
-    lead = (slice(None),) * (len(state_ref.shape) - 2)
+    # batched): out_ref is (…, H, W), buf slots are (…, rows, W).
+    lead = (slice(None),) * (len(out_ref.shape) - 2)
+    width = buf.shape[-1]
+    out_w = out_ref.shape[-1]
 
-    def rows(ref, start, size, slot=None):
+    def rows(ref, start, size, slot=None, col=0, cols=None):
         """``ref`` restricted to ``size`` rows from ``start`` on axis -2
-        (optionally under a scratch-stack ``slot`` index)."""
-        idx = lead + (pl.ds(start, size), slice(None))
+        (and to ``cols`` lanes from ``col``; optionally under a
+        scratch-stack ``slot`` index)."""
+        lanes = slice(None) if cols is None else pl.ds(col, cols)
+        idx = lead + (pl.ds(start, size), lanes)
         if slot is not None:
             idx = (slot,) + idx
         return ref.at[idx]
 
     def dma_in(slot, i):
-        up, center, down = src_starts(i)
-        copies = [
-            pltpu.make_async_copy(
-                rows(state_ref, center, block_h),
-                rows(buf, mh, block_h, slot), insem.at[slot, 0]),
-        ]
-        if mh:
-            copies.append(pltpu.make_async_copy(
-                rows(state_ref, up, mh),
-                rows(buf, 0, mh, slot), insem.at[slot, 1]))
-            copies.append(pltpu.make_async_copy(
-                rows(state_ref, down, mh),
-                rows(buf, mh + block_h, mh, slot),
-                insem.at[slot, 2]))
+        """Block ``i``'s stripe DMAs as ``(when, copy)`` pairs; ``when``
+        is ``True`` for a piece every block reads, and pieces that a
+        block known at trace time does not read are left out."""
+        copies = []
+        for k, pc in enumerate(pieces):
+            when = True if pc.when is None else pc.when(i)
+            if when is False:
+                continue
+            src = srcs[pc.src]
+            w = src.shape[-1]
+            copies.append((when, pltpu.make_async_copy(
+                rows(src, pc.start(i), pc.size),
+                rows(buf, pc.row, pc.size, slot, pc.col,
+                     None if w == width else w),
+                insem.at[slot, k])))
         return copies
+
+    def each(copies, op):
+        for when, c in copies:
+            if when is True:
+                op(c)
+            else:
+                pl.when(when)(functools.partial(op, c))
+
+    def start(c):
+        c.start()
+
+    def wait(c):
+        c.wait()
 
     def dma_out(slot, blk):
         return pltpu.make_async_copy(
@@ -123,8 +166,7 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
     if nbuf > 1:
         # Prime the pipeline: block 0's stripe is in flight before the
         # block loop starts.
-        for c in dma_in(0, 0):
-            c.start()
+        each(dma_in(0, 0), start)
 
     def body(i, carry):
         slot = jax.lax.rem(i, nbuf)
@@ -135,15 +177,12 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
 
             @pl.when(i + 1 < nblk)
             def _():
-                for c in dma_in(nxt, i + 1):
-                    c.start()
+                each(dma_in(nxt, i + 1), start)
         else:
             # Single buffer: the one stripe buffer is only free once the
             # previous block fully finished, so start→wait→compute.
-            for c in dma_in(slot, i):
-                c.start()
-        for c in dma_in(slot, i):
-            c.wait()
+            each(dma_in(slot, i), start)
+        each(dma_in(slot, i), wait)
         f_ext = buf[slot]
         for _ in range(m):
             f_ext = step_fn(f_ext, regs)
@@ -154,7 +193,8 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
         def _():
             dma_out(slot, i - nbuf).wait()
 
-        obuf[slot] = f_ext[..., mh:mh + block_h, :]
+        centre = f_ext[..., mh:mh + block_h, :]
+        obuf[slot] = centre if out_w == width else centre[..., :out_w]
         dma_out(slot, i).start()
         return carry
 
@@ -173,30 +213,34 @@ def _stream_kernel(scal_ref, state_ref, out_ref, buf, obuf, insem, outsem, *,
     jax.lax.fori_loop(0, nbuf, drain, 0)
 
 
-def _into_dst(scal_ref, state_ref, _dst_ref, *refs, **kw):
-    """:func:`_stream_kernel` with a destination input that it never
-    reads: the output is that input's buffer (``input_output_aliases``),
-    and every row of it is written."""
-    _stream_kernel(scal_ref, state_ref, *refs, **kw)
+def _streamed_call(step_fn, srcs, scal, *, m, block_h, mh, nblk, nbuf,
+                   out_h, pieces, interpret, name, dst=None):
+    """Launch :func:`_stream_kernel` over the source arrays ``srcs``.
 
-
-def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
-                   out_h, src_starts, interpret, name, dst=None):
+    The stripe is as wide as the pieces that fill it (``srcs[0]`` plus
+    any pieces at lanes past it); the output is ``srcs[0]``'s leading
+    axes by ``out_h`` rows by ``srcs[0]``'s width, written into ``dst``
+    when one is given.
+    """
+    state = srcs[0]
     *lead, _, w = state.shape
+    width = max(pc.col + srcs[pc.src].shape[-1] for pc in pieces)
     interpret = resolve_interpret(interpret)
-    if not interpret and (w % LANES or block_h % SUBLANE_ROWS):
+    widths = sorted({width, *(src.shape[-1] for src in srcs)})
+    if not interpret and (any(v % LANES for v in widths)
+                          or block_h % SUBLANE_ROWS):
         raise ValueError(
             f"the TPU kernel stages whole ({SUBLANE_ROWS}, {LANES}) tiles: "
-            f"width {w} must be a multiple of {LANES} and block_h "
-            f"{block_h} a multiple of {SUBLANE_ROWS}"
+            f"widths {widths} must be multiples of {LANES} and "
+            f"block_h {block_h} a multiple of {SUBLANE_ROWS}"
         )
     rows = block_h + 2 * mh
-    operands = (scal, state) if dst is None else (scal, state, dst)
+    operands = (scal, *srcs) if dst is None else (scal, *srcs, dst)
     return pl.pallas_call(
         functools.partial(
-            _stream_kernel if dst is None else _into_dst, step_fn=step_fn,
-            m=m, block_h=block_h, mh=mh, nblk=nblk, nbuf=nbuf,
-            src_starts=src_starts,
+            _stream_kernel, step_fn=step_fn, m=m, block_h=block_h, mh=mh,
+            nblk=nblk, nbuf=nbuf, pieces=tuple(pieces), nsrc=len(srcs),
+            aliased=dst is not None,
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -204,11 +248,11 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((*lead, out_h, w), state.dtype),
-        input_output_aliases={} if dst is None else {2: 0},
+        input_output_aliases={} if dst is None else {len(srcs) + 1: 0},
         scratch_shapes=[
-            pltpu.VMEM((nbuf, *lead, rows, w), state.dtype),
+            pltpu.VMEM((nbuf, *lead, rows, width), state.dtype),
             pltpu.VMEM((nbuf, *lead, block_h, w), state.dtype),
-            pltpu.SemaphoreType.DMA((nbuf, 3 if mh else 1)),
+            pltpu.SemaphoreType.DMA((nbuf, len(pieces))),
             pltpu.SemaphoreType.DMA((nbuf,)),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -217,6 +261,24 @@ def _streamed_call(step_fn, state, scal, *, m, block_h, mh, nblk, nbuf,
         interpret=interpret,
         name=name,
     )(*operands)
+
+
+def _periodic_pieces(src, *, block_h, mh, nblk, col=0, edges=False):
+    """Centre, up-halo and down-halo pieces of source ``src``, periodic
+    in y: block i's up halo is the tail of block i-1 (mod), its down
+    halo the head of block i+1 (mod). With ``edges``, the first block
+    reads no up halo and the last no down halo from ``src``."""
+    pieces = [Piece(src, lambda i: i * block_h, block_h, mh, col)]
+    if mh:
+        pieces += [
+            Piece(src, lambda i: jnp.mod(i - 1, nblk) * block_h
+                  + (block_h - mh), mh, 0, col,
+                  (lambda i: i > 0) if edges else None),
+            Piece(src, lambda i: jnp.mod(i + 1, nblk) * block_h, mh,
+                  mh + block_h, col,
+                  (lambda i: i < nblk - 1) if edges else None),
+        ]
+    return pieces
 
 
 def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
@@ -249,19 +311,11 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
         )
     mh = halo_rows(m * halo, block_h)
     nblk = h // block_h
-    nbuf = 2 if double_buffer else 1
-
-    def src_starts(i):
-        # Periodic y: block i's up halo is the tail of block i-1 (mod),
-        # its down halo the head of block i+1 (mod).
-        up = jnp.mod(i - 1, nblk) * block_h + (block_h - mh)
-        down = jnp.mod(i + 1, nblk) * block_h
-        return up, i * block_h, down
-
     return _streamed_call(
-        step_fn, state, scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
-        nbuf=nbuf, out_h=h, src_starts=src_starts, interpret=interpret,
-        name=name, dst=dst,
+        step_fn, (state,), scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
+        nbuf=2 if double_buffer else 1, out_h=h,
+        pieces=_periodic_pieces(0, block_h=block_h, mh=mh, nblk=nblk),
+        interpret=interpret, name=name, dst=dst,
     )
 
 
@@ -298,12 +352,69 @@ def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
     mh = halo_rows(m * halo, block_h)
     nblk = local_h // block_h
 
-    def src_starts(i):
-        center = (i + 1) * block_h
-        return center - mh, center, (i + 2) * block_h
-
+    pieces = [
+        Piece(0, lambda i: (i + 1) * block_h, block_h, mh),
+        Piece(0, lambda i: (i + 1) * block_h - mh, mh, 0),
+        Piece(0, lambda i: (i + 2) * block_h, mh, mh + block_h),
+    ]
     return _streamed_call(
-        step_fn, ext, scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
-        nbuf=2 if double_buffer else 1, out_h=local_h,
-        src_starts=src_starts, interpret=interpret, name=name,
+        step_fn, (ext,), scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
+        nbuf=2 if double_buffer else 1, out_h=local_h, pieces=pieces,
+        interpret=interpret, name=name,
+    )
+
+
+def spd_multistep_gathered(step_fn: Callable, state, scal, *, m: int,
+                           block_h: int, halo: int, guards=None,
+                           edges=None, double_buffer: bool = True,
+                           interpret: bool | None = None,
+                           name: str | None = None, dst=None):
+    """Streamed fused m-step launch over one shard of a device mesh,
+    its stripes gathered from the exchanged pieces in place.
+
+    ``state`` is the shard's own ``(…, H, W)`` rows, read as it is. The
+    stripe is laid out ``[shard W | guards]`` and advanced by the
+    *periodic* stripe body: ``guards`` is a ``(…, H, G)`` array whose
+    first columns are the right neighbour's first columns and whose
+    last columns are the left neighbour's last ones, so shard column 0
+    wraps onto its true left neighbour and column ``W - 1`` runs into
+    its true right one; the two guards meet in the middle of ``G``,
+    where the stale columns stay. ``edges``, for a mesh whose row axis
+    is sharded, is the pair of ``(…, mh, W + G)`` halo-row pieces that
+    block 0 reads above it and the last block reads below it, in the
+    stripe's layout (``mh`` = :func:`~repro.core.legalize.halo_rows`);
+    without it the halo rows are periodic within ``state`` and
+    ``guards``. The output is the shard's own ``(…, H, W)``, written
+    into ``dst`` when one is given, as in :func:`spd_multistep_streamed`
+    (docs/pipeline.md §distribute, DESIGN.md §15).
+    """
+    *_, h, w = state.shape
+    if h % block_h:
+        raise ValueError(f"H={h} must be divisible by block_h={block_h}")
+    if m * halo > block_h:
+        raise ValueError(
+            f"m*halo={m * halo} must be <= block_h={block_h} (halo source)"
+        )
+    mh = halo_rows(m * halo, block_h)
+    nblk = h // block_h
+    edged = edges is not None and mh > 0
+    srcs = [state] if guards is None else [state, guards]
+    pieces = [
+        pc for k in range(len(srcs))
+        for pc in _periodic_pieces(k, block_h=block_h, mh=mh, nblk=nblk,
+                                   col=w if k else 0, edges=edged)
+    ]
+    if edged:
+        # Block 0's up halo and the last block's down halo are the
+        # exchanged rows; every other block's stay in the shard.
+        pieces += [
+            Piece(len(srcs), lambda i: 0, mh, 0, when=lambda i: i == 0),
+            Piece(len(srcs) + 1, lambda i: 0, mh, mh + block_h,
+                  when=lambda i: i == nblk - 1),
+        ]
+        srcs += list(edges)
+    return _streamed_call(
+        step_fn, tuple(srcs), scal, m=m, block_h=block_h, mh=mh,
+        nblk=nblk, nbuf=2 if double_buffer else 1, out_h=h, pieces=pieces,
+        interpret=interpret, name=name, dst=dst,
     )

@@ -106,33 +106,100 @@ def test_halo_extended_shard_launch_compiles(one_chip, kernels):
     )
 
 
-@pytest.mark.parametrize("app,n,block_h,m", [
-    ("diffusion", 2048, 32, 2),
-    ("lbm", 2048, 16, 1),
-])
-def test_sharded_2x2_mesh_compiles(topo, kernels, app, n, block_h, m):
+def _mesh_text(topo, kern, dy, dx, n, block_h, m, launches):
+    """The compiled ``jit_spd_run_sharded`` of an n² grid on a (dy, dx)
+    mesh of the described chips, ``launches`` launches of plan
+    (block_h, m)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.distribute import ShardedStreamKernel
     from repro.parallel.sharding import stream_grid_pspec
 
-    kern = kernels[app]
-    sk = ShardedStreamKernel(kern, 4, devices=topo.devices, dx=2)
-    assert sk.mesh.devices.shape == (2, 2)
+    sk = ShardedStreamKernel(kern, dy * dx, devices=topo.devices, dx=dx)
+    assert sk.mesh.devices.shape == (dy, dx)
     spec = stream_grid_pspec("d", axis_x="dx")
     state = jax.ShapeDtypeStruct((len(kern._ports), n, n), jnp.float32,
                                  sharding=NamedSharding(sk.mesh, spec))
     scal = jax.ShapeDtypeStruct((max(1, len(kern._regs)),), jnp.float32,
                                 sharding=NamedSharding(sk.mesh, P(None)))
-    fn = sk._fn(2 * m, m, block_h, True, True, False)
-    compiled = fn.lower(state, scal).compile()
-    text = compiled.as_text()
+    fn = sk._fn(launches * m, m, block_h, True, True, False)
+    return fn.lower(state, scal).compile().as_text()
+
+
+#: Ops that may hold a shard-sized value without moving it: the loop
+#: and its tuples, parameters and bitcasts. A kernel is the one
+#: custom call that may write one.
+PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast"}
+
+
+def _shard_sized_ops(text, shard):
+    """Every op in ``text`` other than a kernel or a pass-through whose
+    f32 result holds at least ``shard`` elements."""
+    import math
+    import re
+
+    found = []
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%(\S+) = f32\[([\d,]*)\]\S* ([\w-]+)\(",
+                      line)
+        if not op or op.group(3) in PASS_THROUGH:
+            continue
+        if op.group(3) == "custom-call" and "tpu_custom_call" in line:
+            continue
+        if math.prod(int(v) for v in op.group(2).split(",") if v) >= shard:
+            found.append(f"{op.group(3)} %{op.group(1)} f32[{op.group(2)}]")
+    return found
+
+
+@pytest.mark.parametrize("app,n,block_h,m", [
+    ("diffusion", 2048, 32, 2),
+    ("lbm", 2048, 16, 1),
+])
+def test_sharded_2x2_mesh_compiles(topo, kernels, app, n, block_h, m):
+    kern = kernels[app]
+    text = _mesh_text(topo, kern, 2, 2, n, block_h, m, 2)
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
-    # The exchange and the shard assembly carry the program's scopes.
+    # The exchange and the guard pieces' assembly carry the program's
+    # scopes.
     assert text.startswith("HloModule jit_spd_run_sharded")
     assert "/spd.exchange/ppermute" in text
     assert "/spd.assemble/concatenate" in text
+
+
+#: (app, n, block_h, m): grids whose shards do not fit in VMEM, so the
+#: compiler leaves them in HBM as it does at full size.
+MESH_LOOPS = [("lbm", 2048, 16, 2), ("diffusion", 8192, 32, 2)]
+
+
+@pytest.mark.parametrize("launches", [16, 17])
+@pytest.mark.parametrize("dy,dx", [(1, 4), (2, 2)], ids=["1x4", "2x2"])
+@pytest.mark.parametrize("app,n,block_h,m", MESH_LOOPS,
+                         ids=[c[0] for c in MESH_LOOPS])
+def test_mesh_loop_copies_no_state(topo, kernels, app, n, block_h, m, dy,
+                                   dx, launches):
+    """The four-chip twin of ``test_run_blocked_copies_no_state``: each
+    launch gathers its stripes from the shard and the guard pieces in
+    place and writes its own columns into a buffer the loop recycles,
+    so the compiled loop holds no copy, concatenate or fusion of a
+    shard's size, neither from the loop carry nor from the caller's
+    input. Every kernel is ``spd_<core>`` under ``spd.launch``, every
+    collective permute under ``spd.exchange``."""
+    import re
+
+    kern = kernels[app]
+    text = _mesh_text(topo, kern, dy, dx, n, block_h, m, launches)
+    shard = len(kern._ports) * (n // dy) * (n // dx)
+    assert not _shard_sized_ops(text, shard)
+    _assert_kernel_named_under_launch_scope(kern, text,
+                                            "jit_spd_run_sharded")
+    permutes = [line for line in text.splitlines()
+                if re.search(r" collective-permute(-start)?\(", line)]
+    assert permutes
+    for line in permutes:
+        assert "/spd.exchange/" in re.search(r'op_name="([^"]*)"',
+                                             line).group(1)
 
 
 def _run_blocked_text(kern, one_chip, n, steps):
@@ -144,10 +211,11 @@ def _run_blocked_text(kern, one_chip, n, steps):
         interpret=False).compile().as_text()
 
 
-def _assert_kernel_named_under_launch_scope(kern, text):
+def _assert_kernel_named_under_launch_scope(kern, text,
+                                            module="jit_spd_run_blocked"):
     import re
 
-    assert text.startswith("HloModule jit_spd_run_blocked")
+    assert text.startswith(f"HloModule {module}")
     calls = [line for line in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert calls
