@@ -262,56 +262,6 @@ def run(topk: int = 3, interpret: bool | None = None, reps: int = 3,
             **sres.as_dict(), "vs_exhaustive_best": float(ratio),
         }
 
-    # Overlapped halo exchange (docs/pipeline.md §overlap): time each
-    # app's sharded kernel with the exchange overlapped against interior
-    # compute vs the monolithic launch, same plan, same honest harness.
-    # Wall clock only — the bitwise contract is tests/test_distribute.py's.
-    overlap_bench: dict = {}
-    if jax.device_count() >= 2:
-        from repro.core.measure import time_run
-
-        out.append(
-            "\n## DSE sweep 2g: overlapped vs monolithic halo exchange "
-            "(d=2, per app)"
-        )
-        ov_bh, ov_m = 16, 2  # 128-row shards -> nblk=8 >= 3: overlap engages
-        for name, kern, state, regs in (
-            ("lbm", msim.stream_kernel(), mstate, mregs),
-            ("diffusion", dsim.kernel, dsim.state(u0), (dsim.alpha,)),
-        ):
-            sk = kern.sharded(2)
-            walls = {}
-            for overlap in (True, False):
-                timing = time_run(
-                    lambda: sk.run_blocked(
-                        state, regs, steps=ov_m, m=ov_m, block_h=ov_bh,
-                        overlap=overlap, interpret=interpret,
-                    ),
-                    reps=reps, warmup=1,
-                )
-                walls["on" if overlap else "off"] = float(timing.wall_s)
-            overlap_bench[name] = {
-                "d": 2, "block_h": ov_bh, "m": ov_m,
-                "overlap_on_s": walls["on"], "overlap_off_s": walls["off"],
-            }
-            out.append(
-                f"  {name}: overlap on {walls['on']*1e3:.2f} ms vs "
-                f"off {walls['off']*1e3:.2f} ms per {ov_m}-step launch "
-                f"(block_h={ov_bh}, d=2)"
-            )
-        if interpret:
-            out.append(
-                "(interpret mode serializes the would-be concurrent "
-                "launches; the split is recorded so the TPU run shows "
-                "the real hiding)"
-            )
-    else:
-        out.append(
-            "\n## DSE sweep 2g: overlapped halo exchange skipped — "
-            "needs >= 2 devices (XLA_FLAGS="
-            "--xla_force_host_platform_device_count=8)"
-        )
-
     # 2h --------------------------------------------------------------
     # Stream programs: the fusion partition as a measured axis
     # (docs/pipeline.md §program, DESIGN.md §14). For each program app,
@@ -626,7 +576,6 @@ def run(topk: int = 3, interpret: bool | None = None, reps: int = 3,
                 "search": sr.as_dict(),
             }
         bench["autotune"] = autotune
-        bench["overlap"] = overlap_bench
         bench["program"] = program_bench
         bench["study"] = {
             "name": study_name,

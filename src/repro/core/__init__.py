@@ -5,7 +5,7 @@ exploration engine."""
 from .codegen import CodegenError, StencilSummary, StreamKernel, stencil_summary
 from .compiler import CompiledCore, HardwareReport, Registry, SPDCompileError
 from .dfg import Core, Node, SPDError, SPDGraphError, schedule
-from .distribute import ShardedStreamKernel, device_axis_values, ring_mesh
+from .distribute import ShardedStreamKernel, device_axis_values
 from .dse import DesignPoint, FPGAModel, StreamWorkload, TPUModel
 from .explorer import Explorer, Sweep, pareto_mask
 from .legalize import (
@@ -85,7 +85,6 @@ __all__ = [
     "parse_spd",
     "parse_spd_file",
     "resolve_run_plan",
-    "ring_mesh",
     "schedule",
     "shard_height",
     "spatial_duplicate",

@@ -411,24 +411,14 @@ class StreamKernel:
         self._params = dict(core.params)
         #: The kernel's name in the compiled program and device trace.
         self.name = tracing.kernel_name(core.name)
-        from repro.kernels.spd_stream.ops import stream_run_blocked
-        from repro.kernels.spd_stream.spd_stream import spd_multistep
-        from repro.kernels.spd_stream.streaming import spd_multistep_streamed
+        from repro.kernels.spd_stream import spd_multistep, stream_run_blocked
 
         # The jitted entries are named functions, not partials, so their
         # XLA modules read jit_spd_… in the compiled program and trace.
-        def spd_multistep_blockspec(state, scal, *, m, block_h,
-                                    interpret=None):
-            with jax.named_scope(tracing.LAUNCH):
-                return spd_multistep(
-                    self._step_fn, state, scal, m=m, block_h=block_h,
-                    halo=self.halo, interpret=interpret, name=self.name,
-                )
-
         def spd_launch(state, scal, *, m, block_h, double_buffer=True,
                        interpret=None, dst=None):
             with jax.named_scope(tracing.LAUNCH):
-                return spd_multistep_streamed(
+                return spd_multistep(
                     self._step_fn, state, scal, m=m, block_h=block_h,
                     halo=self.halo, double_buffer=double_buffer,
                     interpret=interpret, name=self.name, dst=dst,
@@ -443,14 +433,8 @@ class StreamKernel:
                 interpret=interpret,
             )
 
-        # Declarative BlockSpec launch: the reference pipeline (tests
-        # compare the streamed path against it bit for bit).
-        self._multistep = jax.jit(
-            spd_multistep_blockspec,
-            static_argnames=("m", "block_h", "interpret"),
-        )
-        # Manually pipelined launch (docs/pipeline.md §stream): the
-        # execution path, with double_buffer a real plan knob.
+        # Manually pipelined launch (docs/pipeline.md §stream), with
+        # double_buffer a real plan knob.
         self._streamed = jax.jit(
             spd_launch,
             static_argnames=("m", "block_h", "double_buffer", "interpret"),
@@ -524,7 +508,7 @@ class StreamKernel:
 
         ``double_buffer`` selects the streamed launch's buffer protocol
         (ping/pong vs single-buffer, docs/pipeline.md §stream); both are
-        bitwise identical to the declarative BlockSpec launch.
+        bitwise identical to :meth:`reference`.
         """
         with jax.profiler.TraceAnnotation(tracing.RUN):
             out = self._streamed(

@@ -6,17 +6,16 @@ production-scale continuation (DESIGN.md §8, §15, docs/pipeline.md
 §distribute): duplicate across *chips*. A codegen'd
 :class:`~repro.core.codegen.StreamKernel`'s ``(P, H, W)`` grid is
 decomposed across a 2-D device mesh ``(dy, dx)``: rows split into ``dy``
-equal shards on the row axis (the original one-axis ring) and columns
-into ``dx`` equal shards on the column axis, ``d = dy·dx`` devices in
-total. Every device runs the same temporal-blocking Pallas launch on its
-own ``(H/dy, W/dx)`` shard under ``shard_map``, and before each fused
-m-step launch the boundary data is exchanged with the mesh neighbors via
-``lax.ppermute`` (both axes are rings, which is what makes the global
-periodic boundary come out right: shard 0's up-neighbor is shard dy-1,
-and column shard 0's left-neighbor is column shard dx-1).
+equal shards on the row axis and columns into ``dx`` equal shards on
+the column axis, ``d = dy·dx`` devices in total. Every device runs the
+same temporal-blocking Pallas launch on its own ``(H/dy, W/dx)`` shard
+under ``shard_map``, and before each fused m-step launch the boundary
+data is exchanged with the mesh neighbors via ``lax.ppermute`` (both
+axes are rings, which is what makes the global periodic boundary come
+out right: shard 0's up-neighbor is shard dy-1, and column shard 0's
+left-neighbor is column shard dx-1).
 
-Halo-exchange protocol, per fused launch (DESIGN.md §8 for the row axis,
-§15 for the column axis):
+Halo-exchange protocol, per fused launch, for every mesh shape:
 
 1. each shard sends its bottom ``m·halo`` rows to the next row shard and
    its top ``m·halo`` rows to the previous one, and — when ``dx > 1`` —
@@ -24,49 +23,31 @@ Halo-exchange protocol, per fused launch (DESIGN.md §8 for the row axis,
    leftmost to the previous one (``ppermute`` collectives issued
    together, all depending only on the current shard — on TPU these ride
    the ICI links the DSE model's ``t_collective`` term prices, row and
-   column volumes separately); a mesh with one row shard (``dy == 1``)
-   sends no rows, since its halo rows wrap within the shard;
-2. on the 1-D ring (``dx == 1``) the received rows are padded out to one
-   full ``block_h`` guard block per side, giving
-   ``[pad | up-halo | local | down-halo | pad]``, and
-   :func:`repro.kernels.spd_stream.sharded.spd_multistep_halo` (via its
-   streamed twin) advances the extended shard m steps with the exact
-   single-device stripe assembly;
-3. on the 2-D mesh (``dx > 1``) nothing shard-sized is assembled: the
-   two received column pieces are padded into one guard array of
-   ``2·guard_cols(m·halo_x)`` columns, ``[right | zeros | left]``; a
-   small second hop column-permutes the edges of the received row
-   guards to fetch the four ``(m·halo, m·halo_x)`` corner blocks from
-   the diagonal neighbors, which join the row guards in the same
-   layout; and :func:`repro.kernels.spd_stream.streaming
-   .spd_multistep_gathered` DMAs each stripe from the shard, the guard
-   array and the row guards where they lie, into a VMEM stripe laid
-   out ``[shard | right guard | left guard]``. The kernel's periodic
-   stripe body then wraps each shard edge onto the guard columns that
-   hold its true neighbors; the stale columns stay where the two guards
-   meet, and the launch writes back only the shard's own columns, into
-   one of two buffers the loop ping-pongs as on one chip.
+   column volumes separately). A mesh with one row shard (``dy == 1``)
+   sends no rows, since its halo rows wrap within the shard, and one
+   with one column shard (``dx == 1``) sends no columns, since its
+   stripes are full rows that wrap in x in registers, as on one chip;
+2. nothing shard-sized is assembled. The two received column pieces are
+   padded into one guard array of ``2·guard_cols(m·halo_x)`` columns,
+   ``[right | zeros | left]``; a small second hop column-permutes the
+   edges of the received row pieces to fetch the four
+   ``(m·halo, m·halo_x)`` corner blocks from the diagonal neighbors,
+   which join the row pieces in the same layout; and
+   :func:`repro.kernels.spd_stream.spd_multistep` DMAs each stripe from
+   the shard, the guard array and the row pieces where they lie, into a
+   VMEM stripe laid out ``[shard | right guard | left guard]``. The
+   kernel's periodic stripe body then wraps each shard edge onto the
+   guard columns that hold its true neighbors; the stale columns stay
+   where the two guards meet, and the launch writes back only the
+   shard's own columns, into one of two buffers the loop ping-pongs as
+   on one chip.
 
 Because the launches reuse the single-device kernel arithmetic and the
 exchange delivers exactly the rows and columns the periodic index maps
 and periodic in-register x shifts would have read, the sharded run is
 **bit-identical** to the single-device kernel for any legal mesh — the
-correctness contract asserted in ``tests/test_distribute.py`` (1-D
-ring), ``tests/test_mesh.py`` and ``tests/test_mesh_launch.py`` (the
-2-D mesh).
-
-**Overlapped exchange** on the ring (docs/pipeline.md §overlap,
-DESIGN.md §12): only the shard's two *edge* row blocks read exchanged
-rows — every interior block's stripe is fully local in y. When a shard
-has at least three blocks, the fused launch is decomposed into an
-interior launch plus two one-block edge launches; the interior launch
-depends on no collective, so XLA is free to run the exchange on the ICI
-links while the interior blocks compute. Each block's stripe is
-assembled from exactly the same values either way, which keeps the
-decomposition bitwise identical to the monolithic launch; shards
-shorter than three blocks fall back to the monolithic
-exchange-then-compute path. The mesh runs one launch per ``m`` steps:
-its launch reads the column guards in every block.
+correctness contract asserted in ``tests/test_distribute.py``,
+``tests/test_mesh.py`` and ``tests/test_mesh_launch.py``.
 
 Plans come from the shared legalizer (docs/pipeline.md §legalize) with
 per-shard accounting: ``blocking_plan(..., d=d, dx=dx)`` requires
@@ -102,7 +83,7 @@ from .legalize import (
     stripe_cols,
 )
 
-#: Name of the row device axis (the original ring axis).
+#: Name of the row device axis.
 DEVICE_AXIS = "d"
 
 #: Name of the column device axis of the 2-D mesh (DESIGN.md §15).
@@ -115,7 +96,6 @@ __all__ = [
     "device_axis_values",
     "device_mesh",
     "mesh_axis_values",
-    "ring_mesh",
 ]
 
 
@@ -137,7 +117,7 @@ def mesh_axis_values(max_d: int) -> tuple[tuple[int, int], ...]:
     The mesh-shape enumeration of the device count's factorizations
     (DESIGN.md §15): the searched lattice of spatial decompositions, the
     2-D generalization of :func:`device_axis_values`. ``(d, 1)`` shapes
-    are the legacy 1-D rings.
+    shard rows only.
     """
     return tuple(
         (dy, dx)
@@ -145,26 +125,6 @@ def mesh_axis_values(max_d: int) -> tuple[tuple[int, int], ...]:
         for dx in device_axis_values(max_d)
         if dy * dx <= max_d
     )
-
-
-def ring_mesh(d: int, devices: Sequence | None = None) -> Mesh:
-    """A one-axis mesh of ``d`` devices named :data:`DEVICE_AXIS`.
-
-    The axis order is a ring for ``lax.ppermute``: neighbor exchange
-    between shard i and shards (i±1) mod d realizes the grid's periodic
-    y boundary across chips. Raises when the platform has fewer than
-    ``d`` devices (off-TPU, force host devices with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
-    """
-    if d < 1:
-        raise ValueError(f"device axis must be >= 1, got d={d}")
-    devs = list(devices) if devices is not None else list(jax.devices())
-    if len(devs) < d:
-        raise ValueError(
-            f"need {d} devices for a d={d} ring, have {len(devs)} "
-            f"(off-TPU: XLA_FLAGS=--xla_force_host_platform_device_count={d})"
-        )
-    return Mesh(np.array(devs[:d]), (DEVICE_AXIS,))
 
 
 def device_mesh(dy: int, dx: int,
@@ -200,143 +160,53 @@ class ShardedStreamKernel:
     (docs/pipeline.md §execute); ``d == 1`` simply delegates to the
     wrapped kernel (no mesh, no exchange). ``d`` is the *total* device
     count and ``dx`` its column factor (``dy = d / dx``, DESIGN.md §15);
-    ``dx == 1`` keeps the original 1-D ring path byte-for-byte.
+    every mesh with ``d > 1`` runs the same launch loop.
     """
 
     def __init__(self, kernel, d: int, devices: Sequence | None = None,
-                 overlap: bool = True, dx: int = 1):
+                 dx: int = 1):
         self.kernel = kernel
         self.d = int(d)
         self.dy, self.dx = mesh_shape(self.d, dx)
         self.halo = kernel.halo
         self.halo_x = int(getattr(kernel, "halo_x", kernel.halo))
-        self.overlap = bool(overlap)
-        if self.d == 1:
-            self.mesh = None
-        elif self.dx == 1:
-            self.mesh = ring_mesh(self.d, devices)
-        else:
-            self.mesh = device_mesh(self.dy, self.dx, devices)
+        self.mesh = (None if self.d == 1
+                     else device_mesh(self.dy, self.dx, devices))
         self._jitted: dict = {}
 
     # ---- the shard-mapped launch loop --------------------------------------
 
     def _fn(self, steps: int, m: int, block_h: int, double_buffer: bool,
-            overlap: bool, interpret: bool):
+            interpret: bool):
         """Build (and cache) the jitted shard_map'd run for one plan."""
-        overlap = overlap and self.dx == 1  # the mesh loop has no split
-        key = (steps, m, block_h, double_buffer, overlap, interpret)
+        key = (steps, m, block_h, double_buffer, interpret)
         cached = self._jitted.get(key)
         if cached is not None:
             return cached
-        if self.dx == 1:
-            local_run = self._local_run_ring(steps, m, block_h,
-                                             double_buffer, overlap,
-                                             interpret)
-        else:
-            local_run = self._local_run_mesh(steps, m, block_h,
-                                             double_buffer, interpret)
-        spec = stream_grid_pspec(
-            DEVICE_AXIS, axis_x=DEVICE_AXIS_X if self.dx > 1 else None
-        )
+        spec = stream_grid_pspec(DEVICE_AXIS, axis_x=DEVICE_AXIS_X)
         fn = jax.jit(jax.shard_map(
-            local_run, mesh=self.mesh, in_specs=(spec, P(None)),
-            out_specs=spec, check_vma=False,
+            self._local_run(steps, m, block_h, double_buffer, interpret),
+            mesh=self.mesh, in_specs=(spec, P(None)), out_specs=spec,
+            check_vma=False,
         ))
         self._jitted[key] = fn
         return fn
 
-    def _local_run_ring(self, steps, m, block_h, double_buffer, overlap,
-                        interpret):
-        """The 1-D row-ring per-shard loop (DESIGN.md §8) — unchanged
-        from the pre-mesh module, so ``dx == 1`` plans lower exactly as
-        before."""
-        from repro.kernels.spd_stream.streaming import (
-            spd_multistep_halo_streamed,
-        )
-
-        d, halo = self.d, self.halo
-        step_fn = self.kernel._step_fn
-        name = self.kernel.name
-        mh = m * halo
-        perm_dn = [(i, (i + 1) % d) for i in range(d)]  # bottom rows -> next
-        perm_up = [(i, (i - 1) % d) for i in range(d)]  # top rows -> previous
-
-        # Named for the compiled module (jit_spd_run_sharded).
-        def spd_run_sharded(local, scal):
-            p, lh, w = local.shape
-            nblk = lh // block_h
-
-            def shard_launch(ext, scal):
-                with jax.named_scope(tracing.LAUNCH):
-                    return spd_multistep_halo_streamed(
-                        step_fn, ext, scal, m=m, block_h=block_h,
-                        halo=halo, double_buffer=double_buffer,
-                        interpret=interpret, name=name,
-                    )
-
-            def body(_, cur):
-                if mh == 0:
-                    # Elementwise core: shards never read each other.
-                    return shard_launch(cur, scal)
-                # Ring halo exchange: receive the up-neighbor's bottom
-                # rows and the down-neighbor's top rows (periodic in y
-                # because the ring closes).
-                with jax.named_scope(tracing.EXCHANGE):
-                    up = jax.lax.ppermute(
-                        cur[:, lh - mh:, :], DEVICE_AXIS, perm_dn
-                    )
-                    dn = jax.lax.ppermute(
-                        cur[:, :mh, :], DEVICE_AXIS, perm_up
-                    )
-                with jax.named_scope(tracing.ASSEMBLE):
-                    pad = jnp.zeros((p, block_h - mh, w), cur.dtype)
-                if overlap and nblk >= 3:
-                    # Overlapped exchange (docs/pipeline.md §overlap):
-                    # the interior blocks 1..nblk-2 read only local rows
-                    # — the shard itself is their guard-extended array —
-                    # so their launch carries no data dependence on the
-                    # ppermute results and runs while the exchange is in
-                    # flight. Only the two one-block edge launches
-                    # consume the received rows. Every block's stripe is
-                    # assembled from the same rows as the monolithic
-                    # launch below, keeping the decomposition (and the
-                    # sharded run) bitwise identical.
-                    interior = shard_launch(cur, scal)
-                    with jax.named_scope(tracing.ASSEMBLE):
-                        ext_top = jnp.concatenate(
-                            [pad, up, cur[:, :2 * block_h, :]], axis=1
-                        )
-                        ext_bot = jnp.concatenate(
-                            [cur[:, lh - 2 * block_h:, :], dn, pad], axis=1
-                        )
-                    top = shard_launch(ext_top, scal)
-                    bot = shard_launch(ext_bot, scal)
-                    with jax.named_scope(tracing.ASSEMBLE):
-                        return jnp.concatenate([top, interior, bot], axis=1)
-                with jax.named_scope(tracing.ASSEMBLE):
-                    ext = jnp.concatenate([pad, up, cur, dn, pad], axis=1)
-                return shard_launch(ext, scal)
-
-            return jax.lax.fori_loop(0, steps // m, body, local)
-
-        return spd_run_sharded
-
-    def _local_run_mesh(self, steps, m, block_h, double_buffer, interpret):
-        """The 2-D mesh per-shard loop (DESIGN.md §15): each launch
-        gathers its stripes from the shard and the exchanged guard
-        pieces in place and writes only the shard's own columns, and
-        the launches ping-pong between two buffers the kernel writes,
-        as on one chip (:func:`~repro.kernels.spd_stream
-        .stream_run_blocked`)."""
-        from repro.kernels.spd_stream.ops import stream_run_blocked
-        from repro.kernels.spd_stream.streaming import spd_multistep_gathered
+    def _local_run(self, steps, m, block_h, double_buffer, interpret):
+        """The per-shard loop (DESIGN.md §8, §15): each launch gathers
+        its stripes from the shard and the exchanged halo pieces in
+        place and writes only the shard's own columns, and the launches
+        ping-pong between two buffers the kernel writes, as on one chip
+        (:func:`~repro.kernels.spd_stream.stream_run_blocked`)."""
+        from repro.kernels.spd_stream import spd_multistep, stream_run_blocked
 
         dy, dx, halo = self.dy, self.dx, self.halo
         step_fn = self.kernel._step_fn
         name = self.kernel.name
         mh = m * halo
-        mhx = m * self.halo_x
+        # A full-width shard (dx == 1) wraps x in its registers, as on
+        # one chip: it takes no column guards.
+        mhx = m * self.halo_x if dx > 1 else 0
         # Guard columns per side: the mhx exchanged columns plus zero
         # padding up to whole half-lane tiles, so the two guards make
         # whole 128-lane tiles. The zero columns only widen the stale
@@ -418,7 +288,7 @@ class ShardedStreamKernel:
                     edge(dn, exchange_x(dn) if mhx else None, top=False),
                 )
             with jax.named_scope(tracing.LAUNCH):
-                return spd_multistep_gathered(
+                return spd_multistep(
                     step_fn, cur, scal, m=m, block_h=block_h, halo=halo,
                     guards=guards, edges=edges,
                     double_buffer=double_buffer, interpret=interpret,
@@ -438,24 +308,17 @@ class ShardedStreamKernel:
 
     def run_blocked(self, state, regs: Sequence = (), *, steps: int,
                     m: int, block_h: int, double_buffer: bool = True,
-                    overlap: bool | None = None,
                     interpret: bool | None = None):
         """Advance ``steps`` time steps, halo-exchanging every m steps.
 
         ``double_buffer`` selects the per-shard streamed launch's buffer
-        protocol (docs/pipeline.md §stream); ``overlap`` toggles the
-        ring's exchange/compute overlap decomposition (docs/pipeline.md
-        §overlap, default: the kernel's construction-time setting). The
-        mesh loop (``dx > 1``) runs one launch per ``m`` steps either
-        way. ``state`` is only read.
+        protocol (docs/pipeline.md §stream). ``state`` is only read.
         """
         if self.d == 1:
             return self.kernel.run_blocked(
                 state, regs, steps=steps, m=m, block_h=block_h,
                 double_buffer=double_buffer, interpret=interpret,
             )
-        if overlap is None:
-            overlap = self.overlap
         p, h, w = state.shape
         local_h = shard_height(h, self.dy)
         local_w = shard_width(w, self.dx)
@@ -479,14 +342,12 @@ class ShardedStreamKernel:
             raise ValueError(f"steps={steps} must be a multiple of m={m}")
         with jax.profiler.TraceAnnotation(tracing.RUN):
             fn = self._fn(steps, m, block_h, bool(double_buffer),
-                          bool(overlap), interpret)
+                          interpret)
             out = fn(state, self.kernel._scal(regs))
             launches = steps // m
-            # Every shard's launch moves and computes the same rows: on
-            # the ring, the interior and edge launches of the overlapped
-            # exchange move and compute, together, what one monolithic
-            # launch over the extended shard does; on the mesh, a launch
-            # gathers and computes its stripes at the guarded width.
+            # Every shard's launch gathers and computes its stripes at
+            # the guarded width, and the loop ping-pongs two
+            # kernel-written buffers, as on one chip.
             width = stripe_cols(local_w, m, self.halo_x if self.dx > 1 else 0)
             shard_bytes = launch_dma_bytes(
                 local_h, width, p, block_h=block_h, m=m, halo=self.halo,
@@ -494,13 +355,10 @@ class ShardedStreamKernel:
             shard_flops = launch_flops(
                 local_h, width, 1, block_h=block_h, m=m, halo=self.halo,
                 flops=self.kernel.compiled.flops)
-            # The mesh loop ping-pongs two kernel-written buffers, as on
-            # one chip; the ring loop recycles none.
             tracing.count(launches=launches, steps=steps,
                           dma_bytes=launches * self.d * shard_bytes,
                           kernel_flops=launches * self.d * shard_flops,
-                          aliased_launches=max(0, launches - 2)
-                          if self.dx > 1 else 0)
+                          aliased_launches=max(0, launches - 2))
         return out
 
     def run_for_point(self, state, regs: Sequence = (), *, point,

@@ -252,8 +252,6 @@ _SALT_MODULES = (
     "repro.core.codegen",
     "repro.core.program",
     "repro.core.distribute",
-    "repro.kernels.spd_stream.spd_stream",
-    "repro.kernels.spd_stream.sharded",
     "repro.kernels.spd_stream.streaming",
     "repro.kernels.spd_stream.ops",
     "repro.kernels.lbm_stream.lbm_stream",

@@ -1,28 +1,19 @@
 """Generic SPD→Pallas temporal-blocking stream kernels.
 
-Where :mod:`repro.kernels.lbm_stream` is the hand-written kernel for one
-application, this package is the *codegen target*: `repro.core.codegen`
-lowers any compiled SPD core into the stripe-update function that
-:func:`spd_multistep` launches on the TPU grid (docs/pipeline.md §codegen).
-:func:`spd_multistep_halo` is the per-shard variant of the same launch
-for multi-device runs, with the y-halo pre-exchanged by
-``repro.core.distribute`` (docs/pipeline.md §distribute).
-
-:func:`spd_multistep_streamed` / :func:`spd_multistep_halo_streamed` are
-the manually pipelined twins of those two launches: the state stays in
-HBM and stripes are staged through ping/pong VMEM buffers by explicit
-async copies, making the ``double_buffer`` plan knob real
-(docs/pipeline.md §stream).
+This package is the *codegen target*: `repro.core.codegen` lowers any
+compiled SPD core into the stripe-update function that
+:func:`spd_multistep` launches on the TPU (docs/pipeline.md §codegen).
+The launch keeps the state in HBM and stages its stripes through
+ping/pong VMEM buffers by explicit async copies (docs/pipeline.md
+§stream); on a device mesh the same launch gathers the halo pieces
+that ``repro.core.distribute`` exchanges (docs/pipeline.md
+§distribute). :func:`stream_run_blocked` is the launch loop.
 """
 
-from .ops import spd_multistep, stream_run_blocked
-from .sharded import spd_multistep_halo
-from .streaming import spd_multistep_halo_streamed, spd_multistep_streamed
+from .ops import stream_run_blocked
+from .streaming import spd_multistep
 
 __all__ = [
     "spd_multistep",
-    "spd_multistep_halo",
-    "spd_multistep_halo_streamed",
-    "spd_multistep_streamed",
     "stream_run_blocked",
 ]

@@ -1,11 +1,12 @@
-"""Run-plan wrappers for codegen'd SPD stream kernels.
+"""The launch loop of codegen'd SPD stream kernels.
 
-Mirrors :mod:`repro.kernels.lbm_stream.ops`: multi-launch stepping over
-the fused kernel plus the explorer hand-off, with (block_h, m) plans
-legalized through the shared :mod:`repro.core.legalize`
+Multi-launch stepping over the fused kernel
+(:func:`repro.kernels.spd_stream.spd_multistep`), with (block_h, m)
+plans legalized through the shared :mod:`repro.core.legalize`
 (docs/pipeline.md §legalize). The kernel-building side lives in
-:class:`repro.core.codegen.StreamKernel`, which wraps these for a
-specific compiled core.
+:class:`repro.core.codegen.StreamKernel`, which runs this loop for a
+specific compiled core, and ``repro.core.distribute`` runs it on every
+shard of a device mesh.
 """
 
 from __future__ import annotations
@@ -16,16 +17,14 @@ import jax
 
 from repro.core.legalize import blocking_plan, resolve_run_plan
 
-from .spd_stream import spd_multistep
-
 
 def stream_run_blocked(multistep: Callable, state, scal, *, steps: int,
                        m: int, block_h: int, interpret: bool | None = None):
     """Advance ``steps`` time steps using m-fused kernel launches.
 
     ``multistep`` is a (typically jitted) closure over
-    :func:`repro.kernels.spd_stream.spd_multistep_streamed` with the
-    stripe function and halo bound —
+    :func:`repro.kernels.spd_stream.spd_multistep` with the stripe
+    function and halo bound —
     ``multistep(state, scal, m=, block_h=, interpret=, dst=)``.
 
     The launches ping-pong between two buffers that the kernel writes
@@ -61,6 +60,5 @@ def stream_run_blocked(multistep: Callable, state, scal, *, steps: int,
 __all__ = [
     "blocking_plan",
     "resolve_run_plan",
-    "spd_multistep",
     "stream_run_blocked",
 ]

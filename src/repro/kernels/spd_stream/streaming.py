@@ -1,35 +1,36 @@
-"""Manually pipelined HBM↔VMEM streaming launch for SPD stream kernels.
+"""The streamed fused m-step launch of SPD stream kernels.
 
-The BlockSpec launch in :mod:`.spd_stream` describes stripes
-*declaratively* and leaves the HBM↔VMEM movement to the Pallas grid
-pipeliner. This module is the explicit form (DESIGN.md §12,
-docs/pipeline.md §stream): the state stays in ``pl.ANY`` memory (HBM
-on real TPUs), a single kernel program walks the row blocks with
-``jax.lax.fori_loop``, and every ``(P, block_h + 2·mh, W)`` stripe
-is staged through VMEM scratch buffers by explicit async copies
-(``pltpu.make_async_copy`` + DMA semaphores) — ``emit_pipeline``-style
-manual pipelining, written out so the buffer protocol is inspectable
-and the ``double_buffer`` plan knob is *real*:
+The grid state is one stacked ``(P, H, W)`` f32 array, one channel per
+main-stream port of the SPD core. Each row block is advanced ``m`` time
+steps per HBM round trip in a ``(P, block_h + 2·mh, W)`` stripe: the
+block plus ``mh`` halo rows per side from its neighbour blocks
+(periodic in y). After each application of the core ``halo`` edge rows
+per side go stale and are never read again (the temporal-blocking
+trapezoid); periodic x is handled inside the stripe function with
+in-register shifts. The *stripe function* ``step_fn((P, rows, W),
+regs)`` is produced by :class:`repro.core.codegen.StreamKernel` from
+the core's data-flow graph; this module owns the ``pallas_call``.
+
+The state stays in ``pl.ANY`` memory (HBM on real TPUs), a single
+kernel program walks the row blocks with ``jax.lax.fori_loop``, and
+every stripe is staged through VMEM scratch buffers by explicit async
+copies (``pltpu.make_async_copy`` + DMA semaphores; DESIGN.md §12,
+docs/pipeline.md §stream), so the ``double_buffer`` plan knob is
+*real*:
 
 * ``double_buffer=True`` — ping/pong: two stripe buffers; while block
-  ``i`` computes from one, block ``i+1``'s three-piece stripe DMA (up
-  halo, center, down halo) already fills the other, and the finished
-  block's output drains back to HBM asynchronously. Copy and compute
-  overlap; VMEM holds two stripes (the legalizer's
-  ``VMEM_DOUBLE_BUFFER`` accounting).
+  ``i`` computes from one, block ``i+1``'s stripe DMAs (up halo,
+  center, down halo) already fill the other, and the finished block's
+  output drains back to HBM asynchronously. Copy and compute overlap;
+  VMEM holds two stripes (the legalizer's ``VMEM_DOUBLE_BUFFER``
+  accounting).
 * ``double_buffer=False`` — one stripe buffer, sequential
   start→wait→compute per block. No overlap, but the stripe budget is
   the whole VMEM: this is the *streaming fallback* the legalizer drops
   to when a ping/pong pair of minimal stripes cannot fit.
 
-Both variants stage block rows through VMEM instead of requiring the
-grid to fit anywhere in particular, so grids whose full height
-overflows VMEM stream at bandwidth. Stripe assembly (up-halo tail,
-center block, down-halo head) is row-for-row identical to the
-BlockSpec kernel's ``jnp.concatenate``, so streamed and declarative
-launches — and the two ``nbuf`` variants — are bitwise identical.
-
-Like the BlockSpec launch, state may carry extra leading dimensions —
+Both protocols assemble every stripe from the same rows, so they are
+bitwise identical. State may carry extra leading dimensions —
 ``(B, P, H, W)`` batches B independent simulations into one walk
 (docs/pipeline.md §serve, DESIGN.md §13): rows stay on axis ``-2``,
 every stripe DMA moves all leading axes whole, and the VMEM scratch
@@ -37,15 +38,15 @@ stacks scale by B exactly as the legalizer's
 ``stripe_vmem_bytes(..., b=B)`` prices them. Each stripe is gathered
 from a list of pieces (:class:`Piece`), each one DMA from one source
 array into a window of the stripe buffer: on one chip the three pieces
-above, from the state itself. Under a column-sharded mesh (``dx > 1``,
-DESIGN.md §15) :func:`spd_multistep_gathered` gathers a stripe
-``W/dx + 2·guard_cols(m·halo_x)`` lanes wide from the shard and the
-exchanged guard pieces where they lie, and writes back only the
-shard's own ``W/dx`` columns; the legalizer prices the stripes at that
-width (``stripe_vmem_bytes(..., halo_x=)``). Compiled for the TPU,
-every piece's width and the stripe's must be multiples of 128 lanes and
-``block_h`` a multiple of 8 rows (a ``ValueError`` says so before the
-compiler does).
+above, from the state itself. On a device mesh (DESIGN.md §8, §15)
+:func:`spd_multistep` also gathers the exchanged halo rows and, under a
+sharded column axis, a stripe ``W/dx + 2·guard_cols(m·halo_x)`` lanes
+wide from the exchanged guard columns, where they lie, and writes back
+only the shard's own ``W/dx`` columns; the legalizer prices the
+stripes at that width (``stripe_vmem_bytes(..., halo_x=)``). Compiled
+for the TPU, every piece's width and the stripe's must be multiples of
+128 lanes and ``block_h`` a multiple of 8 rows (a ``ValueError`` says
+so before the compiler does).
 
 Halo rows are carried in whole sublane tiles: ``mh`` is ``m·halo``
 rounded up to a multiple of 8 rows (capped at ``block_h``,
@@ -96,7 +97,7 @@ class Piece(NamedTuple):
 
 def _stream_kernel(scal_ref, *refs, step_fn: Callable, m: int, block_h: int,
                    mh: int, nblk: int, nbuf: int, pieces: tuple,
-                   nsrc: int, aliased: bool):
+                   nsrc: int, aliased: bool, interpret: bool):
     """One-program streaming walk over ``nblk`` row blocks.
 
     ``refs`` are the ``nsrc`` source refs, the aliased destination when
@@ -198,7 +199,15 @@ def _stream_kernel(scal_ref, *refs, step_fn: Callable, m: int, block_h: int,
         dma_out(slot, i).start()
         return carry
 
-    jax.lax.fori_loop(0, nblk, body, 0)
+    # Interpreted, the block loop is an XLA while loop on the CPU, and
+    # XLA removes one of a single trip: the stripe arithmetic is then
+    # compiled among the ops around it, whose fusions contract its
+    # mul-adds otherwise than a loop body's do, so a one-block launch
+    # rounds differently from a many-block one. A bound XLA cannot
+    # fold keeps every launch's arithmetic in a loop body. Compiled for
+    # the TPU, Mosaic lowers the body once whatever the bound.
+    trips = jax.lax.optimization_barrier(nblk) if interpret else nblk
+    jax.lax.fori_loop(0, trips, body, 0)
 
     # Drain: the last nbuf output copies are still in flight.
     def drain(i, carry):
@@ -240,7 +249,7 @@ def _streamed_call(step_fn, srcs, scal, *, m, block_h, mh, nblk, nbuf,
         functools.partial(
             _stream_kernel, step_fn=step_fn, m=m, block_h=block_h, mh=mh,
             nblk=nblk, nbuf=nbuf, pieces=tuple(pieces), nsrc=len(srcs),
-            aliased=dst is not None,
+            aliased=dst is not None, interpret=bool(interpret),
         ),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -281,19 +290,34 @@ def _periodic_pieces(src, *, block_h, mh, nblk, col=0, edges=False):
     return pieces
 
 
-def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
-                           block_h: int, halo: int,
-                           double_buffer: bool = True,
-                           interpret: bool | None = None,
-                           name: str | None = None, dst=None):
-    """Streamed fused m-step launch, periodic in y.
+def spd_multistep(step_fn: Callable, state, scal, *, m: int, block_h: int,
+                  halo: int, guards=None, edges=None,
+                  double_buffer: bool = True, interpret: bool | None = None,
+                  name: str | None = None, dst=None):
+    """Streamed fused m-step launch: advance ``state`` ``m`` steps, its
+    stripes gathered from the state and any exchanged pieces in place.
 
-    Drop-in for :func:`repro.kernels.spd_stream.spd_multistep` — same
-    stripe function contract, same validation, bitwise-identical output
-    — but with manual double-buffered DMA staging (docs/pipeline.md
-    §stream). ``double_buffer`` picks the ping/pong (True) or
-    single-buffer streaming-fallback (False) protocol. ``name`` names
-    the kernel in the compiled program and the device trace.
+    ``state`` is the ``(…, H, W)`` grid (on a mesh, the shard's own
+    rows), read as it is, and ``step_fn`` the stripe function
+    (docs/pipeline.md §stream). Without ``guards`` and ``edges`` the
+    launch is periodic in y and x within ``state``, as on one chip.
+    ``double_buffer`` picks the ping/pong (True) or single-buffer
+    streaming-fallback (False) protocol; ``name`` names the kernel in
+    the compiled program and the device trace.
+
+    On a device mesh (docs/pipeline.md §distribute, DESIGN.md §8, §15)
+    the stripe is laid out ``[shard W | guards]`` and advanced by the
+    same *periodic* stripe body: ``guards``, for a sharded column axis,
+    is a ``(…, H, G)`` array whose first columns are the right
+    neighbour's first columns and whose last columns are the left
+    neighbour's last ones, so shard column 0 wraps onto its true left
+    neighbour and column ``W - 1`` runs into its true right one; the
+    two guards meet in the middle of ``G``, where the stale columns
+    stay. ``edges``, for a sharded row axis, is the pair of
+    ``(…, mh, W + G)`` halo-row pieces that block 0 reads above it and
+    the last block reads below it, in the stripe's layout (``mh`` =
+    :func:`~repro.core.legalize.halo_rows`). The output is
+    ``state``'s own ``(…, H, W)``.
 
     ``dst``, an array of ``state``'s shape and dtype that must not be
     ``state``, is the buffer the output is written into
@@ -301,92 +325,6 @@ def spd_multistep_streamed(step_fn: Callable, state, scal, *, m: int,
     loop that hands each launch the buffer it last wrote needs no copy
     (:func:`repro.kernels.spd_stream.stream_run_blocked`). Without it
     the output is a new buffer.
-    """
-    *_, h, _ = state.shape
-    if h % block_h:
-        raise ValueError(f"H={h} must be divisible by block_h={block_h}")
-    if m * halo > block_h:
-        raise ValueError(
-            f"m*halo={m * halo} must be <= block_h={block_h} (halo source)"
-        )
-    mh = halo_rows(m * halo, block_h)
-    nblk = h // block_h
-    return _streamed_call(
-        step_fn, (state,), scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
-        nbuf=2 if double_buffer else 1, out_h=h,
-        pieces=_periodic_pieces(0, block_h=block_h, mh=mh, nblk=nblk),
-        interpret=interpret, name=name, dst=dst,
-    )
-
-
-def spd_multistep_halo_streamed(step_fn: Callable, ext, scal, *, m: int,
-                                block_h: int, halo: int,
-                                double_buffer: bool = True,
-                                interpret: bool | None = None,
-                                name: str | None = None):
-    """Streamed fused m-step launch over one halo-extended shard.
-
-    The streamed twin of
-    :func:`repro.kernels.spd_stream.spd_multistep_halo`: ``ext`` is the
-    ``(P, local_h + 2·block_h, W)`` guard-block-extended shard and the
-    stripe source offsets are non-periodic — block i's center is ext
-    block i+1, its halos come from ext blocks i / i+2 (docs/pipeline.md
-    §stream).
-    """
-    if m * halo == 0:
-        return spd_multistep_streamed(
-            step_fn, ext, scal, m=m, block_h=block_h, halo=0,
-            double_buffer=double_buffer, interpret=interpret, name=name,
-        )
-    *_, rows, _ = ext.shape
-    local_h = rows - 2 * block_h
-    if local_h < 1 or local_h % block_h:
-        raise ValueError(
-            f"extended shard of {rows} rows is not local_h + 2*block_h "
-            f"with block_h={block_h} dividing local_h"
-        )
-    if m * halo > block_h:
-        raise ValueError(
-            f"m*halo={m * halo} must be <= block_h={block_h} (halo source)"
-        )
-    mh = halo_rows(m * halo, block_h)
-    nblk = local_h // block_h
-
-    pieces = [
-        Piece(0, lambda i: (i + 1) * block_h, block_h, mh),
-        Piece(0, lambda i: (i + 1) * block_h - mh, mh, 0),
-        Piece(0, lambda i: (i + 2) * block_h, mh, mh + block_h),
-    ]
-    return _streamed_call(
-        step_fn, (ext,), scal, m=m, block_h=block_h, mh=mh, nblk=nblk,
-        nbuf=2 if double_buffer else 1, out_h=local_h, pieces=pieces,
-        interpret=interpret, name=name,
-    )
-
-
-def spd_multistep_gathered(step_fn: Callable, state, scal, *, m: int,
-                           block_h: int, halo: int, guards=None,
-                           edges=None, double_buffer: bool = True,
-                           interpret: bool | None = None,
-                           name: str | None = None, dst=None):
-    """Streamed fused m-step launch over one shard of a device mesh,
-    its stripes gathered from the exchanged pieces in place.
-
-    ``state`` is the shard's own ``(…, H, W)`` rows, read as it is. The
-    stripe is laid out ``[shard W | guards]`` and advanced by the
-    *periodic* stripe body: ``guards`` is a ``(…, H, G)`` array whose
-    first columns are the right neighbour's first columns and whose
-    last columns are the left neighbour's last ones, so shard column 0
-    wraps onto its true left neighbour and column ``W - 1`` runs into
-    its true right one; the two guards meet in the middle of ``G``,
-    where the stale columns stay. ``edges``, for a mesh whose row axis
-    is sharded, is the pair of ``(…, mh, W + G)`` halo-row pieces that
-    block 0 reads above it and the last block reads below it, in the
-    stripe's layout (``mh`` = :func:`~repro.core.legalize.halo_rows`);
-    without it the halo rows are periodic within ``state`` and
-    ``guards``. The output is the shard's own ``(…, H, W)``, written
-    into ``dst`` when one is given, as in :func:`spd_multistep_streamed`
-    (docs/pipeline.md §distribute, DESIGN.md §15).
     """
     *_, h, w = state.shape
     if h % block_h:

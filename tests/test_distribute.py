@@ -27,7 +27,7 @@ from repro.apps import lbm
 from repro.core.distribute import (
     ShardedStreamKernel,
     device_axis_values,
-    ring_mesh,
+    device_mesh,
 )
 from repro.core.dse import StreamWorkload, TPUModel
 from repro.core.legalize import (
@@ -158,9 +158,9 @@ def test_device_axis_reaches_both_apps_frontiers(make_sim):
 
 def test_ring_mesh_needs_enough_devices():
     with pytest.raises(ValueError, match="device"):
-        ring_mesh(jax.device_count() + 1)
-    with pytest.raises(ValueError, match="device axis"):
-        ring_mesh(0)
+        device_mesh(jax.device_count() + 1, 1)
+    with pytest.raises(ValueError, match="mesh axes"):
+        device_mesh(0, 1)
 
 
 def test_sharded_d1_delegates(dif_sim):
@@ -237,62 +237,6 @@ def test_lbm_sharded_bitmatch_walls(lbm_sim):
     single = kern.run_blocked(state, regs, steps=4, m=2, block_h=4)
     shard = kern.sharded(4).run_blocked(state, regs, steps=4, m=2, block_h=4)
     np.testing.assert_array_equal(np.asarray(shard), np.asarray(single))
-
-
-# ----------------------- overlapped halo exchange ---------------------------
-
-
-@_needs_devices(2)
-@pytest.mark.parametrize("m", [1, 2])
-def test_overlapped_exchange_bitmatch_diffusion(dif_sim, m):
-    """ISSUE 7 satellite: overlapping the ppermute halo exchange with
-    interior compute (docs/pipeline.md §overlap) is a scheduling choice,
-    not a numerics choice — overlapped ≡ non-overlapped ≡ single-device,
-    bit for bit. block_h=2 gives each 8-row shard nblk=4 ≥ 3, so the
-    interior/edge decomposition actually engages."""
-    u0, _ = dif.sine_init(16, 64)
-    state = dif_sim.state(u0)
-    kern = dif_sim.kernel
-    single = kern.run_blocked(state, (0.2,), steps=2 * m, m=m, block_h=2)
-    sk = kern.sharded(2)
-    on = sk.run_blocked(state, (0.2,), steps=2 * m, m=m, block_h=2,
-                        overlap=True)
-    off = sk.run_blocked(state, (0.2,), steps=2 * m, m=m, block_h=2,
-                         overlap=False)
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(single))
-
-
-@_needs_devices(2)
-def test_overlapped_exchange_bitmatch_lbm(lbm_sim):
-    """Same contract on the codegen'd uLBM core (nine crossing
-    stencils), in both buffer protocols."""
-    kern = lbm_sim.stream_kernel()
-    f, attr, _ = lbm.taylor_green_init(16, 64)
-    state = lbm_sim.stream_state(f, attr)
-    single = kern.run_blocked(state, LBM_REGS, steps=2, m=1, block_h=2)
-    sk = kern.sharded(2)
-    for db in (True, False):
-        on = sk.run_blocked(state, LBM_REGS, steps=2, m=1, block_h=2,
-                            overlap=True, double_buffer=db)
-        off = sk.run_blocked(state, LBM_REGS, steps=2, m=1, block_h=2,
-                             overlap=False, double_buffer=db)
-        np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-        np.testing.assert_array_equal(np.asarray(on), np.asarray(single))
-
-
-@_needs_devices(2)
-def test_overlap_falls_back_below_three_blocks(dif_sim):
-    """nblk < 3 leaves no exchange-free interior: the overlapped path
-    must quietly use the monolithic launch and still match."""
-    u0, _ = dif.sine_init(16, 64)
-    state = dif_sim.state(u0)
-    kern = dif_sim.kernel
-    single = kern.run_blocked(state, (0.2,), steps=2, m=1, block_h=4)
-    on = kern.sharded(2).run_blocked(  # 8-row shards, nblk=2
-        state, (0.2,), steps=2, m=1, block_h=4, overlap=True
-    )
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(single))
 
 
 @_needs_devices(2)

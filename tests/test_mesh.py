@@ -117,27 +117,6 @@ def test_lbm_couette_mesh_bitmatch(lbm_sim, dy, dx, m, db):
                LBM_COUETTE_REGS, dy, dx, m, db)
 
 
-@pytest.mark.parametrize("dy,dx", [(1, 2), (2, 2)])
-def test_mesh_overlap_bitmatch(dif_sim, dy, dx):
-    """The PR-7 interior/edge overlap generalizes to both exchanges:
-    overlapped ≡ monolithic ≡ single-device under a column-sharded
-    mesh too."""
-    d = dy * dx
-    if jax.device_count() < d:
-        pytest.skip("needs forced host devices")
-    u0, _ = dif.sine_init(16, 64)
-    state = dif_sim.state(u0)
-    kern = dif_sim.kernel
-    single = kern.run_blocked(state, (0.2,), steps=4, m=2, block_h=2)
-    sk = kern.sharded(d, dx=dx)
-    on = sk.run_blocked(state, (0.2,), steps=4, m=2, block_h=2,
-                        overlap=True)
-    off = sk.run_blocked(state, (0.2,), steps=4, m=2, block_h=2,
-                         overlap=False)
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(off))
-    np.testing.assert_array_equal(np.asarray(on), np.asarray(single))
-
-
 # ----------------------- legalizer mesh geometry -----------------------
 
 
@@ -221,7 +200,7 @@ def test_model_prices_the_sharded_launch_geometry():
 
     state = jnp.zeros((1, 64, 256), jnp.float32)
     for bh, m in ((8, 1), (16, 2), (32, 3)):
-        fn = sk._fn(m, m, bh, True, False, True)
+        fn = sk._fn(m, m, bh, True, True)
         (eqn,) = calls(jax.make_jaxpr(fn)(state, kern._scal((0.2,))).jaxpr)
         rows, cols = [v.aval for v in eqn.params["jaxpr"].invars
                       if "vmem" in str(v.aval)][0].shape[-2:]

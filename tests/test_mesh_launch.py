@@ -1,20 +1,21 @@
-"""The 2-D mesh's launch loop (``ShardedStreamKernel._local_run_mesh``):
-each launch gathers its stripes from the shard and the exchanged guard
-pieces in place, and the launches ping-pong between two buffers the
-kernel writes (DESIGN.md §15, docs/pipeline.md §distribute).
+"""The sharded launch loop (``ShardedStreamKernel._local_run``): each
+launch gathers its stripes from the shard and the exchanged halo pieces
+in place, and the launches ping-pong between two buffers the kernel
+writes (DESIGN.md §8, §15, docs/pipeline.md §distribute).
 
-Over the meshes (1, 4), (2, 2) and (2, 4), one to five launches, both
-buffer protocols, diffusion and lattice Boltzmann with walls and a
-moving lid, the mesh run is bitwise equal to the one-chip
+Over the meshes (1, 4), (2, 2), (2, 4), (2, 1) and (4, 1), one to five
+launches, both buffer protocols, diffusion and lattice Boltzmann with
+walls and a moving lid, the mesh run is bitwise equal to the one-chip
 ``run_blocked``, leaves the caller's input as it was, and gives the
 same result when called again. A shard of two row blocks and halo rows
 carried as 4-row tiles (``m·halo`` = 2) take every path of the launch:
 periodic rows on a one-row-shard mesh, exchanged rows and their corners
-otherwise, and zero rows between the exchanged ones and the tile.
+otherwise, and zero rows between the exchanged ones and the tile. The
+(4, 1) shard is one row block, which reads both exchanged row pieces.
 
 The meshes need eight devices, so the runs are made once, in a child
-process per mesh with eight virtual CPU devices (the three side by
-side), and each case reads its own line of the children's results.
+process per mesh with eight virtual CPU devices (all side by side), and
+each case reads its own line of the children's results.
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ from pathlib import Path
 import pytest
 
 REPO = Path(__file__).resolve().parents[1]
-MESHES = ((1, 4), (2, 2), (2, 4))
+MESHES = ((1, 4), (2, 2), (2, 4), (2, 1), (4, 1))
 LAUNCHES = (1, 2, 3, 4, 5)
 APPS = ("diffusion", "lbm_walls")
 M, BLOCK_H = 2, 4

@@ -4,8 +4,9 @@ streaming`): double_buffer as a real, end-to-end plan dimension.
 Load-bearing assertions (ISSUE 7 acceptance criteria):
 * **differential bit-match matrix** — the ping/pong streamed launch
   (``double_buffer=True``), the single-buffer streamed launch
-  (``double_buffer=False``), and the declarative BlockSpec reference
-  produce identical bits across (block_h, m ∈ {1, 2, 4}, d ∈ {1, 2})
+  (``double_buffer=False``), and the jnp oracle
+  (:meth:`~repro.core.codegen.StreamKernel.reference`) produce
+  identical bits across (block_h, m ∈ {1, 2, 4}, d ∈ {1, 2})
   for both shipped apps (lbm fluid + walls, diffusion);
 * **VMEM-overflow fallback** — a grid whose minimal double-buffered
   stripe exceeds the VMEM budget legalizes onto the single-buffer
@@ -85,7 +86,7 @@ def _run_both(kern, state, regs, *, m, block_h, d):
 @pytest.mark.parametrize("block_h", [4, 8])
 def test_diffusion_double_vs_single_buffer_bitmatch(dif_sim, block_h, m, d):
     """ISSUE 7 matrix, diffusion: nbuf is a protocol choice, never a
-    numerics choice — and both match the declarative reference."""
+    numerics choice — and both match the jnp reference."""
     if jax.device_count() < d:
         pytest.skip(f"needs {d} devices (force host devices in XLA_FLAGS)")
     if m > block_h or (d > 1 and m * dif_sim.kernel.halo > 16 // d):
@@ -96,12 +97,7 @@ def test_diffusion_double_vs_single_buffer_bitmatch(dif_sim, block_h, m, d):
                        m=m, block_h=block_h, d=d)
     np.testing.assert_array_equal(np.asarray(pp), np.asarray(sb))
     if d == 1:
-        ref = dif_sim.kernel._multistep(
-            state, dif_sim.kernel._scal((0.2,)), m=m, block_h=block_h
-        )
-        ref = dif_sim.kernel._multistep(
-            ref, dif_sim.kernel._scal((0.2,)), m=m, block_h=block_h
-        )
+        ref = dif_sim.kernel.reference(state, (0.2,), m=2 * m)
         np.testing.assert_array_equal(np.asarray(pp), np.asarray(ref))
 
 

@@ -93,19 +93,6 @@ def test_streamed_launch_compiles(one_chip, kernels, app, n, block_h, m,
     )
 
 
-def test_halo_extended_shard_launch_compiles(one_chip, kernels):
-    from repro.kernels.spd_stream.streaming import spd_multistep_halo_streamed
-
-    kern = kernels["diffusion"]
-    block_h, m, local_h, w = 32, 2, 1024, 2048
-    ext, scal = _shapes(kern, (1, local_h + 2 * block_h, w), one_chip)
-    _assert_compiles(
-        functools.partial(spd_multistep_halo_streamed, kern._step_fn, m=m,
-                          block_h=block_h, halo=kern.halo, interpret=False),
-        ext, scal,
-    )
-
-
 def _mesh_text(topo, kern, dy, dx, n, block_h, m, launches):
     """The compiled ``jit_spd_run_sharded`` of an n² grid on a (dy, dx)
     mesh of the described chips, ``launches`` launches of plan
@@ -122,7 +109,7 @@ def _mesh_text(topo, kern, dy, dx, n, block_h, m, launches):
                                  sharding=NamedSharding(sk.mesh, spec))
     scal = jax.ShapeDtypeStruct((max(1, len(kern._regs)),), jnp.float32,
                                 sharding=NamedSharding(sk.mesh, P(None)))
-    fn = sk._fn(launches * m, m, block_h, True, True, False)
+    fn = sk._fn(launches * m, m, block_h, True, False)
     return fn.lower(state, scal).compile().as_text()
 
 
@@ -174,18 +161,20 @@ MESH_LOOPS = [("lbm", 2048, 16, 2), ("diffusion", 8192, 32, 2)]
 
 
 @pytest.mark.parametrize("launches", [16, 17])
-@pytest.mark.parametrize("dy,dx", [(1, 4), (2, 2)], ids=["1x4", "2x2"])
+@pytest.mark.parametrize("dy,dx", [(1, 4), (2, 2), (4, 1)],
+                         ids=["1x4", "2x2", "4x1"])
 @pytest.mark.parametrize("app,n,block_h,m", MESH_LOOPS,
                          ids=[c[0] for c in MESH_LOOPS])
 def test_mesh_loop_copies_no_state(topo, kernels, app, n, block_h, m, dy,
                                    dx, launches):
-    """The four-chip twin of ``test_run_blocked_copies_no_state``: each
-    launch gathers its stripes from the shard and the guard pieces in
-    place and writes its own columns into a buffer the loop recycles,
-    so the compiled loop holds no copy, concatenate or fusion of a
-    shard's size, neither from the loop carry nor from the caller's
-    input. Every kernel is ``spd_<core>`` under ``spd.launch``, every
-    collective permute under ``spd.exchange``."""
+    """The four-chip twin of ``test_run_blocked_copies_no_state``, on
+    every mesh shape of four chips: each launch gathers its stripes
+    from the shard and the exchanged halo pieces in place and writes
+    its own columns into a buffer the loop recycles, so the compiled
+    loop holds no copy, concatenate or fusion of a shard's size,
+    neither from the loop carry nor from the caller's input. Every
+    kernel is ``spd_<core>`` under ``spd.launch``, every collective
+    permute under ``spd.exchange``."""
     import re
 
     kern = kernels[app]
@@ -315,7 +304,7 @@ def test_largest_legal_plan_compiles_at_the_budget_edge(
     monkeypatch.setattr(streaming, "VMEM_BYTES", budget)
     state, scal = _shapes(kern, (words, h, w), one_chip)
     _assert_compiles(
-        functools.partial(streaming.spd_multistep_streamed, kern._step_fn,
+        functools.partial(streaming.spd_multistep, kern._step_fn,
                           m=m, block_h=bh, halo=kern.halo,
                           double_buffer=db, interpret=False),
         state, scal,
@@ -349,5 +338,5 @@ def test_every_column_sharded_plan_compiles(topo, kernels):
     scal = jax.ShapeDtypeStruct((1,), jnp.float32,
                                 sharding=NamedSharding(sk.mesh, P(None)))
     for block_h, m, db in sorted(plans):
-        fn = sk._fn(m, m, block_h, db, True, False)
+        fn = sk._fn(m, m, block_h, db, False)
         assert "tpu_custom_call" in fn.lower(state, scal).compile().as_text()

@@ -235,7 +235,7 @@ CHILD = textwrap.dedent("""
         sk.run_blocked(x, regs, steps=steps, m=m,
                        block_h=bh).block_until_ready()
         after = tracing.snapshot()
-        fn = sk._fn(steps, m, bh, True, True, True)
+        fn = sk._fn(steps, m, bh, True, True)
         text = fn.lower(x, kern._scal(regs)).compile().as_text()
         out[f"{d}x{dx}"] = {
             "delta": {k: after[k] - before[k] for k in before},
@@ -282,8 +282,7 @@ def test_mesh_counts_every_shard_and_scopes_its_glue(tmp_path):
         assert shard_flops == launch_flops(
             local_h, width, 1, block_h=bh, m=m, halo=got["halo"],
             flops=got["flops"])
-        # The mesh loop ping-pongs two kernel-written buffers, as on one
-        # chip; the ring's loop recycles none.
+        # Every mesh loop ping-pongs two kernel-written buffers, as on
+        # one chip.
         launches = steps // m
-        assert got["delta"]["aliased_launches"] == (
-            launches - 2 if d != dy else 0)
+        assert got["delta"]["aliased_launches"] == launches - 2
