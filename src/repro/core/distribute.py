@@ -21,26 +21,30 @@ Halo-exchange protocol, per fused launch, for every mesh shape:
    its top ``m·halo`` rows to the previous one, and — when ``dx > 1`` —
    its rightmost ``m·halo_x`` columns to the next column shard and its
    leftmost to the previous one (``ppermute`` collectives issued
-   together, all depending only on the current shard — on TPU these ride
-   the ICI links the DSE model's ``t_collective`` term prices, row and
-   column volumes separately). A mesh with one row shard (``dy == 1``)
-   sends no rows, since its halo rows wrap within the shard, and one
-   with one column shard (``dx == 1``) sends no columns, since its
-   stripes are full rows that wrap in x in registers, as on one chip;
-2. nothing shard-sized is assembled. The two received column pieces are
-   padded into one guard array of ``2·guard_cols(m·halo_x)`` columns,
-   ``[right | zeros | left]``; a small second hop column-permutes the
-   edges of the received row pieces to fetch the four
-   ``(m·halo, m·halo_x)`` corner blocks from the diagonal neighbors,
-   which join the row pieces in the same layout; and
+   together — on TPU these ride the ICI links the DSE model's
+   ``t_collective`` term prices, row and column volumes separately).
+   The columns go as the launch before wrote them beside its output:
+   ``(H / block_h, P, m·halo_x, 128)`` arrays, one row a block with the
+   block's rows on lanes (:func:`repro.kernels.spd_stream.edge_columns`
+   gives that layout of the input, for a call's first launch). A mesh
+   with one row shard (``dy == 1``) sends no rows, since its halo rows
+   wrap within the shard, and one with one column shard (``dx == 1``)
+   sends no columns, since its stripes are full rows that wrap in x in
+   registers, as on one chip;
+2. nothing shard-sized, nor anything guard-shaped for the columns, is
+   assembled. A small second hop column-permutes the edges of the
+   received row pieces to fetch the four ``(m·halo, m·halo_x)`` corner
+   blocks from the diagonal neighbors, which join the row pieces in
+   the stripe's layout; and
    :func:`repro.kernels.spd_stream.spd_multistep` DMAs each stripe from
-   the shard, the guard array and the row pieces where they lie, into a
-   VMEM stripe laid out ``[shard | right guard | left guard]``. The
+   the shard and the row pieces where they lie, into a VMEM stripe
+   laid out ``[shard | right guard | left guard]``, and transposes the
+   received edge columns of its rows into the guard lanes. The
    kernel's periodic stripe body then wraps each shard edge onto the
    guard columns that hold its true neighbors; the stale columns stay
    where the two guards meet, and the launch writes back only the
    shard's own columns, into one of two buffers the loop ping-pongs as
-   on one chip.
+   on one chip, and its own edge columns for the next launch.
 
 Because the launches reuse the single-device kernel arithmetic and the
 exchange delivers exactly the rows and columns the periodic index maps
@@ -198,7 +202,11 @@ class ShardedStreamKernel:
         place and writes only the shard's own columns, and the launches
         ping-pong between two buffers the kernel writes, as on one chip
         (:func:`~repro.kernels.spd_stream.stream_run_blocked`)."""
-        from repro.kernels.spd_stream import spd_multistep, stream_run_blocked
+        from repro.kernels.spd_stream import (
+            edge_columns,
+            spd_multistep,
+            stream_run_blocked,
+        )
 
         dy, dx, halo = self.dy, self.dx, self.halo
         step_fn = self.kernel._step_fn
@@ -232,23 +240,31 @@ class ShardedStreamKernel:
                                 left.dtype)
                 return jnp.concatenate([right, pad, left], axis=-1)
 
+        def ship(first, last):
+            """The neighbours' edge columns, as a launch wrote them:
+            the right neighbour's first and the left one's last."""
+            with jax.named_scope(tracing.EXCHANGE):
+                return (jax.lax.ppermute(first, DEVICE_AXIS_X, perm_l),
+                        jax.lax.ppermute(last, DEVICE_AXIS_X, perm_r))
+
         def exchange_x(piece):
-            """The column guard of ``piece``'s rows via the dx ring."""
+            """The column guard of a halo-row ``piece`` (its corners) via
+            the dx ring."""
             w = piece.shape[-1]
 
-            def ship(cols, perm):
+            def send(cols, perm):
                 # Sent flat: a (…, rows, mhx) operand takes a column-major
                 # layout for the collective, which XLA would otherwise
-                # push back onto the whole shard in the launch loop (a
-                # shard-sized copy a launch); flat, it stays row-major.
+                # push back onto the array it is sliced from; flat, it
+                # stays row-major.
                 shape = cols.shape
                 flat = cols.reshape(shape[:-2] + (shape[-2] * shape[-1],))
                 return jax.lax.ppermute(
                     flat, DEVICE_AXIS_X, perm).reshape(shape)
 
             with jax.named_scope(tracing.EXCHANGE):
-                left = ship(piece[..., w - mhx:], perm_r)
-                right = ship(piece[..., :mhx], perm_l)
+                left = send(piece[..., w - mhx:], perm_r)
+                right = send(piece[..., :mhx], perm_l)
             return guard(left, right)
 
         def edge(got, corner, top):
@@ -263,12 +279,14 @@ class ShardedStreamKernel:
                 return jnp.concatenate(
                     [pad, piece] if top else [piece, pad], axis=-2)
 
-        def launch(cur, scal, *, m, block_h, interpret, dst=None):
+        def launch(cur, scal, *, m, block_h, interpret, dst=None,
+                   carry=None):
             lh = cur.shape[-2]
-            # All first-hop collectives depend only on `cur` and are
-            # issued together: the column exchange and, on a sharded
-            # row axis, the row exchange (guard rows at local width).
-            guards = exchange_x(cur) if mhx else None
+            # All first-hop collectives are issued together: the column
+            # exchange of the edge columns the last launch wrote
+            # (`carry`) and, on a sharded row axis, the row exchange
+            # (guard rows at local width).
+            guards = None if carry is None else ship(*carry)
             edges = None
             if mh and dy > 1:
                 with jax.named_scope(tracing.EXCHANGE):
@@ -297,9 +315,15 @@ class ShardedStreamKernel:
 
         # Named for the compiled module (jit_spd_run_sharded).
         def spd_run_sharded(local, scal):
+            carry = None
+            if mhx:
+                # The first launch's edge columns come from the input;
+                # every later one's from the launch before it.
+                with jax.named_scope(tracing.EXCHANGE):
+                    carry = edge_columns(local, mhx, block_h)
             return stream_run_blocked(
                 launch, local, scal, steps=steps, m=m, block_h=block_h,
-                interpret=interpret,
+                interpret=interpret, carry=carry,
             )
 
         return spd_run_sharded
@@ -345,20 +369,26 @@ class ShardedStreamKernel:
                           interpret)
             out = fn(state, self.kernel._scal(regs))
             launches = steps // m
-            # Every shard's launch gathers and computes its stripes at
-            # the guarded width, and the loop ping-pongs two
-            # kernel-written buffers, as on one chip.
-            width = stripe_cols(local_w, m, self.halo_x if self.dx > 1 else 0)
+            # Every shard's launch gathers its stripes from the shard
+            # and the exchanged pieces and computes them at the guarded
+            # width, and the loop ping-pongs two kernel-written buffers,
+            # as on one chip. Under column guards, every launch after a
+            # call's first takes them from the launch before it.
+            halo_x = self.halo_x if self.dx > 1 else 0
             shard_bytes = launch_dma_bytes(
-                local_h, width, p, block_h=block_h, m=m, halo=self.halo,
-                itemsize=state.dtype.itemsize)
+                local_h, local_w, p, block_h=block_h, m=m, halo=self.halo,
+                itemsize=state.dtype.itemsize, halo_x=halo_x,
+                edged=self.dy > 1)
             shard_flops = launch_flops(
-                local_h, width, 1, block_h=block_h, m=m, halo=self.halo,
+                local_h, stripe_cols(local_w, m, halo_x), 1,
+                block_h=block_h, m=m, halo=self.halo,
                 flops=self.kernel.compiled.flops)
             tracing.count(launches=launches, steps=steps,
                           dma_bytes=launches * self.d * shard_bytes,
                           kernel_flops=launches * self.d * shard_flops,
-                          aliased_launches=max(0, launches - 2))
+                          aliased_launches=max(0, launches - 2),
+                          kernel_edge_launches=(max(0, launches - 1)
+                                                if m * halo_x else 0))
         return out
 
     def run_for_point(self, state, regs: Sequence = (), *, point,

@@ -246,6 +246,14 @@ def guard_cols(mhx):
     return -(-mhx // half) * half
 
 
+def edge_lanes(block_h: int) -> int:
+    """Lanes of one block's row in the compact edge-column layout of a
+    column-sharded launch (:func:`repro.kernels.spd_stream.edge_columns`):
+    ``block_h`` rounded up to whole lane tiles, since a DMA moves whole
+    tiles of the lane axis."""
+    return -(-block_h // LANES) * LANES
+
+
 def stripe_rows(block_h, m, halo):
     """Rows of one streamed stripe: ``block_h`` plus the carried halo
     (:func:`halo_rows`) on both sides — what the launch DMAs and
@@ -261,17 +269,33 @@ def stripe_cols(width, m, halo_x):
 
 
 def launch_dma_bytes(rows: int, width: int, planes: int, *, block_h: int,
-                     m: int, halo: int, itemsize: int) -> int:
+                     m: int, halo: int, itemsize: int, halo_x: int = 0,
+                     edged: bool = False) -> int:
     """Bytes the DMAs of one streamed fused launch are programmed to move
     over a ``(planes, rows, width)`` state (``planes`` = batch × words):
     each of the ``rows / block_h`` blocks reads its stripe
     (:func:`stripe_rows`) into VMEM, and the ``rows`` output rows are
-    written back. A halo-extended shard's launch (its interior and two
-    edge launches alike) moves the same rows: ``rows`` is then the
-    shard's height and ``width`` its launch width (:func:`stripe_cols`)."""
+    written back. On a mesh ``rows`` and ``width`` are the shard's.
+
+    ``halo_x > 0`` marks a launch with column guards: each block also
+    reads both neighbours' edge-column pieces of its own block and,
+    with halo rows, of the two blocks around it, ``m·halo_x`` rows of
+    :func:`edge_lanes` lanes each, and writes its own two. ``edged``
+    (a sharded row axis) takes the first block's up halo and the last
+    block's down halo from the exchanged halo rows, which are
+    :func:`stripe_cols` wide, instead of from the shard and the pieces.
+    """
     nblk = rows // block_h
-    return (planes * width * itemsize
-            * (nblk * stripe_rows(block_h, m, halo) + rows))
+    moved = width * (nblk * stripe_rows(block_h, m, halo) + rows)
+    if halo_x:
+        mhx = m * halo_x
+        mh = halo_rows(m * halo, block_h)
+        reads = nblk * (3 if mh else 1)
+        if edged and mh:
+            reads -= 2
+            moved += 2 * mh * 2 * guard_cols(mhx)
+        moved += 2 * mhx * edge_lanes(block_h) * (reads + nblk)
+    return planes * itemsize * moved
 
 
 def launch_flops(rows: int, width: int, batch: int, *, block_h: int,
@@ -890,6 +914,7 @@ __all__ = [
     "blocking_plan",
     "cluster_vmem_bytes",
     "constraint_violation",
+    "edge_lanes",
     "guard_cols",
     "halo_rows",
     "lane_multiple",

@@ -27,6 +27,11 @@ Counters (process-wide, read with :func:`snapshot`):
   one: ``max(0, launches - 2)`` per ``StreamKernel.run_blocked`` call
   and per mesh ``ShardedStreamKernel.run_blocked`` call
   (:func:`repro.kernels.spd_stream.stream_run_blocked`);
+* ``kernel_edge_launches``: those launches whose column guards came
+  from the edge columns the launch before them wrote
+  (:func:`repro.kernels.spd_stream.spd_multistep`): ``launches - 1``
+  per ``ShardedStreamKernel.run_blocked`` call on a mesh that shards
+  columns (``dx > 1``), 0 elsewhere;
 * ``jit_traces``: jaxpr traces, one per jit cache miss of any function
   in the process (the program's and its caller's alike);
 * ``jit_s``: seconds spent tracing, lowering and compiling (a
@@ -66,7 +71,8 @@ COMPILE_EVENTS = (
 
 _lock = threading.Lock()
 _counters = {"launches": 0, "steps": 0, "dma_bytes": 0, "kernel_flops": 0,
-             "aliased_launches": 0, "jit_traces": 0, "jit_s": 0.0}
+             "aliased_launches": 0, "kernel_edge_launches": 0,
+             "jit_traces": 0, "jit_s": 0.0}
 #: Disjoint compile spans seen so far, ``[start, end]`` in seconds.
 _spans: list[list[float]] = []
 
@@ -78,16 +84,17 @@ def kernel_name(core_name: str) -> str:
 
 
 def count(*, launches: int, steps: int, dma_bytes: int, kernel_flops: int,
-          aliased_launches: int = 0) -> None:
+          aliased_launches: int = 0, kernel_edge_launches: int = 0) -> None:
     """Add one call's launches, steps, programmed DMA bytes, executed
-    float operations and the launches among them that wrote into a
-    recycled buffer."""
+    float operations, the launches among them that wrote into a
+    recycled buffer and those whose column guards a kernel wrote."""
     with _lock:
         _counters["launches"] += int(launches)
         _counters["steps"] += int(steps)
         _counters["dma_bytes"] += int(dma_bytes)
         _counters["kernel_flops"] += int(kernel_flops)
         _counters["aliased_launches"] += int(aliased_launches)
+        _counters["kernel_edge_launches"] += int(kernel_edge_launches)
 
 
 def snapshot() -> dict:

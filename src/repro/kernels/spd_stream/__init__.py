@@ -11,9 +11,10 @@ that ``repro.core.distribute`` exchanges (docs/pipeline.md
 """
 
 from .ops import stream_run_blocked
-from .streaming import spd_multistep
+from .streaming import edge_columns, spd_multistep
 
 __all__ = [
+    "edge_columns",
     "spd_multistep",
     "stream_run_blocked",
 ]

@@ -19,7 +19,8 @@ from repro.core.legalize import blocking_plan, resolve_run_plan
 
 
 def stream_run_blocked(multistep: Callable, state, scal, *, steps: int,
-                       m: int, block_h: int, interpret: bool | None = None):
+                       m: int, block_h: int, interpret: bool | None = None,
+                       carry=None):
     """Advance ``steps`` time steps using m-fused kernel launches.
 
     ``multistep`` is a (typically jitted) closure over
@@ -34,27 +35,37 @@ def stream_run_blocked(multistep: Callable, state, scal, *, steps: int,
     reads and overwrites, and XLA copies nothing. ``state`` is only
     read: it is never a destination, so the caller may keep using it.
     ``max(0, steps // m - 2)`` launches write into a recycled buffer.
+
+    With ``carry`` (a mesh launch's edge columns, docs/pipeline.md
+    §distribute), ``multistep`` also takes ``carry=`` and returns
+    ``(out, carry)``: the first launch gets ``carry`` and each later one
+    what the launch before it returned.
     """
     if steps % m:
         raise ValueError(f"steps={steps} must be a multiple of m={m}")
     n = steps // m
 
     def launch(src, dst=None):
-        return multistep(src, scal, m=m, block_h=block_h,
-                         interpret=interpret, dst=dst)
+        x, c = src
+        kw = dict(m=m, block_h=block_h, interpret=interpret, dst=dst)
+        if carry is None:
+            return multistep(x, scal, **kw), None
+        return multistep(x, scal, carry=c, **kw)
 
+    if n < 1:
+        return state
+    a = launch((state, carry))
     if n < 2:
-        return launch(state) if n else state
-    a = launch(state)
+        return a[0]
     b = launch(a)
 
     def body(_, ab):
         a, b = ab
-        a = launch(b, a)
-        return a, launch(a, b)
+        a = launch(b, a[0])
+        return a, launch(a, b[0])
 
     a, b = jax.lax.fori_loop(0, (n - 2) // 2, body, (a, b))
-    return launch(b, a) if (n - 2) % 2 else b
+    return (launch(b, a[0]) if (n - 2) % 2 else b)[0]
 
 
 __all__ = [

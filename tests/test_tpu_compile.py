@@ -13,6 +13,7 @@ test skips when the TPU compiler is not installed.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -189,6 +190,60 @@ def test_mesh_loop_copies_no_state(topo, kernels, app, n, block_h, m, dy,
     for line in permutes:
         assert "/spd.exchange/" in re.search(r'op_name="([^"]*)"',
                                              line).group(1)
+
+
+def _loop_body(text):
+    """The op lines of the body computation of the one ``while`` loop
+    in ``text``."""
+    import re
+
+    (body,) = set(re.findall(r" while\(.*?body=%([\w.-]+)", text))
+    lines = text.split(f"\n%{body} ", 1)[1].split("\n}", 1)[0]
+    return lines.splitlines()[1:]
+
+
+def _f32_results(line):
+    """The shapes of an op line's f32 results, as tuples of ints."""
+    import re
+
+    op = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [\w-]+\(", line)
+    if not op:
+        return []
+    return [tuple(int(v) for v in dims.split(",") if v)
+            for dims in re.findall(r"f32\[([\d,]*)\]", op.group(1))]
+
+
+@pytest.mark.parametrize("launches", [16, 17])
+def test_mesh_loop_builds_no_guard_piece(topo, kernels, launches):
+    """On a mesh that shards columns each kernel writes its own edge
+    columns beside its output, in the compact layout its neighbours'
+    kernels read, and the loop ships them as they are. So the compiled
+    (1, 4) loop body at the four-chip cell's plan (64-row blocks,
+    m = 4) holds no op whose result is a shard-tall guard piece,
+    ``(…, H, m·halo_x)`` or ``(…, H, 2·guard_cols)``, nor one of them
+    flattened, and its collectives ship at most twice the bytes of the
+    edge columns a launch: the compact rows pad 64 rows to 128 lanes."""
+    import re
+
+    from repro.core.legalize import guard_cols
+
+    kern = kernels["lbm"]
+    n, block_h, m = 2048, 64, 4
+    words, h, mhx = len(kern._ports), n, m * kern.halo_x
+    text = _mesh_text(topo, kern, 1, 4, n, block_h, m, launches)
+    body = _loop_body(text)
+    guard = {(h, mhx), (h, 2 * guard_cols(mhx))}
+    pieces = [line.strip()[:120] for line in body
+              if any(shape[-2:] in guard or shape[-1:] == (h * mhx,)
+                     for shape in _f32_results(line))]
+    assert not pieces, pieces
+    shipped = [_f32_results(line)[0] for line in body
+               if re.search(r" collective-permute(-start)?\(", line)]
+    kernels_run = sum("tpu_custom_call" in line for line in body)
+    assert shipped and kernels_run
+    compact = 2 * words * h * mhx
+    per_launch = sum(math.prod(shape) for shape in shipped) / kernels_run
+    assert per_launch <= 2 * compact
 
 
 def _run_blocked_text(kern, one_chip, n, steps):

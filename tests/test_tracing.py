@@ -80,6 +80,29 @@ def test_launch_dma_bytes_by_hand(rows, width, planes, block_h, m, halo,
         planes * width * 4 * rows_moved
 
 
+@pytest.mark.parametrize("block_h,m,halo,edged,guard", [
+    # The four-chip cell's plan on a 16384-row shard: 256 blocks read
+    # 3 edge-column pieces of 4 × 128 a side each and write one.
+    (64, 4, 1, False, 2 * 4 * 128 * (3 * 256 + 256)),
+    # Exchanged halo rows: the first block reads none above and the last
+    # none below, and those rows come 128 guard lanes wider.
+    (64, 4, 1, True, 2 * 4 * 128 * (3 * 256 - 2 + 256) + 2 * 8 * 128),
+    # m·halo = 0: a block reads only its own pieces; 256-row blocks
+    # fill two lane tiles a row.
+    (256, 3, 0, True, 2 * 3 * 256 * (64 + 64)),
+])
+def test_launch_dma_bytes_counts_the_edge_columns(block_h, m, halo, edged,
+                                                  guard):
+    """A launch with column guards also moves the neighbours' edge
+    columns in and its own out, in their compact layout."""
+    rows, width, planes = 16384, 4096, 10
+    plain = launch_dma_bytes(rows, width, planes, block_h=block_h, m=m,
+                             halo=halo, itemsize=4)
+    assert launch_dma_bytes(rows, width, planes, block_h=block_h, m=m,
+                            halo=halo, itemsize=4, halo_x=1,
+                            edged=edged) == plain + planes * 4 * guard
+
+
 @pytest.mark.parametrize("rows,width,batch,block_h,m,halo,flops,sites", [
     # 4 blocks of 16 rows, each a 32-row stripe (one 8-row halo tile a
     # side), every stripe row computed at each of the 4 steps.
@@ -267,11 +290,23 @@ def test_mesh_counts_every_shard_and_scopes_its_glue(tmp_path):
         assert got["scopes"] == ["spd.assemble", "spd.exchange",
                                  "spd.launch"]
         local_h = n // dy
+        nblk = local_h // bh
         mh = -(-m * got["halo"] // 8) * 8
+        guarded = d != dy and got["halo_x"]
         # Guard columns: m·halo_x rounded up to half a lane tile (64).
-        width = local_w + (2 * 64 if d != dy and got["halo_x"] else 0)
-        per_shard = words * width * 4 * (
-            (local_h // bh) * (bh + 2 * mh) + local_h)
+        width = local_w + (2 * 64 if guarded else 0)
+        # The state's stripes and output rows, at the shard's width.
+        moved = local_w * (nblk * (bh + 2 * mh) + local_h)
+        if guarded:
+            # Both neighbours' m·halo_x edge columns, one 128-lane row a
+            # block: each block reads its own and the two around it
+            # (the first block none above, the last none below: the
+            # exchanged halo rows bring those, guard lanes included) and
+            # writes its own two.
+            mhx = m * got["halo_x"]
+            moved += 2 * mhx * 128 * ((3 * nblk - 2) + nblk)
+            moved += 2 * mh * 128
+        per_shard = words * 4 * moved
         # Each shard computes m steps over every row of its stripes.
         shard_flops = width * (local_h // bh) * (bh + 2 * mh) * m \
             * got["flops"]
@@ -286,3 +321,7 @@ def test_mesh_counts_every_shard_and_scopes_its_glue(tmp_path):
         # one chip.
         launches = steps // m
         assert got["delta"]["aliased_launches"] == launches - 2
+        # Under column guards every launch after the first takes them
+        # from the kernel before it.
+        assert got["delta"]["kernel_edge_launches"] == (
+            launches - 1 if guarded else 0)
